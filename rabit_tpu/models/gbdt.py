@@ -58,19 +58,16 @@ class GBDTConfig(NamedTuple):
     # route+margin kernel (ops/boost.py route_margin_level); False runs
     # the routing-only kernel and leaves ``margin += leaf[node]`` to XLA
     # (a 1M-row gather from a 2**depth-entry table).  Both are exact.
-    # The round-5 whole-round on-chip measurements decided the default:
-    # XLA-final won in BOTH MXU modes and in three independent runs
-    # (73.8 vs 78.1 ms bf16, 77.3 vs 78.7 ms i8 — RESULTS/final_pass.jsonl;
-    # 70.0/70.1 vs 74.0/72.8 ms in the driver-bench races), so False is
-    # the measured default and the fused kernel stays as the challenger
-    # bench.py re-races each capture.
+    # Older chip figures, not re-measured (RESULTS/final_pass.jsonl):
+    # XLA-final won whole-round in both MXU modes (73.8 vs 78.1 ms bf16,
+    # 77.3 vs 78.7 ms i8), so False is the default and the fused kernel
+    # stays as the challenger bench.py re-races.
     fused_final: bool = False
     # Split each row block into this many independent sub-contractions in
     # the level kernels' histogram accumulation (ops/boost.py _accum):
     # sub-block i's MXU matmul has no dependency on sub-block i+1's VPU
-    # indicator build, giving Mosaic explicit overlap room (the measured
-    # VPU/MXU co-dominance headroom, RESULTS.md §1).  Must divide the row
-    # block (1024); results are added in f32.  Default 1 = current
+    # indicator build, giving Mosaic explicit overlap room.  Must divide
+    # the row block (1024); results are added in f32.  Default 1 = current
     # single-contraction form; >1 is the on-chip ablation's experiment.
     r_split: int = 1
 

@@ -118,10 +118,9 @@ def _accum(xb_blk, L, out_ref, *, n_bins: int, n_feat: int, fc: int, i8: bool,
     reassociation only for bf16) — an overlap experiment: sub-block i's
     matmul (MXU) has no data dependency on sub-block i+1's indicator
     build (VPU), giving Mosaic's scheduler explicit room to run them
-    concurrently.  Round-5 on-chip roofline: the ~3.7 ms/level indicator
-    rebuild is co-dominant with the int8-rate matmul, so full overlap is
-    worth up to ~25% of the round (RESULTS.md §1); measured by the
-    ablation's rsplit rows."""
+    concurrently.  The indicator rebuild was modeled as co-dominant with
+    the int8-rate matmul (older chip figure, not re-measured); the
+    ablation's rsplit rows (RESULTS/final_pass.jsonl) measured no gain."""
     be = _bins_eff(n_bins)
     l2, onehot_dtype, acc_dtype, decode = (_encode_i8 if i8 else _encode_bf16)(L)
     r = xb_blk.shape[0]

@@ -221,51 +221,6 @@ def test_fused_builder_input_validation():
         fused.segment_widths(get_codec("zlib"))
 
 
-def test_bench_probe_daemon_reset_budget(monkeypatch):
-    """ISSUE 11 bench prong: the persistent prober spends its reset
-    budget after consecutive failures and records the evidence the
-    driver record embeds (attempts/successes/resets/last-ok age)."""
-    import bench
-
-    verdicts = iter([False, False, True, True])
-    monkeypatch.setattr(bench, "probe_device",
-                        lambda timeout=45.0: next(verdicts))
-    d = bench.ProbeDaemon(interval=999.0, reset_budget=1, reset_after=2)
-    assert not d.healthy()
-    assert not d.probe_now()  # failure 1: under the reset threshold
-    assert d.snapshot()["resets"] == 0
-    # failure 2 trips the reset, and the post-reset retry succeeds
-    assert d.probe_now()
-    snap = d.snapshot()
-    assert snap["resets"] == 1 and snap["successes"] == 1
-    assert snap["attempts"] == 3
-    assert d.healthy(max_age=60)
-    # budget exhausted: a later failure must not reset again
-    monkeypatch.setattr(bench, "probe_device", lambda timeout=45.0: False)
-    assert not d.probe_now()
-    assert d.snapshot()["resets"] == 1
-
-
-def test_bench_partial_capture_preference():
-    """ISSUE 11 bench prong: the parent takes the last FINAL measurement
-    line; partial-round captures only win when no race completed — a
-    losing challenger's partials can never shadow a finished race, and a
-    wedged run still salvages its best-so-far on-chip number."""
-    import bench
-
-    mixed = "\n".join([
-        '{"device_time": 0.5, "platform": "tpu", "mxu": "bf16", "partial": 1}',
-        '{"device_time": 0.45, "platform": "tpu", "mxu": "bf16"}',
-        '{"device_time": 0.39, "platform": "tpu", "mxu": "i8", "partial": 1}',
-    ])
-    res = bench._pick_result(mixed)
-    assert "partial" not in res and res["device_time"] == 0.45
-    only_partial = bench._pick_result(
-        '{"device_time": 0.5, "platform": "tpu", "mxu": "bf16", "partial": 3}')
-    assert only_partial["partial"] == 3
-    assert bench._pick_result("no json here") is None
-
-
 def test_bench_codec_pareto_frontier():
     """ISSUE 11 satellite: the driver record's codec_pareto row — a codec
     is on the frontier unless another strictly dominates it on the

@@ -4,13 +4,12 @@
 The repo's perf evidence is a trajectory of driver runs: ``BENCH_rNN.json``
 (the gbdt macro-bench, one record per run), ``MULTICHIP_rNN.json`` (the
 8-device smoke), and the ``RESULTS/`` snapshots (speed tables, failover
-drills, the ``bench_watch.json`` last-good TPU capture).  History shows
-why a gate must read the WHOLE trajectory, not the last record: runs
-r03–r05 silently fell back from the TPU backend to CPU — every record
-individually "passed" (rc 0, a plausible rounds/s number), yet the
-12+ rounds/s TPU capability from r02 went dark for three straight runs
-with nobody flagging it.  This sentinel makes that shape a first-class
-failure:
+drills).  A gate must read the WHOLE trajectory, not the last record: a
+benchmark that silently falls back from the TPU backend to CPU leaves
+records that individually "pass" (rc 0, a plausible rounds/s number)
+while the TPU capability goes dark with nobody flagging it.  ``bench.py``
+no longer has such a fallback; this sentinel still makes that shape a
+first-class failure:
 
 * **high-water tracking** — per metric, per platform, the best value
   ever measured and the run that measured it;
@@ -18,7 +17,7 @@ failure:
   ``--tolerance`` (default 20%) below that platform's high-water mark;
 * **dark rule** — the platform holding a metric's global high-water has
   produced no sample for the last ``--dark-after`` runs while a sibling
-  platform still reports the metric (the silent-fallback wedge shape);
+  platform still reports the metric (the silent-fallback shape);
 * **failing rule** — the newest run exited non-zero or parsed to nothing.
 
 ``bench.py`` stamps the verdict into every new driver record
@@ -81,11 +80,6 @@ def collect_results(root: str) -> dict:
     multichip smoke — carried in the verdict, not rule inputs (they are
     single snapshots, not a trajectory)."""
     out: dict = {}
-    watch = _load(os.path.join(root, "RESULTS", "bench_watch.json"))
-    if isinstance(watch, dict) and "value" in watch:
-        out["bench_watch"] = {"metric": watch.get("metric"),
-                              "value": watch.get("value"),
-                              "platform": watch.get("platform")}
     speed_path = os.path.join(root, "RESULTS", "speed.jsonl")
     best: dict[str, float] = {}
     try:
@@ -184,14 +178,6 @@ def verdict(root: str = ".", tolerance: float = 0.2,
                 "fallback_platforms": sorted(p for p in platforms
                                              if p != hw_platform),
             }
-            # a carried last_tpu_capture proves the fallback knew better
-            for run in reversed(runs):
-                cap = (run["parsed"] or {}).get("last_tpu_capture") \
-                    if isinstance(run["parsed"], dict) else None
-                if isinstance(cap, dict) and "value" in cap:
-                    reg["carried_capture"] = {"value": cap.get("value"),
-                                              "run": run["n"]}
-                    break
             regressions.append(reg)
 
     if runs and (runs[-1]["rc"] != 0

@@ -18,7 +18,7 @@ PR 7 adds the schedule surface (doc/scheduling.md):
   simulated mesh (no cluster): fixed tree+ring vs planned ring vs Swing
   serpentine ring, plus a degraded-link column (one ring link slowed
   ``--slow-factor``x, unrepaired vs repaired plan).  The measured
-  world-512 depth-17 consensus baseline (RESULTS.md §3) is the anchor
+  world-512 depth-17 consensus baseline (RESULTS/consensus.jsonl) is the anchor
   these modeled curves sit on top of;
 * ``--slow-link-e2e`` — the live repair A/B: a chaos ``slow_link``
   schedule run with repair off then on; the dst worker's cumulative
@@ -33,8 +33,8 @@ PR 9 adds ``--scale-sweep`` (doc/scaling.md, tools/scale_sweep.py):
 simulated worlds at 512-8192 measuring bootstrap/recovery-wave latency,
 heartbeat/metrics RPC p99, and tracker FD/thread high-water marks for
 the thread-per-connection, reactor, and relayed serving paths
-(``--scale-worlds`` picks the curve; the RESULTS §3e anchor is the full
-run in RESULTS/scale_sweep.jsonl).
+(``--scale-worlds`` picks the curve; the anchor is the full run in
+RESULTS/scale_sweep.jsonl).
 
 Usage:  python tools/consensus_bench.py [--world 32] [--iters 200]
 Prints one JSON line per mode; the default latency mode runs as

@@ -23,8 +23,9 @@ trajectory picks them up.
 ``--scale-sweep`` switches to the simulated-world control-plane sweep
 (tools/scale_sweep.py, doc/scaling.md): recovery-wave latency under
 heartbeat load at worlds 512-8192, thread-per-connection vs reactor vs
-relayed — the recovery half of the RESULTS §3e curve (bootstrap rides
-along; ``tools/consensus_bench.py --scale-sweep`` is the same sweep).
+relayed — the recovery half of the RESULTS/scale_sweep.jsonl curve
+(bootstrap rides along; ``tools/consensus_bench.py --scale-sweep`` is the
+same sweep).
 
 ``--failover`` switches to the HA-failover mode (doc/ha.md): per world
 size, an in-thread elastic job with a warm standby gets its PRIMARY

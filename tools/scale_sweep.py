@@ -32,7 +32,7 @@ Three arms per world (doc/scaling.md):
 per (world, arm); ``--quick`` is the tier-1 smoke shape (world 256).
 Also reachable as ``tools/consensus_bench.py --scale-sweep`` and
 ``tools/recovery_bench.py --scale-sweep`` (one durable copy lives in
-RESULTS/scale_sweep.jsonl, summarized in RESULTS.md §3e).
+RESULTS/scale_sweep.jsonl).
 """
 
 from __future__ import annotations
