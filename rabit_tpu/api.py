@@ -471,35 +471,46 @@ def load_checkpoint(with_local: bool = False):
     fresh cluster first agrees on and resumes from the newest disk
     checkpoint (whole-job preemption durability)."""
     global _ckpt_base
-    version, gblob, lblob = _get_engine().load_checkpoint()
-    if _ckpt_store is not None:
-        if version == 0:
-            vmax, dgblob, dlblob = _disk_resume()
-            if vmax > 0:
-                # Resuming a PREVIOUS job: the file's version is the new
-                # base; the wrapper inside carries the old job's base and
-                # is discarded.
-                _ckpt_base = vmax
-                _, gblob = _unwrap(dgblob)
-                lblob = dlblob
-                version = vmax
-        else:
-            # Peer-served blob from the CURRENT job: its wrapper carries
-            # this job's base (authoritative for a restarted worker, whose
-            # process state starts empty).
-            _ckpt_base, gblob = _unwrap(gblob)
-            version = _ckpt_base + version
-    # Cross-rank collective numbering (obs/trace.py): landing on version V
-    # resets the per-version seqno exactly like the survivors' commit of V
-    # did, so a restarted worker resumes the shared (version, seqno) line.
-    obs.collective_epoch(version)
-    obs.record_event("load_checkpoint", version=version,
-                     recovered=version > 0)
-    if version > 0:
-        obs.get_registry().counter("load_checkpoint_recovered_total").inc()
-    gmodel = pickle.loads(gblob) if version > 0 and gblob is not None else None
+    with obs.span("rabit.load_checkpoint") as sp:
+        with obs.span("rabit.load.engine"):
+            version, gblob, lblob = _get_engine().load_checkpoint()
+        if _ckpt_store is not None:
+            if version == 0:
+                with obs.span("rabit.load.disk"):
+                    vmax, dgblob, dlblob = _disk_resume()
+                if vmax > 0:
+                    # Resuming a PREVIOUS job: the file's version is the new
+                    # base; the wrapper inside carries the old job's base and
+                    # is discarded.
+                    _ckpt_base = vmax
+                    _, gblob = _unwrap(dgblob)
+                    lblob = dlblob
+                    version = vmax
+            else:
+                # Peer-served blob from the CURRENT job: its wrapper carries
+                # this job's base (authoritative for a restarted worker, whose
+                # process state starts empty).
+                _ckpt_base, gblob = _unwrap(gblob)
+                version = _ckpt_base + version
+        sp.set(version=version)
+        # Cross-rank collective numbering (obs/trace.py): landing on version
+        # V resets the per-version seqno exactly like the survivors' commit
+        # of V did, so a restarted worker resumes the shared (version, seqno)
+        # line.
+        obs.collective_epoch(version)
+        obs.record_event("load_checkpoint", version=version,
+                         recovered=version > 0)
+        if version > 0:
+            obs.get_registry().counter("load_checkpoint_recovered_total").inc()
+        gmodel = lmodel = None
+        if version > 0:
+            with obs.span("rabit.load.unpickle",
+                          nbytes=len(gblob or b"") + len(lblob or b"")):
+                if gblob is not None:
+                    gmodel = pickle.loads(gblob)
+                if with_local and lblob is not None:
+                    lmodel = pickle.loads(lblob)
     if with_local:
-        lmodel = pickle.loads(lblob) if version > 0 and lblob is not None else None
         return version, gmodel, lmodel
     return version, gmodel
 
@@ -521,25 +532,39 @@ def checkpoint(global_model: Any, local_model: Any = None) -> None:
     ``global_model`` (reference notes, python/rabit.py:320-351).  With
     ``rabit_checkpoint_dir`` configured, the committed blobs are also
     spilled to disk (whole-job preemption durability)."""
-    gblob = pickle.dumps(global_model, protocol=pickle.HIGHEST_PROTOCOL)
-    lblob = None if local_model is None else pickle.dumps(local_model, protocol=pickle.HIGHEST_PROTOCOL)
     engine = _get_engine()
-    if _ckpt_store is None:
-        engine.checkpoint(gblob, lblob)
-        _note_commit(engine, len(gblob))
+    # the spans' label: the version this commit makes (what is saved below
+    # is named by the engine's own count, read after the commit)
+    version = _ckpt_base + engine.version_number() + 1
+    with obs.span("rabit.checkpoint", version=version) as sp:
+        with obs.span("rabit.checkpoint.pickle") as pk:
+            gblob = pickle.dumps(global_model, protocol=pickle.HIGHEST_PROTOCOL)
+            lblob = None if local_model is None else pickle.dumps(
+                local_model, protocol=pickle.HIGHEST_PROTOCOL)
+            if _ckpt_store is not None:
+                gblob = _wrap(_ckpt_base, gblob)
+            n_local = 0 if lblob is None else len(lblob)
+            nbytes = len(gblob) + n_local
+            pk.set(nbytes=nbytes)
+        sp.set(nbytes_global=len(gblob), nbytes_local=n_local)
+        with obs.span("rabit.checkpoint.commit", nbytes=nbytes):
+            engine.checkpoint(gblob, lblob)
+            _note_commit(engine, len(gblob))
+        if _ckpt_store is not None:
+            # Persist AFTER the commit barrier: live ranks' disk versions
+            # can then skew by at most one, which the store's keep-2
+            # retention covers.  The adopted world epoch rides in the frame
+            # (RTC3) so a resume can tell which membership generation
+            # produced each version — replay across a resize stays
+            # deterministic (doc/elasticity.md).
+            with obs.span("rabit.checkpoint.spill"):
+                _ckpt_store.save(_ckpt_base + engine.version_number(), gblob,
+                                 lblob, epoch=_world_epoch["epoch"])
         _publish_commit(engine, gblob)
-        return
-    wrapped = _wrap(_ckpt_base, gblob)
-    engine.checkpoint(wrapped, lblob)
-    _note_commit(engine, len(wrapped))
-    # Persist AFTER the commit barrier: live ranks' disk versions can then
-    # skew by at most one, which the store's keep-2 retention covers.  The
-    # adopted world epoch rides in the frame (RTC3) so a resume can tell
-    # which membership generation produced each version — replay across a
-    # resize stays deterministic (doc/elasticity.md).
-    _ckpt_store.save(_ckpt_base + engine.version_number(), wrapped, lblob,
-                     epoch=_world_epoch["epoch"])
-    _publish_commit(engine, wrapped)
+        # The pickles go back to the allocator here, under a name, and not
+        # at the return, under none: milliseconds for a 42 MB margin.
+        with obs.span("rabit.checkpoint.release", nbytes=nbytes):
+            del gblob, lblob
 
 
 def _publish_commit(engine: Engine, blob: bytes) -> None:
@@ -551,16 +576,18 @@ def _publish_commit(engine: Engine, blob: bytes) -> None:
     if _publisher is None:
         return
     version = _ckpt_base + engine.version_number()
-    try:
-        _publisher.publish(version, blob, epoch=_world_epoch["epoch"])
-        if _ckpt_store is not None:
-            # Pin what subscribers were just told about: the retention
-            # prune must not race a fetch-in-flight of this version.
-            _ckpt_store.pin(version)
-        obs.record_event("snapshot_published", version=version,
-                         nbytes=len(blob))
-    except (ConnectionError, OSError, ValueError):
-        pass
+    with obs.span("rabit.checkpoint.publish", version=version,
+                  nbytes=len(blob)):
+        try:
+            _publisher.publish(version, blob, epoch=_world_epoch["epoch"])
+            if _ckpt_store is not None:
+                # Pin what subscribers were just told about: the retention
+                # prune must not race a fetch-in-flight of this version.
+                _ckpt_store.pin(version)
+            obs.record_event("snapshot_published", version=version,
+                             nbytes=len(blob))
+        except (ConnectionError, OSError, ValueError):
+            pass
 
 
 def lazy_checkpoint(global_model: Any) -> None:

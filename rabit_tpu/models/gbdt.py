@@ -34,6 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from rabit_tpu import obs
+
 
 class GBDTConfig(NamedTuple):
     """Static hyperparameters (hashable: usable as a jit static arg)."""
@@ -239,22 +241,25 @@ def train_round(
     feats, thrs = [], []
     for d in range(cfg.depth):
         n_nodes = 2 ** d
-        hist = hist_fn(xb, g, h, node, n_nodes, cfg.n_bins)
-        feat, thr, _gain = best_splits(hist, cfg)
-        feats.append(jnp.zeros(max_nodes, jnp.int32).at[:n_nodes].set(feat))
-        thrs.append(jnp.zeros(max_nodes, jnp.int32).at[:n_nodes].set(thr))
-        # Route every row one level down: right iff bin > threshold.
-        fsel = feat[node]                                        # [n]
-        xv = jnp.take_along_axis(xb, fsel[:, None], 1)[:, 0]
-        node = node * 2 + (xv > thr[node]).astype(jnp.int32)
+        with jax.named_scope(f"level{d}"):
+            hist = hist_fn(xb, g, h, node, n_nodes, cfg.n_bins)
+            feat, thr, _gain = best_splits(hist, cfg)
+            feats.append(jnp.zeros(max_nodes, jnp.int32).at[:n_nodes].set(feat))
+            thrs.append(jnp.zeros(max_nodes, jnp.int32).at[:n_nodes].set(thr))
+            # Route every row one level down: right iff bin > threshold.
+            fsel = feat[node]                                        # [n]
+            xv = jnp.take_along_axis(xb, fsel[:, None], 1)[:, 0]
+            node = node * 2 + (xv > thr[node]).astype(jnp.int32)
     # Leaf weights from summed per-leaf gradient mass.
     from rabit_tpu.ops import hist as _hist
 
     n_leaves = 2 ** cfg.depth
-    leaf_gh = _hist.segment_sum(jnp.stack([g, h], -1), node, n_leaves)
-    leaf_gh = combine_leaf(leaf_gh)  # [n_leaves, 2] allreduce
-    leaf = -cfg.learning_rate * leaf_gh[:, 0] / (leaf_gh[:, 1] + cfg.reg_lambda)
-    margin = state.margin + leaf[node]
+    with jax.named_scope("leaf"):
+        leaf_gh = _hist.segment_sum(jnp.stack([g, h], -1), node, n_leaves)
+        leaf_gh = combine_leaf(leaf_gh)  # [n_leaves, 2] allreduce
+        leaf = (-cfg.learning_rate * leaf_gh[:, 0]
+                / (leaf_gh[:, 1] + cfg.reg_lambda))
+        margin = state.margin + leaf[node]
     t = state.round
     forest = Forest(
         feature=lax.dynamic_update_index_in_dim(
@@ -333,21 +338,24 @@ def train_round_fused(
             "silently mispaired with gradients"
         )
 
-    hist = combine(boost.hist_level0(xb3, g3, h3, n_bins=cfg.n_bins,
-                                     interpret=interpret, mxu_i8=cfg.mxu_i8,
-                                     r_split=cfg.r_split))
-    feat, thr, _ = best_splits(hist, cfg)
+    with jax.named_scope("level0"):
+        hist = combine(boost.hist_level0(xb3, g3, h3, n_bins=cfg.n_bins,
+                                         interpret=interpret,
+                                         mxu_i8=cfg.mxu_i8,
+                                         r_split=cfg.r_split))
+        feat, thr, _ = best_splits(hist, cfg)
     feats = [jnp.zeros(max_nodes, jnp.int32).at[:1].set(feat)]
     thrs = [jnp.zeros(max_nodes, jnp.int32).at[:1].set(thr)]
     node3 = jnp.zeros_like(g3, shape=g3.shape, dtype=jnp.int32)
     for d in range(1, cfg.depth):
-        hist, node3 = boost.hist_level(xb3, node3, g3, h3, feat, thr,
-                                       depth=d, n_bins=cfg.n_bins,
-                                       interpret=interpret,
-                                       mxu_i8=cfg.mxu_i8,
-                                       r_split=cfg.r_split)
-        hist = combine(hist)
-        feat, thr, _ = best_splits(hist, cfg)
+        with jax.named_scope(f"level{d}"):
+            hist, node3 = boost.hist_level(xb3, node3, g3, h3, feat, thr,
+                                           depth=d, n_bins=cfg.n_bins,
+                                           interpret=interpret,
+                                           mxu_i8=cfg.mxu_i8,
+                                           r_split=cfg.r_split)
+            hist = combine(hist)
+            feat, thr, _ = best_splits(hist, cfg)
         feats.append(jnp.zeros(max_nodes, jnp.int32).at[: 2 ** d].set(feat))
         thrs.append(jnp.zeros(max_nodes, jnp.int32).at[: 2 ** d].set(thr))
     # Leaf (g, h) masses come straight off the final combined histogram
@@ -359,18 +367,20 @@ def train_round_fused(
     # 2**depth-entry leaf table — the gather form measured faster
     # whole-round in both MXU modes and is the default; see the
     # GBDTConfig.fused_final docstring (RESULTS/final_pass.jsonl).
-    leaf_gh = split_child_masses(hist, feat, thr)
-    leaf = -cfg.learning_rate * leaf_gh[:, 0] / (leaf_gh[:, 1] + cfg.reg_lambda)
-    if cfg.fused_final:
-        margin3, _ = boost.block_rows(state.margin, block)
-        margin3, _node3 = boost.route_margin_level(
-            xb3, node3, margin3, feat, thr, leaf, depth=cfg.depth,
-            interpret=interpret)
-        margin = boost.unblock_rows(margin3, n)
-    else:
-        node3 = boost.route_level(xb3, node3, feat, thr, depth=cfg.depth,
-                                  interpret=interpret)
-        margin = state.margin + leaf[boost.unblock_rows(node3, n)]
+    with jax.named_scope("leaf"):
+        leaf_gh = split_child_masses(hist, feat, thr)
+        leaf = (-cfg.learning_rate * leaf_gh[:, 0]
+                / (leaf_gh[:, 1] + cfg.reg_lambda))
+        if cfg.fused_final:
+            margin3, _ = boost.block_rows(state.margin, block)
+            margin3, _node3 = boost.route_margin_level(
+                xb3, node3, margin3, feat, thr, leaf, depth=cfg.depth,
+                interpret=interpret)
+            margin = boost.unblock_rows(margin3, n)
+        else:
+            node3 = boost.route_level(xb3, node3, feat, thr, depth=cfg.depth,
+                                      interpret=interpret)
+            margin = state.margin + leaf[boost.unblock_rows(node3, n)]
     t = state.round
     forest = Forest(
         feature=lax.dynamic_update_index_in_dim(
@@ -424,8 +434,15 @@ def train_round_hybrid(
         # (io_callback(ordered=True) would be the canonical primitive, but
         # XLA's SPMD partitioner rejects side-effecting ops with the
         # replicated shardings this program needs.)
+        def host(x, _t):
+            # the host side of one hop, device->host copy to the result's
+            # copy back: what the device waits for beyond the engine's call
+            with obs.span("gbdt.cross", level=tag):
+                return np.asarray(engine_allreduce(np.asarray(x)),
+                                  dtype=x.dtype)
+
         return jax.pure_callback(
-            lambda x, _t: np.asarray(engine_allreduce(np.asarray(x)), dtype=x.dtype),
+            host,
             jax.ShapeDtypeStruct(a.shape, a.dtype),
             a,
             np.int32(tag),

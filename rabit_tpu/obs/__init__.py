@@ -1,12 +1,16 @@
 """rabit_tpu.obs — per-rank observability: flight recorder + metrics.
 
-Three pieces (ISSUE 1 tentpole):
+Four pieces (ISSUE 1 tentpole; spans since ISSUE 25):
 
 * a per-rank **flight recorder** (events.py) — bounded ring of structured
   events: op begin/end with cache_key/nbytes, bootstrap/recovery phases,
   checkpoint commits, engine lifecycle;
 * a **metrics registry** (metrics.py) — thread-safe counters / gauges /
   latency histograms subsuming the old ``CollectiveStats``;
+* **spans** (:class:`span`, below) — the one timing primitive: one
+  event and one histogram observation a span, and a ``TraceAnnotation`` on
+  the profiler's clock wherever jax is already imported;
+  :func:`collective` is built on it;
 * **shipping** (ship.py) — workers send metric snapshots to the tracker
   (``CMD_METRICS``) on shutdown/heartbeat; the tracker writes a job-level
   ``telemetry.json``.
@@ -262,6 +266,92 @@ def collective_seq() -> tuple[int, int]:
         return _STATE.op_version, _STATE.op_seq
 
 
+# -- spans: the one timing primitive ------------------------------------------
+
+_SPANS = threading.local()   # .stack: this thread's open spans, outermost first
+
+
+class span:
+    """Time one named stretch of the program: ``with obs.span("rabit.x",
+    nbytes=n) as sp: ...`` (doc/observability.md, "Spans on the profiler's
+    clock").
+
+    * Where jax is ALREADY imported the span is also a
+      ``jax.profiler.TraceAnnotation(name, **fields)`` — the only place in
+      ``rabit_tpu/`` that opens one — so inside a profiler session it lies
+      on the profiler's clock beside the device's operations, its fields
+      as the annotation's stats.  With no session running the annotation
+      is a no-op in the runtime.  A span never imports jax: protocol-only
+      workers, the tracker and the launcher stay jax-free.
+    * A thread-local stack gives each span its ``parent`` (the enclosing
+      span's name), and each carries ``version``, the checkpoint version
+      it belongs to: given, else the parent's, else the one
+      :func:`collective_epoch` keeps.
+    * On exit ONE flight-recorder event ``span`` (``name``, ``t0`` as
+      ``time.time()``, ``seconds``, ``parent``, ``version``, fields) and
+      one observation into the registry histogram ``<name>_seconds``.
+
+    ``sp.set(**fields)`` adds what is only known inside the window (an
+    encoded size, the version a load landed on)."""
+
+    __slots__ = ("name", "fields", "version", "parent", "_t0", "_p0", "_ann")
+
+    def __init__(self, name: str, /, version: int | None = None, **fields):
+        self.name = name
+        self.fields = fields
+        self.version = version
+        self.parent: str | None = None
+        self._ann = None
+
+    def set(self, **fields) -> None:
+        if self._ann is not None:
+            self._ann.set_metadata(**fields)
+        if "version" in fields:
+            self.version = int(fields.pop("version"))
+        self.fields.update(fields)
+
+    def __enter__(self) -> "span":
+        stack = getattr(_SPANS, "stack", None)
+        if stack is None:
+            stack = _SPANS.stack = []
+        if stack:
+            top = stack[-1]
+            self.parent = top.name
+            if self.version is None:
+                self.version = top.version
+        elif self.version is None:
+            self.version = _STATE.op_version
+        stack.append(self)
+        jax = sys.modules.get("jax")
+        profiler = getattr(jax, "profiler", None)
+        if profiler is not None:
+            self._ann = profiler.TraceAnnotation(
+                self.name, version=self.version, **self.fields)
+            self._ann.__enter__()
+        self._t0 = time.time()
+        self._p0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dt = time.perf_counter() - self._p0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        # the entering thread's stack; a span closed elsewhere or out of
+        # order (a generator-held one) must not stay as everybody's parent
+        stack = getattr(_SPANS, "stack", None)
+        if stack:
+            if stack[-1] is self:
+                stack.pop()
+            elif self in stack:
+                stack.remove(self)
+        GLOBAL_RECORDER.record(
+            "span", name=self.name, t0=round(self._t0, 6),
+            seconds=round(dt, 6), parent=self.parent, version=self.version,
+            **self.fields)
+        GLOBAL_REGISTRY.histogram(self.name + "_seconds").observe(dt)
+
+
 @contextlib.contextmanager
 def collective(op: str, nbytes: int, cache_key: str | None = None,
                codec: str | None = None, fused: bool = False):
@@ -295,15 +385,21 @@ def collective(op: str, nbytes: int, cache_key: str | None = None,
     record_event("op_begin", op=op, nbytes=nbytes, cache_key=cache_key,
                  version=version, seqno=seqno, **extra)
     t0 = time.perf_counter()
-    span = _Span(op, nbytes, cache_key)
+    handle = _Span(op, nbytes, cache_key)
     try:
-        yield span
+        # the same interval as a span ``rabit.<op>``: on the profiler's
+        # clock in a traced run, a ``span`` event in the flight recorder
+        with span("rabit." + op, version=version, seqno=seqno,
+                  nbytes=nbytes, **extra) as sp:
+            yield handle
+            if handle.nbytes != nbytes:
+                sp.set(nbytes=handle.nbytes)
     finally:
         dt = time.perf_counter() - t0
         with _STATE.lock:
             _STATE.inflight.pop(tid, None)
-        GLOBAL_REGISTRY.observe_op(op, span.nbytes, dt)
-        record_event("op_end", op=op, nbytes=span.nbytes,
+        GLOBAL_REGISTRY.observe_op(op, handle.nbytes, dt)
+        record_event("op_end", op=op, nbytes=handle.nbytes,
                      cache_key=cache_key, seconds=round(dt, 6),
                      version=version, seqno=seqno, **extra)
 

@@ -50,6 +50,9 @@ KINDS: dict[str, str] = {
     "op_begin": "collective entered: op, nbytes, cache_key, version, seqno",
     "op_end": "collective completed: adds seconds; pairs with op_begin",
     "op_inflight": "dump-time marker: op stuck in flight, stuck_seconds",
+    # spans (obs.span; one event a span, drawn as a slice by trace.py)
+    "span": "a timed stretch ended: name, t0, seconds, parent, version, "
+            "+ the span's fields (nbytes, raw, encoded, codec, level...)",
     # engine lifecycle (api.py / engine bridge)
     "engine_ready": "init() complete: engine class, rank, world",
     "engine_init": "native bridge entering RabitInit",
@@ -84,6 +87,9 @@ KINDS: dict[str, str] = {
     "lease_expired": "heartbeat lease lapsed: task_id, rank, overdue",
     "snapshot_rejected": "CMD_METRICS snapshot with out-of-range rank",
     "metrics_snapshot": "CMD_METRICS snapshot accepted: rank, task_id",
+    # launcher (tracker/launcher.py; in LocalCluster.events/telemetry.json)
+    "worker_respawn": "a dead worker was restarted: task, attempt, died_at "
+                      "(poll() first returned), spawned_at (after Popen)",
     # live telemetry plane (rabit_tpu/obs/stream.py,
     # doc/observability.md "Live telemetry plane")
     "obs_scrape": "first CMD_OBS scrape served this tracker lifetime "

@@ -371,6 +371,7 @@ _TRACKER_INSTANTS = {
     "obs_scrape", "metrics_delta_folded",
     "incident_opened", "incident_resolved", "critical_path_folded",
     "snapshot_published", "snapshot_fetched", "blob_cache_evicted",
+    "worker_respawn",
 }
 
 
@@ -411,6 +412,9 @@ def build_chrome_trace(job: JobTrace) -> dict:
     all_ts: list[float] = []
     for rank, events in job.ranks.items():
         all_ts.extend(job.project(rank, e.ts) for e in events)
+        # a span's event is stamped at its end; its slice starts at t0
+        all_ts.extend(job.project(rank, float(e.fields["t0"]))
+                      for e in events if e.kind == "span" and "t0" in e.fields)
     if job.telemetry:
         all_ts.extend(float(e["ts"]) for e in
                       (job.telemetry.get("events") or []) if "ts" in e)
@@ -445,6 +449,24 @@ def build_chrome_trace(job: JobTrace) -> dict:
                 "ts": _us(span.begin + off, t_base),
                 "dur": round(max(span.end - span.begin, 0.0) * 1e6, 3),
                 "pid": rank, "tid": 0, "args": args,
+            })
+        # obs.span events: one slice each, on the rank's track.  A child
+        # lies inside its parent in time, so the viewer nests them; parents
+        # go first where two start on the same microsecond.
+        spans = sorted((ev for ev in events if ev.kind == "span"),
+                       key=lambda e: (e.fields.get("t0", e.ts),
+                                      -e.fields.get("seconds", 0.0)))
+        for ev in spans:
+            f = ev.fields
+            seconds = float(f.get("seconds") or 0.0)
+            out.append({
+                "name": str(f.get("name", "?")), "cat": "span", "ph": "X",
+                "ts": _us(float(f.get("t0", ev.ts - seconds)) + off, t_base),
+                "dur": round(max(seconds, 0.0) * 1e6, 3),
+                "pid": rank, "tid": 0,
+                "args": {k: v for k, v in f.items()
+                         if k not in ("name", "t0", "seconds")
+                         and v is not None},
             })
         # bootstrap spans: engine_init -> bootstrap_done, sequential per life
         init_ts: float | None = None

@@ -272,6 +272,7 @@ def route_margin_level(xb3, node3, margin3, feat, thr, leaf, *, depth: int,
             jax.ShapeDtypeStruct((nb, R, 1), jnp.int32),
         ],
         interpret=interpret,
+        name=f"route_margin_d{depth}",
     )(xb3, node3, margin3, featp, thrp, leafp)
 
 
@@ -297,6 +298,7 @@ def route_level(xb3, node3, feat, thr, *, depth: int, interpret: bool = False):
         out_specs=_blk(R, 1),
         out_shape=jax.ShapeDtypeStruct((nb, R, 1), jnp.int32),
         interpret=interpret,
+        name=f"route_level_d{depth}",
     )(xb3, node3, featp, thrp)
 
 
@@ -345,6 +347,7 @@ def hist_level0(xb3, g3, h3, *, n_bins: int, interpret: bool = False,
         out_specs=pl.BlockSpec((8, F * be), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((8, F * be), jnp.float32),
         interpret=interpret,
+        name="hist_level0",
     )(xb3, g3, h3)
     out = out.reshape(8, F, be)[..., :n_bins]
     return jnp.stack([out[0:1], out[1:2]], axis=-1)
@@ -390,6 +393,7 @@ def hist_level(xb3, node3, g3, h3, feat, thr, *, depth: int, n_bins: int,
             jax.ShapeDtypeStruct((nb, R, 1), jnp.int32),
         ],
         interpret=interpret,
+        name=f"hist_level_d{depth}",
     )(xb3, node3, g3, h3, featp, thrp)
     out = out.reshape(m_pad, F, be)[..., :n_bins]
     hist = jnp.stack([out[:n_nodes], out[n_nodes : 2 * n_nodes]], axis=-1)
@@ -428,6 +432,7 @@ def leaf_fit(xb3, node3, g3, h3, feat, thr, *, depth: int,
             jax.ShapeDtypeStruct((nb, R, 1), jnp.int32),
         ],
         interpret=interpret,
+        name="leaf_fit",
     )(xb3, node3, g3, h3, featp, thrp)
     gh = out[:, 0]
     return jnp.stack([gh[:n_leaves], gh[n_leaves : 2 * n_leaves]], axis=-1), node_out

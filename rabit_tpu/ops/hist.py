@@ -169,6 +169,7 @@ def node_histograms_pallas(xb, g, h, node, n_nodes: int, n_bins: int,
         out_specs=pl.BlockSpec((m_pad, F * be), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m_pad, F * be), jnp.float32),
         interpret=interpret,
+        name=f"node_histograms_n{n_nodes}",
     )(
         xb.reshape(nb, R, F),
         node.reshape(nb, R, 1),

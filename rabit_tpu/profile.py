@@ -1,4 +1,5 @@
-"""Tracing / profiling (SURVEY §5 aux subsystems) — legacy facade.
+"""Tracing / profiling (SURVEY §5 aux subsystems): the historical stats
+facade, and ``xla_trace``, the operator's way to take a profiler trace.
 
 The reference's observability is (a) ``rabit_debug=1`` per-op latency log
 lines (allreduce_robust.cc:214-217,289-294) and (b) the mock engine's
@@ -76,11 +77,22 @@ GLOBAL_STATS = CollectiveStats(registry=GLOBAL_REGISTRY)
 
 @contextlib.contextmanager
 def xla_trace(logdir: str):
-    """Capture an XLA device trace for TensorBoard/xprof — the TPU-native
-    replacement for hand-rolled per-link counters."""
+    """Take a profiler trace of the enclosed steps into ``logdir`` (open
+    ``<logdir>/plugins/profile/*/*.xplane.pb`` in TensorBoard/xprof, or
+    read it with ``jax.profiler.ProfileData``).  The program's own spans
+    (:func:`rabit_tpu.obs.span`: ``rabit.checkpoint``, ``rabit.spill.*``,
+    ``rabit.allreduce``, ``gbdt.cross``) land in it on the profiler's
+    clock, beside the device's operations.  The tracer levels are the ones
+    the benchmark's worker sets: ``host_tracer_level=2`` (the
+    TraceAnnotations and the runtime's own host events) and
+    ``python_tracer_level=0`` (no per-call Python events, which would
+    swamp a round)."""
     import jax
 
-    jax.profiler.start_trace(logdir)
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = 2
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(logdir, profiler_options=opts)
     try:
         yield
     finally:

@@ -24,6 +24,8 @@ import struct
 import zlib
 from pathlib import Path
 
+from rabit_tpu import obs
+
 _GLOBAL_RE = re.compile(r"^global_r(\d+)_v(\d+)\.bin$")
 _KEEP = 2  # two-phase commit skews live ranks by at most one version
 # (the default; rabit_checkpoint_keep raises it — a deeper window for
@@ -108,7 +110,10 @@ class CheckpointStore:
         if version not in self._versions:
             self._versions.append(version)
             self._versions.sort()
-        self._prune()
+        # its own span: unlinking the two files of the version that falls
+        # out of the window is milliseconds at 10 MB a file
+        with obs.span("rabit.spill.prune"):
+            self._prune()
 
     def pin(self, version: int) -> None:
         """Exempt ``version`` from pruning (and release every older
@@ -128,45 +133,46 @@ class CheckpointStore:
                 self._cache.pop(p, None)
 
     def _write(self, path: Path, blob: bytes, epoch: int = 0) -> None:
-        if epoch > 0:
-            # Elastic job: the frame carries the committing world epoch.
-            # Codec id 0 (identity) keeps the layout uniform when the
-            # store is configured uncompressed.
-            codec_id, payload = 0, blob
+        codec_id, payload = 0, blob
+        with obs.span("rabit.spill.encode", raw=len(blob),
+                      codec=self._codec.name if self._codec else "identity",
+                      ) as sp:
             if self._codec is not None:
                 from rabit_tpu.compress import observe
 
                 payload = self._codec.encode_bytes(blob)
                 observe(self._codec.name, raw=len(blob), wire=len(payload))
                 codec_id = self._codec.codec_id
-            header = _HDR3.pack(_MAGIC3, codec_id, zlib.crc32(payload),
-                                len(payload), epoch)
+            crc = zlib.crc32(payload)
+            sp.set(encoded=len(payload))
+        if epoch > 0:
+            # Elastic job: the frame carries the committing world epoch.
+            # Codec id 0 (identity) keeps the layout uniform when the
+            # store is configured uncompressed.
+            header = _HDR3.pack(_MAGIC3, codec_id, crc, len(payload), epoch)
         elif self._codec is None:
-            header, payload = _HDR.pack(_MAGIC, zlib.crc32(blob),
-                                        len(blob)), blob
+            header = _HDR.pack(_MAGIC, crc, len(blob))
         else:
-            from rabit_tpu.compress import observe
-
-            payload = self._codec.encode_bytes(blob)
-            observe(self._codec.name, raw=len(blob), wire=len(payload))
-            header = _HDR2.pack(_MAGIC2, self._codec.codec_id,
-                                zlib.crc32(payload), len(payload))
+            header = _HDR2.pack(_MAGIC2, codec_id, crc, len(payload))
         tmp = path.with_suffix(".tmp")
-        with open(tmp, "wb") as f:
-            f.write(header)
-            f.write(payload)
-            f.flush()
-            os.fsync(f.fileno())
-        os.replace(tmp, path)  # atomic: readers see old or new, never torn
+        with obs.span("rabit.spill.write",
+                      bytes=len(header) + len(payload)):
+            with open(tmp, "wb") as f:
+                f.write(header)
+                f.write(payload)
+                f.flush()
+                os.fsync(f.fileno())
+            os.replace(tmp, path)  # atomic: readers see old or new, never torn
         self._cache[path] = blob
         # The rename itself must survive a host crash too — fsync the
         # directory entry, or the "durable" newest version can vanish on
         # power loss while the prune of the older one persisted.
-        dfd = os.open(self.dir, os.O_RDONLY)
-        try:
-            os.fsync(dfd)
-        finally:
-            os.close(dfd)
+        with obs.span("rabit.spill.dirsync"):
+            dfd = os.open(self.dir, os.O_RDONLY)
+            try:
+                os.fsync(dfd)
+            finally:
+                os.close(dfd)
 
     # -- reads --------------------------------------------------------------
 
@@ -193,36 +199,13 @@ class CheckpointStore:
         unchanged."""
         if path in self._cache:
             return self._cache[path]
-        try:
-            raw = path.read_bytes()
-        except FileNotFoundError:
-            return None
-        blob: bytes | None = None
-        if len(raw) >= _HDR3.size and raw[:4] == _MAGIC3:
-            _magic, codec_id, crc, n, _epoch = _HDR3.unpack_from(raw)
-            enc = raw[_HDR3.size:]
-            if len(enc) == n and zlib.crc32(enc) == crc:
-                from rabit_tpu.compress import get_codec_by_id
-
-                try:
-                    blob = get_codec_by_id(codec_id).decode_bytes(enc)
-                except (ValueError, zlib.error):
-                    blob = None
-        elif len(raw) >= _HDR2.size and raw[:4] == _MAGIC2:
-            _magic, codec_id, crc, n = _HDR2.unpack_from(raw)
-            enc = raw[_HDR2.size:]
-            if len(enc) == n and zlib.crc32(enc) == crc:
-                from rabit_tpu.compress import get_codec_by_id
-
-                try:
-                    blob = get_codec_by_id(codec_id).decode_bytes(enc)
-                except (ValueError, zlib.error):
-                    blob = None  # unknown codec / stream the crc cannot vouch for
-        elif len(raw) >= _HDR.size and raw[:4] == _MAGIC:
-            magic, crc, n = _HDR.unpack_from(raw)
-            payload = raw[_HDR.size:]
-            if len(payload) == n and zlib.crc32(payload) == crc:
-                blob = payload
+        with obs.span("rabit.load.disk.read") as sp:
+            try:
+                frame = path.read_bytes()
+            except FileNotFoundError:
+                return None
+            blob = self._decode_frame(frame)
+            sp.set(encoded=len(frame), raw=0 if blob is None else len(blob))
         if blob is None:
             print(f"[rabit_tpu] checkpoint store: ignoring unreadable blob "
                   f"{path} (missing/invalid RTC1/RTC2 header or crc "
@@ -230,6 +213,32 @@ class CheckpointStore:
             return None
         self._cache[path] = blob
         return blob
+
+    @staticmethod
+    def _decode_frame(raw: bytes) -> bytes | None:
+        """The payload of one frame (any generation), decoded after its
+        crc has passed; None for a torn or unknown one."""
+        if len(raw) >= _HDR3.size and raw[:4] == _MAGIC3:
+            _magic, codec_id, crc, n, _epoch = _HDR3.unpack_from(raw)
+            enc = raw[_HDR3.size:]
+        elif len(raw) >= _HDR2.size and raw[:4] == _MAGIC2:
+            _magic, codec_id, crc, n = _HDR2.unpack_from(raw)
+            enc = raw[_HDR2.size:]
+        elif len(raw) >= _HDR.size and raw[:4] == _MAGIC:
+            _magic, crc, n = _HDR.unpack_from(raw)
+            codec_id, enc = 0, raw[_HDR.size:]
+        else:
+            return None
+        if len(enc) != n or zlib.crc32(enc) != crc:
+            return None
+        if raw[:4] == _MAGIC:
+            return enc
+        from rabit_tpu.compress import get_codec_by_id
+
+        try:
+            return get_codec_by_id(codec_id).decode_bytes(enc)
+        except (ValueError, zlib.error):
+            return None  # unknown codec / stream the crc cannot vouch for
 
     def epoch_of(self, version: int) -> int:
         """World epoch recorded in the version's global frame (RTC3), 0

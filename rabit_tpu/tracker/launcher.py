@@ -396,6 +396,7 @@ class LocalCluster:
                     else:
                         # Worker died: the reference tracker restarts it and
                         # peers recover (doc/guide.md:338-374).
+                        died_at = time.time()   # poll() first said so
                         self.returncodes[tid] = ret
                         if self.restarts[tid] >= self.max_restarts:
                             raise RuntimeError(
@@ -414,6 +415,17 @@ class LocalCluster:
                                 flush=True,
                             )
                         procs[tid] = self._spawn(cmd, tracker, tid)
+                        # kill -> noticed is death_times against the
+                        # worker's own stamp, noticed -> spawned is here,
+                        # spawned -> the new life's main is the worker's
+                        self.events.append({
+                            "ts": round(died_at, 6),
+                            "kind": "worker_respawn",
+                            "task": tid,
+                            "attempt": self.restarts[tid],
+                            "died_at": round(died_at, 6),
+                            "spawned_at": round(time.time(), 6),
+                        })
                         alive += 1
                 if alive == 0:
                     return 0
