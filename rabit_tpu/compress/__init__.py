@@ -14,7 +14,8 @@ every data-plane seam:
   one collective per group and two-plane codecs ride as planes of the
   same fused buffer;
 * ``store.CheckpointStore`` — a codec byte in the durable frame
-  (``rabit_checkpoint_compress``; old frames stay readable);
+  (``rabit_checkpoint_compress``, applied where a probe of the blob says
+  it pays; old frames stay readable);
 * ``api._disk_resume`` — peer-served recovery/bootstrap blobs cross the
   wire zlib-compressed.
 

@@ -82,8 +82,9 @@ DEFAULTS: dict[str, str] = {
     # rabit_compress_wire_deflate: lossless deflate stage on the host
     # transport's wire bytes (the in-graph XLA path ships raw planes).
     # rabit_compress_broadcast: byte codec (zlib) for api.broadcast
-    # payloads.  rabit_checkpoint_compress: codec byte of the durable
-    # store's frames (old frames stay readable; empty = uncompressed).
+    # payloads.  rabit_checkpoint_compress: the codec the durable store
+    # MAY apply, a frame at a time, where a probe of the blob says it
+    # pays (store.py; old frames stay readable; empty = uncompressed).
     "rabit_compress_allreduce": "",
     "rabit_compress_min_bytes": "1024",
     "rabit_compress_wire_deflate": "1",

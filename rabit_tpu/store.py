@@ -36,8 +36,10 @@ _KEEP = 2  # two-phase commit skews live ranks by at most one version
 # version or the holder-broadcast path instead of crashing on garbage.
 #
 # Three frame generations: RTC1 (uncompressed payload), RTC2, which adds
-# a codec byte (rabit_tpu.compress ids) so spilled blobs land compressed
-# (rabit_checkpoint_compress, default zlib), and RTC3, which additionally
+# a codec byte (rabit_tpu.compress ids) so spilled blobs can land compressed
+# (rabit_checkpoint_compress, default zlib: the codec the store MAY apply —
+# the byte is per frame, and a blob the probe below finds incompressible is
+# written raw under codec id 0), and RTC3, which additionally
 # records the WORLD EPOCH (rabit_tpu.elastic) the committing membership
 # generation held — so a resume can tell which world size produced each
 # version and replay stays deterministic across an elastic resize.  The
@@ -51,6 +53,31 @@ _MAGIC2 = b"RTC2"
 _HDR2 = struct.Struct("<4sBxxxII")  # magic, codec id, pad, crc, enc len
 _MAGIC3 = b"RTC3"
 _HDR3 = struct.Struct("<4sBxxxIII")  # ..., crc, enc len, world epoch
+
+# The probe: before a blob larger than _PROBE_BYTES is encoded, a sample of
+# it is — _PROBE_SLICES equal slices spread evenly over the blob, the first
+# and the last included, no RNG (the same blob gives the same frame) — and
+# the codec runs over the whole blob only where the sample's encoded/raw is
+# at most _PROBE_MAX_RATIO: the codec must take a quarter off.  A dense
+# float32 state does not pay: the flagship margin (10.5 MB) deflates to
+# 0.931 of itself at 30 ms/MB beside a disk that takes the frame at 1 ms/MB
+# (PERF.md, PR 25); its sample reads 0.93 once the rounds' sums have spread
+# over the mantissa, a sparse forest's 0.02, and the line sits well away
+# from both.  (A young margin, a sum of few leaf values, reads 0.3-0.7 and
+# is deflated: on the flagship job the first twelve commits; PERF.md, PR 26.)
+_PROBE_BYTES = 64 << 10
+_PROBE_SLICES = 16
+_PROBE_MAX_RATIO = 0.75
+
+
+def _probe_sample(blob: bytes) -> bytes:
+    """The probe's sample of a blob larger than ``_PROBE_BYTES``, cut
+    through a memoryview: the blob itself is not copied."""
+    view = memoryview(blob)
+    size = _PROBE_BYTES // _PROBE_SLICES
+    last = len(view) - size
+    starts = (i * last // (_PROBE_SLICES - 1) for i in range(_PROBE_SLICES))
+    return b"".join(view[a:a + size] for a in starts)
 
 
 class CheckpointStore:
@@ -132,19 +159,43 @@ class CheckpointStore:
                 p.unlink(missing_ok=True)
                 self._cache.pop(p, None)
 
-    def _write(self, path: Path, blob: bytes, epoch: int = 0) -> None:
-        codec_id, payload = 0, blob
-        with obs.span("rabit.spill.encode", raw=len(blob),
-                      codec=self._codec.name if self._codec else "identity",
-                      ) as sp:
-            if self._codec is not None:
-                from rabit_tpu.compress import observe
+    def _encode(self, blob: bytes) -> tuple[int, bytes, float | None]:
+        """``(codec id, payload, probe)``: the configured codec applied to
+        ``blob`` where the probe says it pays, else the blob as it is
+        under codec id 0.  ``probe`` is the encoded/raw ratio the decision
+        was taken on — a sample's, or the whole blob's where the blob is
+        no larger than the probe — and None with no codec configured."""
+        codec = self._codec
+        if codec is None:
+            return 0, blob, None
+        if len(blob) <= _PROBE_BYTES:
+            # no dearer than the probe: encode it whole, keep the smaller
+            payload = codec.encode_bytes(blob)
+            probe = len(payload) / max(len(blob), 1)
+            keep = len(payload) < len(blob)
+        else:
+            probe = len(codec.encode_bytes(_probe_sample(blob))) / _PROBE_BYTES
+            keep = probe <= _PROBE_MAX_RATIO
+            payload = codec.encode_bytes(blob) if keep else blob
+        if not keep:
+            return 0, blob, probe
+        from rabit_tpu.compress import observe
 
-                payload = self._codec.encode_bytes(blob)
-                observe(self._codec.name, raw=len(blob), wire=len(payload))
-                codec_id = self._codec.codec_id
+        observe(codec.name, raw=len(blob), wire=len(payload))
+        return codec.codec_id, payload, probe
+
+    def _write(self, path: Path, blob: bytes, epoch: int = 0) -> None:
+        with obs.span("rabit.spill.encode", raw=len(blob)) as sp:
+            codec_id, payload, probe = self._encode(blob)
             crc = zlib.crc32(payload)
-            sp.set(encoded=len(payload))
+            # the codec actually applied to this frame, and what decided it
+            sp.set(encoded=len(payload),
+                   codec=self._codec.name if codec_id else "identity")
+            if probe is not None:
+                sp.set(probe=round(probe, 4))
+        obs.get_registry().counter(
+            "spill_frames_encoded_total" if codec_id
+            else "spill_frames_raw_total").inc()
         if epoch > 0:
             # Elastic job: the frame carries the committing world epoch.
             # Codec id 0 (identity) keeps the layout uniform when the

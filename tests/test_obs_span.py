@@ -178,7 +178,11 @@ def test_checkpoint_fields_and_parents(ring, tmp_path):
     enc = by["rabit.spill.encode"]
     assert enc[0]["raw"] == top["nbytes_global"]
     assert enc[1]["raw"] == top["nbytes_local"]
-    assert all(0 < f["encoded"] and f["codec"] == "zlib" for f in enc)
+    # both are under the probe's size and both come out smaller: the codec
+    # named is the one applied, the probe is the whole blob's ratio
+    assert all(0 < f["encoded"] < f["raw"] and f["codec"] == "zlib"
+               and f["probe"] == round(f["encoded"] / f["raw"], 4) for f in enc)
+    assert enc[1]["probe"] < 0.05
     assert by["rabit.spill.write"][1]["bytes"] > enc[1]["encoded"]
     second = [f for f in spans_of(ring, "rabit.spill.encode")
               if f["version"] == 2]
@@ -238,6 +242,7 @@ def test_spans_land_in_the_profilers_trace(tmp_path):
     assert len(found["rabit.spill.encode"]) == 2
     enc = found["rabit.spill.encode"][1][2]
     assert enc["raw"] > 80000 and 0 < enc["encoded"] and enc["codec"] == "zlib"
+    assert 0 < enc["probe"] < 0.75          # set inside the window, a stat all the same
     assert found["rabit.spill.write"][1][2]["bytes"] > enc["encoded"]
     stats = found["rabit.allreduce"][0][2]
     assert stats["nbytes"] == 32 and stats["seqno"] == 0
