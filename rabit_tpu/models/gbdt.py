@@ -59,11 +59,13 @@ class GBDTConfig(NamedTuple):
     # Final leaf pass of train_round_fused: True runs the fused Pallas
     # route+margin kernel (ops/boost.py route_margin_level); False runs
     # the routing-only kernel and leaves ``margin += leaf[node]`` to XLA
-    # (a 1M-row gather from a 2**depth-entry table).  Both are exact.
-    # Older chip figures, not re-measured (RESULTS/final_pass.jsonl):
-    # XLA-final won whole-round in both MXU modes (73.8 vs 78.1 ms bf16,
-    # 77.3 vs 78.7 ms i8), so False is the default and the fused kernel
-    # stays as the challenger bench.py re-races.
+    # (a gather of every row from a 2**depth-entry table).  Both are exact.
+    # Figures of an older chip at 1,000,000 rows x 28, depth 6, not
+    # re-measured (RESULTS/final_pass.jsonl): XLA-final won whole-round in
+    # both MXU modes (73.8 vs 78.1 ms bf16, 77.3 vs 78.7 ms i8), so False is
+    # the default and the fused kernel stays as the challenger bench.py
+    # re-races.  The benchmark's cells (PERF.md section 5) run 2.6M rows;
+    # at depth 8 the XLA gather is 21.5 ms of a round.
     fused_final: bool = False
     # Split each row block into this many independent sub-contractions in
     # the level kernels' histogram accumulation (ops/boost.py _accum):
@@ -313,7 +315,10 @@ def train_round_fused(
     """One boosting round via the fused Pallas kernels (ops.boost): routing,
     split lookup, and histogram accumulation run in one streaming pass per
     level, so rows cross HBM depth+1 times per round (depth histogram
-    passes + one routing-only leaf pass) instead of ~3x depth.
+    passes + one routing-only leaf pass) instead of ~3x depth.  When the
+    round is lowered, each level leaves one ``gbdt.hist_plan`` span with
+    what ``ops.boost.hist_plan`` reckoned for its kernel, and the gauge
+    ``gbdt_hist_rows_streamed_per_round`` takes rows x passes.
 
     ``xb3`` is the pre-blocked quantized matrix from ``ops.boost.block_rows``
     (built once per fit).  ``combine`` is the histogram allreduce hook
@@ -348,7 +353,12 @@ def train_round_fused(
     thrs = [jnp.zeros(max_nodes, jnp.int32).at[:1].set(thr)]
     node3 = jnp.zeros_like(g3, shape=g3.shape, dtype=jnp.int32)
     for d in range(1, cfg.depth):
-        with jax.named_scope(f"level{d}"):
+        plan = boost.hist_plan(xb3.shape[2], cfg.n_bins, d, block)
+        with jax.named_scope(f"level{d}"), obs.span(
+                "gbdt.hist_plan", level=d, nodes_built=plan.nodes_built,
+                m_rows=plan.m_rows, m_tiles=plan.m_tiles,
+                acc_block_bytes=plan.acc_block_bytes,
+                vmem_bytes=plan.vmem_bytes):
             hist, node3 = boost.hist_level(xb3, node3, g3, h3, feat, thr,
                                            depth=d, n_bins=cfg.n_bins,
                                            interpret=interpret,
@@ -358,6 +368,9 @@ def train_round_fused(
             feat, thr, _ = best_splits(hist, cfg)
         feats.append(jnp.zeros(max_nodes, jnp.int32).at[: 2 ** d].set(feat))
         thrs.append(jnp.zeros(max_nodes, jnp.int32).at[: 2 ** d].set(thr))
+    # the root's pass, one a level, the leaves' routing pass
+    obs.get_registry().gauge("gbdt_hist_rows_streamed_per_round").set(
+        (cfg.depth + 1) * xb3.shape[0] * block)
     # Leaf (g, h) masses come straight off the final combined histogram
     # (split_child_masses) — already globally reduced, so no leaf collective
     # and no histogram work in the last row pass (depth collectives per

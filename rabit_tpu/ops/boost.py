@@ -18,6 +18,12 @@ row block's gradient matrix L (one g column + one h column per node) is
 contracted against per-feature bin indicators built in VMEM; f32 gradients
 are split hi/lo into two bfloat16 matmuls (error ~2^-16-relative).
 
+``hist_plan`` reckons what a level asks of the chip from the shape alone:
+the accumulator ``(2 * 2**level, F * B_eff)`` float32 is ONE block held
+across the row grid, and the plan asks Mosaic for the scoped VMEM that
+block and the row blocks take where they pass its default, or refuses the
+shape by name where they pass what the kernel may ask for.
+
 All wrappers take pre-blocked arrays (nb, R, ...) so padding/reshaping
 happens once per fit, not once per level.  ``interpret=True`` runs the
 kernels in the Pallas interpreter, which is how the CPU test suite checks
@@ -27,11 +33,13 @@ them against the reference ``train_round`` (tests/test_gbdt.py).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _DN = (((0,), (0,)), ((), ()))  # contract dim 0 vs dim 0
 
@@ -57,14 +65,74 @@ def _pick_fc(n_feat: int, n_bins: int) -> int:
     return min(n_feat, max(1, 1792 // _bins_eff(n_bins)))
 
 
+#: rows of one MXU tile: the stacked gradient matrix (hi and lo plane, g and
+#: h, a node: 4 * 2**level rows) fills it at level 5
+MXU_ROWS = 128
+#: scoped VMEM Mosaic gives one kernel on the v5e unless asked for more
+VMEM_DEFAULT = 16 << 20
+#: the most ``hist_level`` asks for: half of the v5e's 128 MiB
+VMEM_MOST = 64 << 20
+#: room for the kernel's own stack beyond its blocks: twice the most the
+#: compiler has reported (3.0 MiB at level 7, 4.0 at level 8 of F = 67, and
+#: under 2.6 wherever the default limit held a kernel; PR 27)
+VMEM_STACK = 8 << 20
+
+
+class HistPlan(NamedTuple):
+    """What one level's ``hist_level`` asks of the chip."""
+
+    level: int
+    m_pad: int            # accumulator rows: g and h of every node
+    acc_block_bytes: int  # the one (m_pad, F * B_eff) float32 block
+    vmem_bytes: int       # that block, the row blocks twice, VMEM_STACK
+
+    @property
+    def nodes_built(self) -> int:
+        return 2 ** self.level
+
+    @property
+    def m_rows(self) -> int:
+        """Rows of the stacked (two-plane) gradient matrix."""
+        return 2 * self.m_pad
+
+    @property
+    def m_tiles(self) -> int:
+        return -(-self.m_rows // MXU_ROWS)
+
+
+def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int) -> HistPlan:
+    """A level's accumulator block and the scoped VMEM its kernel takes,
+    from the shape alone; ValueError for a shape the kernel cannot hold.
+
+    ``vmem_bytes`` bounds what Mosaic calls the kernel's scoped allocation:
+    the accumulator once (its block index never changes along the row grid),
+    the row blocks twice — codes at 128 lanes, node in and out, g and h at
+    one lane padded to 128 — and ``VMEM_STACK``.  (F = 67, level 7: 16.75 +
+    5 MiB of blocks, Mosaic's own "21.75M", 24.75 with its stack.)  The MXU
+    walks M a 128-row tile at a time by itself."""
+    m_pad = _round_up(2 * 2 ** level, 8)
+    acc = m_pad * n_feat * _bins_eff(n_bins) * 4
+    need = (acc + 2 * 4 * block_rows * (_round_up(n_feat, 128) + 4 * 128)
+            + VMEM_STACK)
+    if need > VMEM_MOST:
+        raise ValueError(
+            f"hist_plan: level {level} of F={n_feat} features x {n_bins} bins "
+            f"at {block_rows}-row blocks does not fit: its accumulator block "
+            f"is {acc} bytes and the kernel needs {need} of {VMEM_MOST} bytes "
+            f"of VMEM (a depth of {level + 1} is one level too many)")
+    return HistPlan(level, m_pad, acc, need)
+
+
 def _encode_bf16(L):
     """Hi/lo-bf16 split of the f32 gradient matrix (~2^-16-relative error).
 
     The two halves share ONE matmul, stacked along M: the MXU pads M to a
-    full 128-row tile anyway, and m_pad <= 64 for depth <= 6, so two
-    separate matmuls each waste >= half the tile — packing them halves the
-    level's MXU passes (measured ~1.4x whole-round).  The result splits
-    back and sums in f32, bitwise identical to the two-matmul form."""
+    full 128-row tile anyway, and the stack fills one at level 5 (4 * 32
+    rows), so up to there two separate matmuls each waste >= half the tile
+    — packing them halves the level's MXU passes (~1.4x whole-round at
+    1,000,000 rows x 28 on an older chip).  Level 6 stacks two tiles and
+    level 7 four (``HistPlan.m_tiles``).  The result splits back and sums
+    in f32, bitwise identical to the two-matmul form."""
     lhi = L.astype(jnp.bfloat16)
     llo = (L - lhi.astype(jnp.float32)).astype(jnp.bfloat16)
     l2 = jnp.concatenate([lhi, llo], axis=1)
@@ -127,7 +195,8 @@ def _accum(xb_blk, L, out_ref, *, n_bins: int, n_feat: int, fc: int, i8: bool,
     rs = r // r_split
     # The indicator compare runs at i32 lane width BY TARGET CONSTRAINT,
     # not choice: narrow codes (int8 4/lane, bf16 2/lane) would cut the
-    # co-dominant ~3.7 ms/level VPU rebuild 2-4x, but the chip's Mosaic
+    # co-dominant VPU rebuild (~3.7 ms/level at 1,000,000 rows x 28, an
+    # older chip's figure) 2-4x, but the chip's Mosaic
     # rejects sub-32-bit vector compares — "Target does not support this
     # comparison" on vector<...xi8> cmpi AND vector<...xbf16> cmpf
     # (RESULTS/narrow_compare_rejection.txt; the local jax.export gate
@@ -230,8 +299,9 @@ def _route_margin_kernel(xb_ref, node_ref, margin_ref, feat_ref, thr_ref,
                   p_pad=p_pad, n_feat=n_feat)
     node_out_ref[0] = node
     # margin += leaf[node] without a gather: the leaf table is tiny (64
-    # entries at depth 6), so the same lane-masked reduction as _route's
-    # split lookup replaces XLA's slow 1M-row gather from a small table.
+    # entries at depth 6, 256 at depth 8), so the same lane-masked
+    # reduction as _route's split lookup replaces XLA's gather of every row
+    # from a small table.
     r = node.shape[0]
     l_iota = lax.broadcasted_iota(jnp.int32, (r, l_pad), 1)
     lv = jnp.sum(jnp.where(node == l_iota, leaf_ref[0:1], 0.0), axis=1,
@@ -245,9 +315,9 @@ def route_margin_level(xb3, node3, margin3, feat, thr, leaf, *, depth: int,
     """Final fused pass: route rows through the level-(depth-1) split tables
     to their leaves AND apply the margin update ``margin += leaf[node]`` in
     the same streaming pass.  Returns (margin3', leaf_node3).  Replaces
-    route_level + a host-level gather: XLA lowers a 1M-row gather from a
-    64-entry table poorly on TPU, while the in-kernel lane-masked sum is a
-    few VPU ops per row."""
+    route_level + a host-level gather: XLA lowers a gather of every row
+    from a 2**depth-entry table poorly on TPU, while the in-kernel
+    lane-masked sum is a few VPU ops per row."""
     nb, R, F = xb3.shape
     n_prev = 2 ** (depth - 1)
     n_leaves = 2 ** depth
@@ -362,13 +432,18 @@ def hist_level(xb3, node3, g3, h3, feat, thr, *, depth: int, n_bins: int,
                r_split: int = 1):
     """Route one level down and histogram; returns
     ([2**depth, F, B, 2], node3').  ``feat``/``thr`` are the level-(depth-1)
-    split tables, shape [2**(depth-1)].  ``r_split``: see _accum."""
+    split tables, shape [2**(depth-1)].  ``r_split``: see _accum.  The
+    kernel asks for ``hist_plan``'s scoped VMEM where Mosaic's default might
+    not hold it (F = 67 from level 5 on), and is the same kernel elsewhere.
+    Compiled on its own, level 7 at F = 67 is refused at the default, on the
+    chip too; inside the whole round XLA keeps the accumulator in VMEM
+    itself and the default holds, and asking costs the round nothing (PR 27)."""
     nb, R, F = xb3.shape
     _check_r_split(R, r_split)
+    plan = hist_plan(F, n_bins, depth, R)
     be = _bins_eff(n_bins)
-    n_nodes = 2 ** depth
+    n_nodes, m_pad = plan.nodes_built, plan.m_pad
     n_prev = 2 ** (depth - 1)
-    m_pad = _round_up(2 * n_nodes, 8)
     p_pad = _round_up(n_prev, 128)
     fc = _pick_fc(F, n_bins)
     featp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(feat)
@@ -394,6 +469,9 @@ def hist_level(xb3, node3, g3, h3, feat, thr, *, depth: int, n_bins: int,
         ],
         interpret=interpret,
         name=f"hist_level_d{depth}",
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=plan.vmem_bytes,
+        ) if plan.vmem_bytes > VMEM_DEFAULT else None,
     )(xb3, node3, g3, h3, featp, thrp)
     out = out.reshape(m_pad, F, be)[..., :n_bins]
     hist = jnp.stack([out[:n_nodes], out[n_nodes : 2 * n_nodes]], axis=-1)

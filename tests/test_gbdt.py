@@ -157,8 +157,32 @@ def test_sharded_training_matches_single(use_fp):
     np.testing.assert_allclose(np.asarray(sstate.margin), ref_margin, rtol=1e-4)
 
 
+def _bench_like(rng, n, f, bins):
+    """Bin codes uniform, the label from two features plus noise: what
+    benchmark/harness/data.py draws."""
+    xb = rng.randint(0, bins, size=(n, f))
+    y = ((xb[:, 0] > bins // 2) + 2.56 / bins * xb[:, 1] + rng.randn(n)
+         > 1.5).astype(np.float32)
+    return jnp.asarray(xb, jnp.int32), jnp.asarray(y)
+
+
+#: (n, features, bins, depth, row block, rounds, eta).  "criteo" is the
+#: shape of benchmark/configs/criteo-1tb-share.json on three row blocks:
+#: 17,152 histogram lanes, 128 parents at the last level, 256 leaves.  It
+#: runs ONE round: from a zero margin g is +-0.5 and h 0.25, every sum is
+#: exact in float32 in both paths, and the splits must be equal node for
+#: node.  From a warm margin candidate splits that part a node's rows alike
+#: tie exactly in real numbers and each path's rounding picks its own:
+#: test_fused_round_from_a_warm_margin_follows_float64 covers those rounds.
+FUSED_SHAPES = {
+    "small": (600, 5, 16, 3, 256, 3, 0.3),
+    "criteo": (2500, 67, 256, 8, 1024, 1, 0.1),
+}
+
+
+@pytest.mark.parametrize("shape", FUSED_SHAPES)
 @pytest.mark.parametrize("fused_final", [True, False])
-def test_train_round_fused_matches_reference(fused_final):
+def test_train_round_fused_matches_reference(fused_final, shape):
     """The fused Pallas round (ops.boost, run via the Pallas interpreter on
     CPU) must grow the exact same trees as the hook-based train_round —
     with either final leaf pass (fused route+margin kernel, or routing
@@ -166,12 +190,16 @@ def test_train_round_fused_matches_reference(fused_final):
     from rabit_tpu.ops import boost
 
     rng = np.random.RandomState(3)
-    n, f = 600, 5
-    cfg = gbdt.GBDTConfig(n_features=f, n_trees=3, depth=3, n_bins=16,
+    n, f, bins, depth, block, rounds, eta = FUSED_SHAPES[shape]
+    cfg = gbdt.GBDTConfig(n_features=f, n_trees=rounds, depth=depth,
+                          n_bins=bins, learning_rate=eta,
                           fused_final=fused_final)
-    xb = jnp.asarray(rng.randint(0, cfg.n_bins, size=(n, f)), jnp.int32)
-    y = jnp.asarray(rng.randint(0, 2, size=n), jnp.float32)
-    xb3, _ = boost.block_rows(xb, 256)
+    if shape == "small":
+        xb = jnp.asarray(rng.randint(0, cfg.n_bins, size=(n, f)), jnp.int32)
+        y = jnp.asarray(rng.randint(0, 2, size=n), jnp.float32)
+    else:
+        xb, y = _bench_like(rng, n, f, bins)
+    xb3, _ = boost.block_rows(xb, block)
 
     ref_step = jax.jit(functools.partial(gbdt.train_round, cfg=cfg))
     fused_step = functools.partial(gbdt.train_round_fused, cfg=cfg, interpret=True)
@@ -185,11 +213,197 @@ def test_train_round_fused_matches_reference(fused_final):
     ff = jax.tree.map(np.asarray, s_f.forest)
     np.testing.assert_array_equal(ff.feature, fr.feature)
     np.testing.assert_array_equal(ff.threshold, fr.threshold)
+    assert len(np.unique(ff.feature[0, depth - 1])) > 1   # the last level split
     # hi/lo-bf16 leaf sums carry ~2^-16-relative error vs the exact-f32 path
     np.testing.assert_allclose(ff.leaf, fr.leaf, rtol=1e-3, atol=1e-5)
     np.testing.assert_allclose(
         np.asarray(s_f.margin), np.asarray(s_ref.margin), rtol=1e-3, atol=1e-5
     )
+
+
+def _follow_round(cfg, xb, g, h, feature, threshold):
+    """One round's tree followed in float64 on the same gradients: the
+    widest (best gain - gain of the split taken) / children score over all
+    nodes, the leaves the tree's own rows give, and each row's leaf."""
+    xb = np.asarray(xb)
+    (n, F), B = xb.shape, cfg.n_bins
+    g, h = np.asarray(g, np.float64), np.asarray(h, np.float64)
+    score = lambda a, b: a * a / (b + cfg.reg_lambda)
+    node, gap = np.zeros(n, np.int64), 0.0
+    for d in range(cfg.depth):
+        k, at = 2 ** d, np.arange(2 ** d)
+        hg, hh = (np.stack([np.bincount(node * B + xb[:, f], weights=w,
+                                        minlength=k * B).reshape(k, B)
+                            for f in range(F)], 1) for w in (g, h))
+        GL, HL = np.cumsum(hg, -1), np.cumsum(hh, -1)     # [k, F, B]
+        G, H = GL[..., -1:], HL[..., -1:]
+        child = score(GL, HL) + score(G - GL, H - HL)
+        valid = (HL >= cfg.min_child_weight) & (H - HL >= cfg.min_child_weight)
+        gain = np.where(valid, child - score(G, H), -np.inf).reshape(k, -1)
+        best = gain.argmax(-1)
+        ft, th = feature[d, :k].astype(np.int64), threshold[d, :k].astype(np.int64)
+        live = np.isfinite(gain[at, best])
+        with np.errstate(invalid="ignore"):               # -inf less -inf
+            short = np.where(live, gain[at, best] - gain[at, ft * B + th], 0.0)
+        scale = np.where(live, child.reshape(k, -1)[at, best], 1.0)
+        gap = max(gap, float(np.max(short / scale)))
+        node = 2 * node + (xb[np.arange(n), ft[node]] > th[node])
+    k = 2 ** cfg.depth
+    leaf = -cfg.learning_rate * np.bincount(node, weights=g, minlength=k) / (
+        np.bincount(node, weights=h, minlength=k) + cfg.reg_lambda)
+    return gap, leaf, node
+
+
+@pytest.mark.parametrize("f,depth", [(28, 6), (67, 8)], ids=["higgs", "criteo"])
+def test_fused_round_from_a_warm_margin_follows_float64(f, depth):
+    """Three rounds on sixteen row blocks, so the second and third start
+    from a margin that is not zero and no sum is exact: every split the
+    fused round takes is the float64 scatter histogram's best along the
+    same tree (an exact tie apart), and its leaves and margin are that
+    tree's to the hi/lo-bf16 error.  Readings at this seed: gain gap 0.0,
+    leaves 2.7e-6 of their rms, margin 9.4e-7."""
+    from rabit_tpu.ops import boost
+
+    rng = np.random.RandomState(3)
+    n, bins, rounds = 16384, 256, 3
+    xb, y = _bench_like(rng, n, f, bins)
+    cfg = gbdt.GBDTConfig(n_features=f, n_trees=rounds, depth=depth,
+                          n_bins=bins, learning_rate=0.1)
+    xb3, _ = boost.block_rows(xb, 1024)
+    step = jax.jit(functools.partial(gbdt.train_round_fused, cfg=cfg,
+                                     interpret=True))
+    s = gbdt.init_state(cfg, n)
+    for r in range(rounds):
+        before = np.asarray(s.margin, np.float64)
+        g, h = gbdt.gradients(cfg, s.margin, y)
+        s = step(s, xb3, y)
+        forest = jax.tree.map(np.asarray, s.forest)
+        gap, leaf, node = _follow_round(cfg, xb, g, h, forest.feature[r],
+                                        forest.threshold[r])
+        assert gap <= 1e-6
+        assert np.sqrt(np.mean((forest.leaf[r] - leaf) ** 2)) <= \
+            2e-5 * np.sqrt(np.mean(leaf ** 2))
+        np.testing.assert_allclose(np.asarray(s.margin), before + leaf[node],
+                                   rtol=0, atol=1e-5)
+    assert np.abs(before).max() > 0.05      # the last round started warm
+
+
+def test_fused_round_at_the_higgs_shape_is_bitwise_what_it_was():
+    """F = 28, 256 bins, depth 6: ``hist_plan`` asks nothing of Mosaic that
+    it did not give before, so two rounds give the bytes they gave before
+    the plan existed (sha256 recorded on the parent of PR 27, same seed)."""
+    import hashlib
+
+    from rabit_tpu.ops import boost
+
+    rng = np.random.RandomState(7)
+    n, f, bins = 2500, 28, 256
+    cfg = gbdt.GBDTConfig(n_features=f, n_trees=2, depth=6, n_bins=bins)
+    xb = jnp.asarray(rng.randint(0, bins, size=(n, f)), jnp.int32)
+    y = jnp.asarray(rng.randint(0, 2, size=n), jnp.float32)
+    xb3, _ = boost.block_rows(xb, 1024)
+    step = jax.jit(functools.partial(gbdt.train_round_fused, cfg=cfg,
+                                     interpret=True))
+    s = gbdt.init_state(cfg, n)
+    for _ in range(2):
+        s = step(s, xb3, y)
+    h = hashlib.sha256()
+    for a in (*s.forest, s.margin):
+        h.update(np.ascontiguousarray(np.asarray(a)).tobytes())
+    assert h.hexdigest() == (
+        "f6334d571d86ccc31f3b9a2cc1f3c75cbb16f0716cc8591335cdf20565689d2c")
+
+
+def test_hist_plan():
+    """The plan alone.  HIGGS (F 28, depth 6): today's fc and m_pad, every
+    level inside Mosaic's default scoped VMEM, so no kernel asks for more.
+    Criteo (F 67, depth 8): one accumulator block a level, 16.75 MiB at
+    level 7 in four MXU tiles of M, every kernel inside what it may ask
+    for.  Level 9 is refused by name."""
+    from rabit_tpu.ops import boost
+
+    assert boost._pick_fc(28, 256) == 7
+    for d in range(1, 6):
+        p = boost.hist_plan(28, 256, d, 1024)
+        assert p.nodes_built == 2 ** d and p.m_pad == max(8, 2 * 2 ** d)
+        assert p.acc_block_bytes == p.m_pad * 28 * 256 * 4
+        assert p.vmem_bytes <= boost.VMEM_DEFAULT
+    assert boost.hist_plan(28, 256, 5, 1024).m_rows == boost.MXU_ROWS
+
+    want = {5: (32, 64, 1), 6: (64, 128, 2), 7: (128, 256, 4)}
+    for d in range(1, 8):
+        p = boost.hist_plan(67, 256, d, 1024)
+        assert p.acc_block_bytes == p.m_pad * 67 * 256 * 4
+        assert p.acc_block_bytes + (5 << 20) < p.vmem_bytes <= boost.VMEM_MOST
+        if d in want:
+            assert (p.nodes_built, p.m_pad, p.m_tiles) == want[d]
+    assert boost.hist_plan(67, 256, 7, 1024).vmem_bytes > boost.VMEM_DEFAULT
+
+    with pytest.raises(ValueError, match=r"level 9 of F=67 .*bytes") as e:
+        boost.hist_plan(67, 256, 9, 1024)
+    assert str(boost.VMEM_MOST) in str(e.value)
+
+
+@pytest.mark.parametrize("d", [6, 7])
+def test_hist_level_at_the_criteo_width_matches_scatter(d):
+    """Levels 6 and 7 at 67 features x 256 bins: 256 and 512 stacked rows
+    of gradient matrix (two and four MXU tiles), one accumulator block of
+    17,152 lanes.  The kernel routes as the tables say and its histogram
+    is the scatter reference's at the routed nodes."""
+    from rabit_tpu.ops import boost, hist as H
+
+    rng = np.random.RandomState(13 + d)
+    n, F, B, block = 2048, 67, 256, 1024
+    n_prev = 1 << (d - 1)
+    xb = jnp.asarray(rng.randint(0, B, size=(n, F)), jnp.int32)
+    g = jnp.asarray(rng.randn(n), jnp.float32)
+    h = jnp.asarray(rng.rand(n), jnp.float32)
+    node = jnp.asarray(rng.randint(0, n_prev, size=n), jnp.int32)
+    feat = jnp.asarray(rng.randint(0, F, size=n_prev), jnp.int32)
+    thr = jnp.asarray(rng.randint(0, B, size=n_prev), jnp.int32)
+    blocked = [boost.block_rows(a, block)[0] for a in (xb, node, g, h)]
+    hist, node_out = boost.hist_level(*blocked, feat, thr, depth=d, n_bins=B,
+                                      interpret=True)
+    assert hist.shape == (2 * n_prev, F, B, 2)
+    routed = boost.unblock_rows(node_out, n)
+    went_right = np.asarray(xb)[np.arange(n), np.asarray(feat)[node]] > \
+        np.asarray(thr)[node]
+    np.testing.assert_array_equal(np.asarray(routed),
+                                  2 * np.asarray(node) + went_right)
+    ref = H.node_histograms_scatter(xb, g, h, routed, 2 * n_prev, B)
+    np.testing.assert_allclose(np.asarray(hist), np.asarray(ref),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_hist_plan_span_and_rows_streamed_gauge():
+    """Lowering the round at the Criteo shape leaves one ``gbdt.hist_plan``
+    span a level with what the plan reckoned, and the gauge takes rows x
+    passes over the row grid: depth histogram passes and the leaves'."""
+    import time
+
+    from rabit_tpu import obs
+    from rabit_tpu.ops import boost
+
+    n, F, depth, block = 2048, 67, 8, 1024
+    cfg = gbdt.GBDTConfig(n_features=F, n_trees=1, depth=depth, n_bins=256)
+    t0 = time.time()
+    jax.jit(functools.partial(gbdt.train_round_fused, cfg=cfg, interpret=True)
+            ).lower(gbdt.init_state(cfg, n),
+                    jnp.zeros((n // block, block, F), jnp.int32),
+                    jnp.zeros(n, jnp.float32))
+    gauge = obs.get_registry().gauge("gbdt_hist_rows_streamed_per_round")
+    assert gauge.value == (depth + 1) * n
+    spans = [e.fields for e in obs.get_recorder().snapshot()
+             if e.ts >= t0 and e.kind == "span"
+             and e.fields.get("name") == "gbdt.hist_plan"]
+    assert [s["level"] for s in spans] == list(range(1, depth))
+    for s in spans:
+        plan = boost.hist_plan(F, 256, s["level"], block)
+        assert (s["nodes_built"], s["m_rows"], s["m_tiles"],
+                s["acc_block_bytes"], s["vmem_bytes"]) == (
+            plan.nodes_built, plan.m_rows, plan.m_tiles,
+            plan.acc_block_bytes, plan.vmem_bytes)
+    assert spans[-1]["nodes_built"] == 128 and spans[-1]["m_tiles"] == 4
 
 
 def test_train_round_fused_i8_matches_reference():

@@ -55,6 +55,15 @@ def test_fused_level_kernels_lower(i8):
             functools.partial(boost.hist_level, depth=d, n_bins=B, mxu_i8=i8),
             xb3, node3, g3, h3, tab, tab,
         )
+    # the Criteo width (67 features, 17,152 lanes) at the two levels whose
+    # gradient matrix passes one MXU tile; level 7 asks for its VMEM
+    xc3 = jnp.zeros((NB, R, 67), jnp.int32)
+    for d in (6, 7):
+        tab = jnp.zeros(1 << (d - 1), jnp.int32)
+        export_tpu(
+            functools.partial(boost.hist_level, depth=d, n_bins=B, mxu_i8=i8),
+            xc3, node3, g3, h3, tab, tab,
+        )
     # The r_split overlap experiment must lower before anyone spends chip
     # time measuring it (the exact failure mode this file exists for).
     tab = jnp.zeros(1 << 4, jnp.int32)
@@ -170,8 +179,8 @@ def _compile(fn, *shapes):
     return compiled
 
 
-def _blocked(sh, nb=NB_REAL):
-    xb3 = _sds((nb, R, F), jnp.int32, sh)
+def _blocked(sh, nb=NB_REAL, f=F):
+    xb3 = _sds((nb, R, f), jnp.int32, sh)
     g3 = _sds((nb, R, 1), jnp.float32, sh)
     node3 = _sds((nb, R, 1), jnp.int32, sh)
     return xb3, g3, node3
@@ -193,6 +202,30 @@ def test_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d, i8):
     assert "tpu_custom_call" in c.as_text()
 
 
+# benchmark/configs/criteo-1tb-share.json: 2,621,440 rows x 67 features,
+# depth 8.  Levels 6 and 7 are where the stacked gradient matrix passes one
+# MXU tile, and level 7's accumulator block alone (16.75 MiB) passes
+# Mosaic's default scoped VMEM.
+NB_CRITEO, F_CRITEO = 2560, 67
+
+
+@pytest.mark.parametrize("d", (5, 6, 7, 8))
+def test_criteo_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d):
+    """What ``hist_plan`` lets through, the chip's compiler takes, with the
+    scoped VMEM the plan asks for: level 7, which the default refuses for
+    the kernel on its own, here and on the chip alike ("Scoped allocation
+    with size 21.75M and limit 16.00M"; PR 27), and level 8, the deepest
+    the plan lets through at this width (527.8 ms on the chip)."""
+    plan = boost.hist_plan(F_CRITEO, B, d, R)
+    xb3, g3, node3 = _blocked(one_chip, NB_CRITEO, F_CRITEO)
+    tab = _sds((1 << (d - 1),), jnp.int32, one_chip)
+    c = _compile(functools.partial(boost.hist_level, depth=d, n_bins=B),
+                 xb3, node3, g3, g3, tab, tab)
+    text = c.as_text()
+    assert "tpu_custom_call" in text
+    assert (str(plan.vmem_bytes) in text) == (plan.vmem_bytes > boost.VMEM_DEFAULT)
+
+
 def test_route_level_compiles_for_v5e(one_chip, no_compile_cache):
     xb3, _g3, node3 = _blocked(one_chip)
     tab = _sds((1 << (DEPTH - 1),), jnp.int32, one_chip)
@@ -207,10 +240,11 @@ def _dp_mesh(topo):
     return create_mesh(("dp",), devices=topo.devices)
 
 
-def test_dp_fused_level_compiles_on_four_chips(topo, no_compile_cache):
+@pytest.mark.parametrize("f,d", ((F, 5), (F_CRITEO, 7)))
+def test_dp_fused_level_compiles_on_four_chips(topo, no_compile_cache, f, d):
     """One level of the sharded round — fused kernel per shard + the
     per-level psum — over the four described chips, a quarter of the
-    blocks each."""
+    blocks each; at the Criteo width and level 7 too."""
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -219,12 +253,12 @@ def test_dp_fused_level_compiles_on_four_chips(topo, no_compile_cache):
     nb = 1000  # 250 blocks a chip (977 does not split four ways)
     rows = NamedSharding(mesh, P("dp", None, None))
     rep = NamedSharding(mesh, P())
-    xb3, g3, node3 = _blocked(rows, nb)
-    tab = _sds((1 << 4,), jnp.int32, rep)
+    xb3, g3, node3 = _blocked(rows, nb, f)
+    tab = _sds((1 << (d - 1),), jnp.int32, rep)
 
     def level(xb3, node3, g3, h3, feat, thr):
         hist, node3 = boost.hist_level(xb3, node3, g3, h3, feat, thr,
-                                       depth=5, n_bins=B)
+                                       depth=d, n_bins=B)
         return lax.psum(hist, "dp"), node3
 
     fn = jax.shard_map(
@@ -236,7 +270,7 @@ def test_dp_fused_level_compiles_on_four_chips(topo, no_compile_cache):
     assert "all-reduce" in text and "tpu_custom_call" in text
     # each device holds a quarter of the rows, not all of them: the
     # int32 feature blocks alone are nb*R*F*4 bytes in total
-    total = nb * R * F * 4
+    total = nb * R * f * 4
     assert c.memory_analysis().argument_size_in_bytes < total
 
 
@@ -253,18 +287,23 @@ def _state_shapes(cfg, n, sh, margin_sh=None):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("rows", (ROWS, 1_024_000, 256_000))
-def test_whole_fused_round_compiles_for_v5e(one_chip, no_compile_cache, rows):
+@pytest.mark.parametrize("rows,f,depth", (
+    (ROWS, F, DEPTH), (1_024_000, F, DEPTH), (256_000, F, DEPTH),
+    (NB_CRITEO * R, F_CRITEO, 8)))
+def test_whole_fused_round_compiles_for_v5e(one_chip, no_compile_cache, rows,
+                                            f, depth):
     """The program chip_smoke.py trains with.  At exactly 1,000,000 rows
     (977 blocks) this compile takes about two minutes and generates six
     times the code of the 1000-block program; cause not established
-    (ROADMAP S3)."""
-    cfg = gbdt.GBDTConfig(n_features=F, n_trees=8, depth=DEPTH, n_bins=B)
-    xb3, _, _ = _blocked(one_chip, -(-rows // R))
+    (ROADMAP S3).  The last case is the benchmark's criteo-1tb-share round
+    (22-32 s and 9.4 GB of temporaries as compiled here; on the chip the
+    allocator's peak is 7.49 GB; PR 27)."""
+    cfg = gbdt.GBDTConfig(n_features=f, n_trees=8, depth=depth, n_bins=B)
+    xb3, _, _ = _blocked(one_chip, -(-rows // R), f)
     y = _sds((rows,), jnp.float32, one_chip)
     c = _compile(functools.partial(gbdt.train_round_fused, cfg=cfg),
                  _state_shapes(cfg, rows, one_chip), xb3, y)
-    assert c.as_text().count("tpu_custom_call") >= DEPTH + 1
+    assert c.as_text().count("tpu_custom_call") >= depth + 1
     assert c.memory_analysis().temp_size_in_bytes < 12e9  # of 16 GB
 
 
