@@ -20,9 +20,12 @@ so the chip is never asked for by two processes at once:
      directory under ``--max-restarts 1``; it SIGKILLs itself after commit
      KILL_AFTER, the launcher restarts it, it resumes off the disk and must
      finish with a forest byte-identical to phase A's.
-  C  the engine hop inside the program: ``train_round_hybrid`` with a host
-     callback into ``rabit_tpu.allreduce`` once per level.  Compared with
-     phase A's first trees: equal splits, leaves within tolerance.
+  C  the engine hop inside the program: ``train_round_hybrid``, which on
+     the chip is phase A's fused kernels on codes it blocks in the graph,
+     with a host callback into ``rabit_tpu.allreduce`` between a level's
+     histogram and the next level's routing and one more for the leaves'
+     masses (depth + 1 hops a round).  Compared with phase A's first
+     trees: equal splits, leaves within tolerance.
   dp (``--chips 4`` only) rows sharded over a ("dp",) mesh of every device,
      ``train_round_dp_fused`` under ``shard_map`` with the per-level psum.
      Compared in the same process with the same rounds on one device.

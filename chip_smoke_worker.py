@@ -9,10 +9,11 @@ resumes byte-identically (a margin rebuilt by re-predicting sums the leaves
 in another order).  Arguments are rabit-style ``key=value``:
 
     mode=fused|hybrid|dp   the round: fused Pallas kernels on one device;
-                           ``train_round_hybrid`` with the engine hop as a
-                           host callback; or the fused round under
-                           ``shard_map`` over every device, followed by the
-                           same rounds on one of them for comparison
+                           ``train_round_hybrid``, the same kernels with
+                           the engine hop as a host callback; or the fused
+                           round under ``shard_map`` over every device,
+                           followed by the same rounds on one of them for
+                           comparison
     rows= rounds= seed=    data from ``bench.make_data(rows, seed)``
     kill_after=K           first life only: SIGKILL self after commit K
     out=DIR tag=NAME       results: DIR/NAME.jsonl (one line per life) and
@@ -143,20 +144,11 @@ def main() -> int:
             hops[0] += 1
             return rabit.allreduce(np.asarray(a, np.float32), rabit.SUM)
 
-        if rehearse:
-            # The round asks jax.default_backend() for its histogram and
-            # would take the exact-f32 scatter on a CPU; the rehearsal is
-            # of the chip's path, so hand it the chip's kernel, interpreted.
-            from rabit_tpu.ops import hist
-
-            hist.node_histograms = (
-                lambda xb, g, h, node, nn, nb, impl=None, mxu_i8=False:
-                hist.node_histograms_pallas(xb, g, h, node, nn, nb,
-                                            interpret=True, mxu_i8=mxu_i8))
         place = jnp.asarray
         data = (place(xb), place(y))
         step = jax.jit(functools.partial(
-            gbdt.train_round_hybrid, cfg=cfg, engine_allreduce=engine_hop))
+            gbdt.train_round_hybrid, cfg=cfg, engine_allreduce=engine_hop,
+            interpret=rehearse))
     elif mode == "dp":
         from jax.sharding import NamedSharding, PartitionSpec as P
 

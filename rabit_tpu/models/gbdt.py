@@ -35,6 +35,7 @@ import numpy as np
 from jax import lax
 
 from rabit_tpu import obs
+from rabit_tpu.obs.stream import series_name
 
 
 class GBDTConfig(NamedTuple):
@@ -311,6 +312,7 @@ def train_round_fused(
     cfg: GBDTConfig,
     combine: Callable[[jax.Array], jax.Array] = lambda x: x,
     interpret: bool = False,
+    combine_leaf: Callable[[jax.Array], jax.Array] | None = None,
 ) -> TrainState:
     """One boosting round via the fused Pallas kernels (ops.boost): routing,
     split lookup, and histogram accumulation run in one streaming pass per
@@ -326,6 +328,14 @@ def train_round_fused(
     histogram via split_child_masses, so there is no leaf collective)
     (e.g. ``lambda a: lax.psum(a, 'dp')`` under shard_map) — the same single
     communication point per level as the reference workload.
+
+    ``combine_leaf``, where given, is one more collective a round, for a
+    deployment whose collective sequence has a leaf hop (train_round_hybrid):
+    it is handed this shard's LOCAL children's masses of the last level,
+    ``[2**depth, 2]`` — linear in the histogram, so the sum over shards is
+    what the combined histogram gives — and returns the global ones.
+    Sending the combined histogram's masses would count every shard's
+    world times.
     """
     from rabit_tpu.ops import boost
 
@@ -344,10 +354,10 @@ def train_round_fused(
         )
 
     with jax.named_scope("level0"):
-        hist = combine(boost.hist_level0(xb3, g3, h3, n_bins=cfg.n_bins,
-                                         interpret=interpret,
-                                         mxu_i8=cfg.mxu_i8,
-                                         r_split=cfg.r_split))
+        local = boost.hist_level0(xb3, g3, h3, n_bins=cfg.n_bins,
+                                  interpret=interpret, mxu_i8=cfg.mxu_i8,
+                                  r_split=cfg.r_split)
+        hist = combine(local)
         feat, thr, _ = best_splits(hist, cfg)
     feats = [jnp.zeros(max_nodes, jnp.int32).at[:1].set(feat)]
     thrs = [jnp.zeros(max_nodes, jnp.int32).at[:1].set(thr)]
@@ -359,12 +369,12 @@ def train_round_fused(
                 m_rows=plan.m_rows, m_tiles=plan.m_tiles,
                 acc_block_bytes=plan.acc_block_bytes,
                 vmem_bytes=plan.vmem_bytes):
-            hist, node3 = boost.hist_level(xb3, node3, g3, h3, feat, thr,
-                                           depth=d, n_bins=cfg.n_bins,
-                                           interpret=interpret,
-                                           mxu_i8=cfg.mxu_i8,
-                                           r_split=cfg.r_split)
-            hist = combine(hist)
+            local, node3 = boost.hist_level(xb3, node3, g3, h3, feat, thr,
+                                            depth=d, n_bins=cfg.n_bins,
+                                            interpret=interpret,
+                                            mxu_i8=cfg.mxu_i8,
+                                            r_split=cfg.r_split)
+            hist = combine(local)
             feat, thr, _ = best_splits(hist, cfg)
         feats.append(jnp.zeros(max_nodes, jnp.int32).at[: 2 ** d].set(feat))
         thrs.append(jnp.zeros(max_nodes, jnp.int32).at[: 2 ** d].set(thr))
@@ -374,14 +384,18 @@ def train_round_fused(
     # Leaf (g, h) masses come straight off the final combined histogram
     # (split_child_masses) — already globally reduced, so no leaf collective
     # and no histogram work in the last row pass (depth collectives per
-    # round, not depth+1).  The last pass routes rows to their leaves and
+    # round, not depth+1, unless the caller's sequence has a leaf hop:
+    # ``combine_leaf``).  The last pass routes rows to their leaves and
     # applies ``margin += leaf[node]`` either inside one fused kernel
     # (cfg.fused_final) or as a routing kernel plus an XLA gather from the
     # 2**depth-entry leaf table — the gather form measured faster
     # whole-round in both MXU modes and is the default; see the
     # GBDTConfig.fused_final docstring (RESULTS/final_pass.jsonl).
     with jax.named_scope("leaf"):
-        leaf_gh = split_child_masses(hist, feat, thr)
+        if combine_leaf is None:
+            leaf_gh = split_child_masses(hist, feat, thr)
+        else:
+            leaf_gh = combine_leaf(split_child_masses(local, feat, thr))
         leaf = (-cfg.learning_rate * leaf_gh[:, 0]
                 / (leaf_gh[:, 1] + cfg.reg_lambda))
         if cfg.fused_final:
@@ -415,20 +429,38 @@ def train_round_hybrid(
     mesh=None,
     dp_axis: str = "dp",
     engine_allreduce: Callable[[np.ndarray], np.ndarray] | None = None,
+    interpret: bool = False,
 ) -> TrainState:
-    """One boosting round for the HYBRID deployment: XLA data plane married
-    to the fault-tolerant native engine (the reference's recovery seam,
-    allreduce_robust.cc:687-725, which round-2's review named the last
+    """One boosting round for the HYBRID deployment: the device's data plane
+    married to the fault-tolerant native engine (the reference's recovery
+    seam, allreduce_robust.cc:687-725, which round-2's review named the last
     first-order gap).
 
-    The whole round is ONE jitted XLA program: per level, local histograms
-    are built under ``shard_map`` with an in-graph ``psum`` over the
-    intra-host device mesh, and the cross-worker hop crosses the robust
-    TCP engine through a host callback.  The callbacks are ordered by data
-    dependence — level d's combined histogram feeds level d+1's routing —
-    so every worker issues the identical deterministic collective sequence,
-    which is exactly what lets the robust engine's replay log serve
-    byte-identical results to a worker recovering mid-round.
+    The whole round is ONE jitted XLA program, and the cross-worker hop
+    crosses the robust TCP engine through a host callback between a level's
+    histogram and the next level's routing.  The callbacks are ordered by
+    data dependence — level d's combined histogram feeds level d+1's
+    routing — so every worker issues the identical deterministic collective
+    sequence, depth + 1 hops a round (a histogram a level, then the
+    leaves' masses), which is exactly what lets the robust engine's replay
+    log serve byte-identical results to a worker recovering mid-round.
+
+    Which kernels do the local work is read from the call, no option:
+
+    * on a TPU (or with ``interpret``, for the tests) and no ``mesh``, the
+      fused kernels: ``train_round_fused``'s level loop with the hop as its
+      per-level hook and the local children's masses of the last level as
+      the leaf hop.  A 2-D ``xb`` ``[n, F]`` is blocked in the graph, every
+      round; a 3-D one is taken as ``ops.boost.block_rows`` left it, so a
+      caller that blocks once a fit pays nothing;
+    * anywhere else, and with a ``mesh`` (local histograms under
+      ``shard_map`` with an in-graph ``psum`` over the intra-host devices,
+      the callback outside it: once a process, never once a device),
+      ``train_round``: the standalone histogram, XLA's gather routing and a
+      ``segment_sum`` for the leaves.
+
+    The counter ``gbdt_hybrid_round_lowered_total{path=fused|xla}`` says
+    which, once a lowering.
 
     ``engine_allreduce`` is a host fn ``np.ndarray -> np.ndarray`` (e.g.
     ``lambda a: rabit_tpu.allreduce(a, rt.SUM)``); None means solo (the
@@ -461,6 +493,21 @@ def train_round_hybrid(
             np.int32(tag),
         )
 
+    cross_leaf = functools.partial(cross, tag=-1)
+    fused = mesh is None and (interpret or jax.default_backend() == "tpu")
+    obs.get_registry().counter(series_name(
+        "gbdt_hybrid_round_lowered_total",
+        path="fused" if fused else "xla")).inc()
+    from rabit_tpu.ops import boost
+
+    if fused:
+        xb3 = xb if xb.ndim == 3 else boost.block_rows(xb)[0]
+        # the tag is the level's node count, 2**level: unique per level
+        return train_round_fused(
+            state, xb3, y, cfg, combine=lambda a: cross(a, a.shape[0]),
+            interpret=interpret, combine_leaf=cross_leaf)
+    if xb.ndim == 3:
+        xb = boost.unblock_rows(xb, y.shape[0])
     if mesh is None:
         hist_fn = lambda xb_, g, h, node, nn, nb: cross(
             node_histograms(xb_, g, h, node, nn, nb, mxu_i8=cfg.mxu_i8), nn
@@ -481,8 +528,7 @@ def train_round_hybrid(
             )(xb_, g, h, node)
             return cross(local, nn)  # nn = 2**level: unique per level
 
-    return train_round(state, xb, y, cfg, hist_fn,
-                       functools.partial(cross, tag=-1))
+    return train_round(state, xb, y, cfg, hist_fn, cross_leaf)
 
 
 def train_round_dp_fused(state, xb3, y, cfg, dp_axis: str = "dp",

@@ -600,3 +600,125 @@ def test_train_round_dp_fused_wire_i8_close_to_exact():
     np.testing.assert_array_equal(fw.feature, fe.feature)
     np.testing.assert_array_equal(fw.threshold, fe.threshold)
     np.testing.assert_allclose(fw.leaf, fe.leaf, rtol=1e-3, atol=1e-3)
+
+
+# -- the hybrid round on the fused kernels ---------------------------------
+
+
+def _hybrid_case(n=2500, f=5, bins=16, depth=3, rounds=2, seed=13):
+    rng = np.random.RandomState(seed)
+    cfg = gbdt.GBDTConfig(n_features=f, n_trees=rounds, depth=depth,
+                          n_bins=bins)
+    xb = jnp.asarray(rng.randint(0, bins, size=(n, f)), jnp.int32)
+    y = jnp.asarray(rng.randint(0, 2, size=n), jnp.float32)
+    return cfg, xb, y
+
+
+def _train(step, cfg, n, *data):
+    s = gbdt.init_state(cfg, n)
+    for _ in range(cfg.n_trees):
+        s = step(s, *data)
+    return jax.tree.map(np.asarray, s)
+
+
+@pytest.mark.parametrize("codes", ["2d", "3d", "3d-xla"])
+def test_hybrid_round_with_an_identity_hop_is_the_fused_round(codes):
+    """With the hop an identity (a world of one) the hybrid round on the
+    fused kernels gives the bytes of ``train_round_fused`` on the same
+    blocked data, whether it is handed the codes ``[n, F]`` and blocks them
+    in the graph or ``[nb, 1024, F]`` blocked already.  Off the fused path
+    blocked codes are unblocked and give the bytes the 2-D ones give."""
+    from rabit_tpu.ops import boost
+
+    cfg, xb, y = _hybrid_case()
+    n = y.shape[0]
+    xb3, _ = boost.block_rows(xb)
+    interpret = codes != "3d-xla"
+    hybrid = jax.jit(functools.partial(
+        gbdt.train_round_hybrid, cfg=cfg, engine_allreduce=lambda a: a,
+        interpret=interpret))
+    got = _train(hybrid, cfg, n, xb if codes == "2d" else xb3, y)
+    if interpret:
+        want = _train(jax.jit(functools.partial(
+            gbdt.train_round_fused, cfg=cfg, interpret=True)), cfg, n, xb3, y)
+    else:
+        want = _train(hybrid, cfg, n, xb, y)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.tobytes() == b.tobytes()
+    assert np.abs(got.forest.leaf).max() > 0
+
+
+def test_hybrid_round_hops_depth_plus_one_times_in_order():
+    """The collective sequence the engine's replay log is written against:
+    a histogram ``[2**d, F, B, 2]`` a level, then the leaves' masses
+    ``[2**depth, 2]``, each under a tag of its own."""
+    import time
+
+    from rabit_tpu import obs
+
+    cfg, xb, y = _hybrid_case(rounds=1)
+    seen = []
+
+    def hop(a):
+        seen.append(a.shape)
+        return a
+
+    t0 = time.time()
+    s = jax.jit(functools.partial(
+        gbdt.train_round_hybrid, cfg=cfg, engine_allreduce=hop,
+        interpret=True))(gbdt.init_state(cfg, y.shape[0]), xb, y)
+    jax.block_until_ready(s)
+    d, f, b = cfg.depth, cfg.n_features, cfg.n_bins
+    assert seen == [(2 ** k, f, b, 2) for k in range(d)] + [(2 ** d, 2)]
+    tags = [e.fields["level"] for e in obs.get_recorder().snapshot()
+            if e.ts >= t0 and e.kind == "span"
+            and e.fields.get("name") == "gbdt.cross"]
+    assert tags == [2 ** k for k in range(d)] + [-1]
+
+
+def test_hybrid_round_leaf_hop_is_right_in_a_world_of_two():
+    """A hop that returns ``2 * a`` is a world of two identical shards.  The
+    forest must be the fused round's on the rows twice over: the leaf hop
+    carries the LOCAL children's masses, so the engine's sum is the global
+    mass and not the world's multiple of it."""
+    from rabit_tpu.ops import boost
+
+    cfg, xb, y = _hybrid_case(n=2048)
+    n = y.shape[0]
+    got = _train(jax.jit(functools.partial(
+        gbdt.train_round_hybrid, cfg=cfg, engine_allreduce=lambda a: 2 * a,
+        interpret=True)), cfg, n, xb, y)
+    xb3, _ = boost.block_rows(jnp.concatenate([xb, xb]))
+    want = _train(jax.jit(functools.partial(
+        gbdt.train_round_fused, cfg=cfg, interpret=True)), cfg, 2 * n, xb3,
+        jnp.concatenate([y, y]))
+    np.testing.assert_array_equal(got.forest.feature, want.forest.feature)
+    np.testing.assert_array_equal(got.forest.threshold, want.forest.threshold)
+    np.testing.assert_allclose(got.forest.leaf, want.forest.leaf,
+                               rtol=1e-3, atol=1e-5)
+    np.testing.assert_allclose(got.margin, want.margin[:n],
+                               rtol=1e-3, atol=1e-5)
+
+
+@pytest.mark.parametrize("how,path", [
+    ("interpret", "fused"), ("cpu", "xla"), ("mesh", "xla")])
+def test_hybrid_round_counts_its_lowerings_by_path(how, path):
+    """``gbdt_hybrid_round_lowered_total{path=...}`` moves by one a
+    lowering, under the path the round took: the fused kernels on a TPU or
+    under ``interpret``, ``train_round`` on any other backend and wherever
+    a mesh is given."""
+    from rabit_tpu import obs
+    from rabit_tpu.obs.stream import series_name
+
+    cfg, xb, y = _hybrid_case(n=2048, rounds=1)
+    kw = {"interpret": how != "cpu"}
+    if how == "mesh":
+        kw["mesh"] = rp.create_mesh(("dp",))
+    read = lambda p: obs.get_registry().counter(series_name(
+        "gbdt_hybrid_round_lowered_total", path=p)).value
+    before = {p: read(p) for p in ("fused", "xla")}
+    jax.jit(functools.partial(gbdt.train_round_hybrid, cfg=cfg, **kw)).lower(
+        gbdt.init_state(cfg, y.shape[0]), xb, y)
+    other = "xla" if path == "fused" else "fused"
+    assert read(path) == before[path] + 1
+    assert read(other) == before[other]

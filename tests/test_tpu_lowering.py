@@ -311,10 +311,12 @@ def test_whole_fused_round_compiles_for_v5e(one_chip, no_compile_cache, rows,
 @pytest.mark.parametrize("rows", (ROWS, 1_024_000))
 def test_whole_hybrid_round_compiles_for_v5e(one_chip, no_compile_cache,
                                              monkeypatch, rows):
-    """The engine-hop round of chip_smoke.py phase C: standalone Pallas
-    histogram per level (six node counts) + a host callback per level.
-    The dispatchers ask ``jax.default_backend()``, which is the CPU here,
-    so the test steers them to their TPU branch."""
+    """The engine-hop round of chip_smoke.py phase C and of the benchmark's
+    ``engine-hop`` cell: the fused round's kernels (a histogram a level and
+    the leaves' routing pass: depth + 1 ``tpu_custom_call``) on codes
+    ``[n, F]`` blocked in the graph, and a host callback a hop, depth + 1
+    of them.  The round asks ``jax.default_backend()``, which is the CPU
+    here, so the test steers it to its TPU branch."""
     import numpy as np
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -326,8 +328,14 @@ def test_whole_hybrid_round_compiles_for_v5e(one_chip, no_compile_cache,
                           engine_allreduce=lambda a: np.asarray(a)),
         _state_shapes(cfg, rows, one_chip), xb, y)
     text = c.as_text()
-    assert text.count("tpu_custom_call") >= DEPTH
-    assert "callback" in text.lower()
+    assert text.count("tpu_custom_call") >= DEPTH + 1
+    assert "hist_level_d5" in text and "route_level_d6" in text
+    assert "node_histograms" not in text
+    # a hop is one host transfer out and one back
+    hops = [ln for ln in text.splitlines()
+            if " recv(" in ln and "is_host_transfer=true" in ln]
+    assert len(hops) == DEPTH + 1 and all("callback" in ln for ln in hops)
+    assert c.memory_analysis().temp_size_in_bytes < 12e9  # of 16 GB
 
 
 @pytest.mark.slow
