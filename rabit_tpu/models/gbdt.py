@@ -64,9 +64,10 @@ class GBDTConfig(NamedTuple):
     # Figures of an older chip at 1,000,000 rows x 28, depth 6, not
     # re-measured (RESULTS/final_pass.jsonl): XLA-final won whole-round in
     # both MXU modes (73.8 vs 78.1 ms bf16, 77.3 vs 78.7 ms i8), so False is
-    # the default and the fused kernel stays as the challenger bench.py
-    # re-races.  The benchmark's cells (PERF.md section 5) run 2.6M rows;
-    # at depth 8 the XLA gather is 21.5 ms of a round.
+    # the default.  No tool races the fused kernel any more, and ROADMAP.md
+    # D2 deletes it once the benchmark stops passing this field.  The
+    # benchmark's cells (PERF.md section 5) run 2.6M rows; at depth 8 the
+    # XLA gather is 21.5 ms of a round.
     fused_final: bool = False
     # Split each row block into this many independent sub-contractions in
     # the level kernels' histogram accumulation (ops/boost.py _accum):

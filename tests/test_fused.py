@@ -221,23 +221,6 @@ def test_fused_builder_input_validation():
         fused.segment_widths(get_codec("zlib"))
 
 
-def test_bench_codec_pareto_frontier():
-    """ISSUE 11 satellite: the driver record's codec_pareto row — a codec
-    is on the frontier unless another strictly dominates it on the
-    (wire bytes, rounds/s) plane."""
-    import bench
-
-    rows = bench.codec_pareto([
-        {"codec": "f32", "allreduce_wire_bytes": 100, "rounds_per_sec": 10.0},
-        {"codec": "i8", "allreduce_wire_bytes": 25, "rounds_per_sec": 9.5},
-        {"codec": "slowfat", "allreduce_wire_bytes": 50,
-         "rounds_per_sec": 9.0},
-        {"codec": "junk"},  # malformed lines are skipped, not fatal
-    ])
-    front = {r["codec"]: r["on_frontier"] for r in rows}
-    assert front == {"f32": True, "i8": True, "slowfat": False}
-
-
 @pytest.mark.slow
 def test_fused_parity_sweep_slow():
     """The larger sweep: MIN joins the op set, identity codec joins (the

@@ -74,9 +74,8 @@ def test_fused_level_kernels_lower(i8):
     )
 
 
-def test_route_and_leaf_kernels_lower():
+def test_route_kernels_lower():
     xb3 = jnp.zeros((NB, R, F), jnp.int32)
-    g3 = h3 = jnp.zeros((NB, R, 1), jnp.float32)
     node3 = jnp.zeros((NB, R, 1), jnp.int32)
     tab = jnp.zeros(1 << 5, jnp.int32)
     export_tpu(
@@ -88,14 +87,12 @@ def test_route_and_leaf_kernels_lower():
         functools.partial(boost.route_margin_level, depth=6),
         xb3, node3, margin3, tab, tab, leaf,
     )
-    export_tpu(
-        functools.partial(boost.leaf_fit, depth=6), xb3, node3, g3, h3, tab, tab
-    )
 
 
 @pytest.mark.parametrize("i8", I8)
 def test_full_fused_round_lowers(i8):
-    """The exact program bench.py jits on the chip, both MXU modes."""
+    """The program the benchmark's ``fused-armed`` cells jit on the chip,
+    both MXU modes."""
     n = NB * R
     cfg = gbdt.GBDTConfig(n_features=F, n_trees=2, depth=6, n_bins=B,
                           mxu_i8=i8)
@@ -123,7 +120,7 @@ def test_full_fused_round_lowers(i8):
 # libtpu — and the compile runs in this process with the persistent
 # compilation cache off (a described-device entry cannot be read back).
 
-ROWS = 1_000_000              # Higgs-1M, the flagship shape (bench.py)
+ROWS = 1_000_000              # Higgs-1M (XGBoost KDD'16); no cell runs it
 NB_REAL = -(-ROWS // R)       # 977 row blocks of 1024
 DEPTH = 6
 
@@ -292,12 +289,12 @@ def _state_shapes(cfg, n, sh, margin_sh=None):
     (NB_CRITEO * R, F_CRITEO, 8)))
 def test_whole_fused_round_compiles_for_v5e(one_chip, no_compile_cache, rows,
                                             f, depth):
-    """The program chip_smoke.py trains with.  At exactly 1,000,000 rows
-    (977 blocks) this compile takes about two minutes and generates six
-    times the code of the 1000-block program; cause not established
-    (ROADMAP S3).  The last case is the benchmark's criteo-1tb-share round
-    (22-32 s and 9.4 GB of temporaries as compiled here; on the chip the
-    allocator's peak is 7.49 GB; PR 27)."""
+    """The round of the benchmark's ``fused-armed`` cells.  At exactly
+    1,000,000 rows (977 blocks) this compile takes about two minutes and
+    generates six times the code of the 1000-block program; cause not
+    established (ROADMAP S3).  The last case is the benchmark's
+    criteo-1tb-share round (22-32 s and 9.4 GB of temporaries as compiled
+    here; on the chip the allocator's peak is 7.49 GB; PR 27)."""
     cfg = gbdt.GBDTConfig(n_features=f, n_trees=8, depth=depth, n_bins=B)
     xb3, _, _ = _blocked(one_chip, -(-rows // R), f)
     y = _sds((rows,), jnp.float32, one_chip)
@@ -311,12 +308,12 @@ def test_whole_fused_round_compiles_for_v5e(one_chip, no_compile_cache, rows,
 @pytest.mark.parametrize("rows", (ROWS, 1_024_000))
 def test_whole_hybrid_round_compiles_for_v5e(one_chip, no_compile_cache,
                                              monkeypatch, rows):
-    """The engine-hop round of chip_smoke.py phase C and of the benchmark's
-    ``engine-hop`` cell: the fused round's kernels (a histogram a level and
-    the leaves' routing pass: depth + 1 ``tpu_custom_call``) on codes
-    ``[n, F]`` blocked in the graph, and a host callback a hop, depth + 1
-    of them.  The round asks ``jax.default_backend()``, which is the CPU
-    here, so the test steers it to its TPU branch."""
+    """The round of the benchmark's ``higgs-quarter.engine-hop`` cell: the
+    fused round's kernels (a histogram a level and the leaves' routing pass:
+    depth + 1 ``tpu_custom_call``) on codes ``[n, F]`` blocked in the graph,
+    and a host callback a hop, depth + 1 of them.  The round asks
+    ``jax.default_backend()``, which is the CPU here, so the test steers it
+    to its TPU branch."""
     import numpy as np
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -340,8 +337,9 @@ def test_whole_hybrid_round_compiles_for_v5e(one_chip, no_compile_cache,
 
 @pytest.mark.slow
 def test_whole_dp_fused_round_compiles_on_four_chips(topo, no_compile_cache):
-    """chip_smoke.py --chips 4: the whole sharded round, 250,000 rows
-    (245 blocks, the last one padded) a chip, one psum per level."""
+    """The round of ``higgs-full.dp4-armed``: the whole sharded round,
+    250,000 rows (245 blocks, the last one padded) a chip, one psum per
+    level."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     mesh = _dp_mesh(topo)

@@ -13,7 +13,9 @@ Arms (``--arm all`` is the default):
   relays while the writer publishes; reports snapshot propagation
   p50/p99 (publish -> a subscriber's poll observes the version), poll
   failure count, and the WRITER-CADENCE tax: rounds/s with the swarm
-  attached vs the same writer unobserved (bar: >= 0.95x).
+  attached vs the same writer unobserved (bar: >= 0.95x); then, at no
+  wall-clock bar, how many subscribers converge on the writer's last
+  digest (``converged``: all of them) and whether a real one fetches it.
 * ``dedup`` — T publishers (tenants) commit IDENTICAL bytes as T grows
   1 -> 8; reports the root-uplink wire bytes per tenant count (bar:
   <= 1.2x the single-tenant bytes — content addressing ships the blob
@@ -24,9 +26,8 @@ Arms (``--arm all`` is the default):
   subscriber rotate via the address list, and all subscribers converge
   on the post-failover digest with ZERO spurious errors.
 
-Output: one JSON line per arm, each tagged ``{"bench": "delivery"}`` —
-the shape bench.py's rider and tools/bench_sentinel.py consume.
-``--smoke`` shrinks every knob for the CI rider.
+Output: one JSON line per arm, each tagged ``{"bench": "delivery"}``.
+``--smoke`` shrinks every knob.
 """
 
 from __future__ import annotations
@@ -89,7 +90,8 @@ def _drive_shard(targets: list[tuple[str, int]], subs: range,
     (phase-staggered) against its round-robin target.  ``publish_ts``
     maps version -> monotonic publish time (the writer fills it); the
     first poll of each subscriber that OBSERVES a version records the
-    propagation latency.  Appends a stats dict to ``out``."""
+    propagation latency.  Appends a stats dict to ``out``; its ``seen`` is
+    the newest version each subscriber observed inside the window."""
     sel = selectors.DefaultSelector()
     t0 = time.monotonic()
     deadline = t0 + duration_sec
@@ -195,7 +197,8 @@ def _drive_shard(targets: list[tuple[str, int]], subs: range,
     for p in list(inflight.values()):
         _close(p, ok=False)
     sel.close()
-    out.append({"polls": polls, "failures": failures, "lat": lat})
+    out.append({"polls": polls, "failures": failures, "lat": lat,
+                "seen": seen})
 
 
 def drive_swarm(targets: list[tuple[str, int]], n_subs: int,
@@ -206,7 +209,8 @@ def drive_swarm(targets: list[tuple[str, int]], n_subs: int,
     """Drive ``n_subs`` simulated subscribers split across ``shards``
     selector threads (socket syscalls release the GIL, so sharding is
     what lets one process stand in for 10^4-10^5 pollers).  Returns
-    aggregate polls/failures/latency percentiles."""
+    aggregate polls/failures/latency percentiles and every subscriber's
+    newest ``seen`` version."""
     shards = max(1, min(shards, n_subs))
     per = (n_subs + shards - 1) // shards
     out: list[dict] = []
@@ -222,6 +226,7 @@ def drive_swarm(targets: list[tuple[str, int]], n_subs: int,
     lat = [x for s in out for x in s["lat"]]
     return {"polls": sum(s["polls"] for s in out),
             "failures": sum(s["failures"] for s in out),
+            "seen": {i: v for s in out for i, v in s["seen"].items()},
             "n_lat": len(lat),
             "prop_p50_ms": (_pct(lat, 0.50) or 0.0) * 1e3,
             "prop_p99_ms": (_pct(lat, 0.99) or 0.0) * 1e3}
@@ -242,6 +247,7 @@ def _writer(pub: Publisher, rounds: int, round_sec: float, size: int,
         except ConnectionError:
             continue
         publish_ts[v] = time.monotonic()
+        out["digest"] = digest_of(blob)
         done += 1
         t_next = t0 + (r + 1) * round_sec
         time.sleep(max(t_next - time.monotonic(), 0.0))
@@ -300,6 +306,28 @@ def run_swarm(n_subs: int, n_relays: int, rounds: int, round_sec: float,
         wt.join(duration + 30)
         stop.set()
         vt.join(5)
+        # The window judges how fast; whether delivery converges is judged
+        # after it, at no deadline a loaded machine can miss: a subscriber
+        # the window starved polls on until it names the writer's last
+        # digest, and a real one fetches those bytes.
+        final = max(publish_ts, default=0)
+
+        def _lands(i: int) -> bool:
+            sub = Subscriber(*targets[i % len(targets)], task_id=f"sw{i}",
+                             poll_sec=poll_sec)
+            try:
+                return sub.wait_for(final)["digest"] == obs.get("digest")
+            except (ConnectionError, TimeoutError):
+                return False
+
+        converged = sum(v >= final or _lands(i)
+                        for i, v in swarm.pop("seen").items())
+        try:   # fetch() holds the bytes to the digest of the line it returns
+            line, _blob = Subscriber(*targets[0], task_id="verify",
+                                     poll_sec=poll_sec).fetch()
+            final_fetch_ok = line["digest"] == obs.get("digest")
+        except (ConnectionError, LookupError, TimeoutError):
+            final_fetch_ok = False
         cadence = (obs.get("rounds_per_sec", 0.0)
                    / max(base.get("rounds_per_sec", 1e-9), 1e-9))
         return {
@@ -307,6 +335,8 @@ def run_swarm(n_subs: int, n_relays: int, rounds: int, round_sec: float,
             "relays": n_relays, "rounds": rounds, "round_sec": round_sec,
             "snapshot_bytes": size, **swarm,
             "fetches_verified": fetched[0], "fetch_errors": fetch_errors[0],
+            "writer_rounds": obs.get("rounds", 0), "converged": converged,
+            "final_fetch_ok": final_fetch_ok,
             "writer_rounds_per_sec": round(obs.get("rounds_per_sec", 0.0), 3),
             "unobserved_rounds_per_sec": round(
                 base.get("rounds_per_sec", 0.0), 3),

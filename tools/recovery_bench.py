@@ -16,9 +16,7 @@ seeded promote/shrink/grow scenarios with in-process ``ElasticWorker``
 threads against an elastic tracker, reporting the spare-promotion-latency
 vs. shrink-wave-latency curve per world size — every number derived from
 structured tracker events (``spare_promoted`` / ``world_shrunk`` /
-``world_grown`` timestamps), no stdout scraping.  The driver embeds these
-lines under ``"elastic"`` in the bench record (bench.py), so the BENCH
-trajectory picks them up.
+``world_grown`` timestamps), no stdout scraping.
 
 ``--scale-sweep`` switches to the simulated-world control-plane sweep
 (tools/scale_sweep.py, doc/scaling.md): recovery-wave latency under
@@ -33,8 +31,7 @@ TRACKER killed abruptly mid-run (``Tracker.kill()``, the in-process
 SIGKILL), with and without a relay tier in front.  Rows report the
 takeover latency (kill -> ``tracker_failover``) and the recovery
 latency (kill -> the first wave/commit progress after the takeover),
-all from structured events.  The driver embeds these lines under
-``"ha_failover"`` in the bench record (``RABIT_BENCH_HA=0`` skips).
+all from structured events.
 
 ``--blob-mb B [B ...]`` switches to the checkpoint-serve-scaling mode
 (round-5 verdict #3): the worker carries a B-MiB content-verified blob in

@@ -29,9 +29,7 @@ the recovery_bench/chaos harness shape):
   plane (HealthMonitor, doc/observability.md) opens ZERO incidents on
   the clean fleet: the false-positive gate.
 
-Every record is one JSON line with ``"bench": "service"`` (the bench.py
-driver embeds them under ``rec["service"]``; RABIT_BENCH_SERVICE=0
-skips).  ``--smoke`` shrinks every arm to CI size and relaxes the
+Every record is one JSON line with ``"bench": "service"``.  ``--smoke`` shrinks every arm to CI size and relaxes the
 wall-clock isolation assert to evidence-only (CPU-oversubscribed CI
 machines cannot hold a 1.2x timing bar honestly); completion + bitwise
 identity are asserted in every mode.  The legacy-wire guarantee is

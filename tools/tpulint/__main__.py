@@ -111,7 +111,7 @@ def _fam_config(ctx: _Ctx) -> list[Finding]:
     py_read_files = iter_python_files(
         ctx.root,
         ["rabit_tpu/**/*.py", "tools/*.py", "tests/**/*.py",
-         "guide/**/*.py", "bench.py"],
+         "guide/**/*.py"],
         exclude_parts=_EXCLUDE_PARTS)
     native_files = [p for p in
                     sorted((ctx.root / "native").glob("**/*"))
@@ -166,8 +166,8 @@ def _fam_ownership(ctx: _Ctx) -> list[Finding]:
 
 
 def _fam_resources(ctx: _Ctx) -> list[Finding]:
-    # builds its OWN graph over a wider scope (tools/, bench.py) — adding
-    # those trees to the shared graph would perturb the v2 families'
+    # builds its OWN graph over a wider scope (tools/) — adding
+    # that tree to the shared graph would perturb the v2 families'
     # private-name fallback resolution.
     return resources.check_resources(ctx.root)
 

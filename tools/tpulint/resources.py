@@ -29,7 +29,7 @@ site); a handle passed into another call is assumed handed off.
 threads are fire-and-forget by design throughout the tracker.
 
 Scope: the fd-budget-critical trees the ISSUE names —
-tracker/relay/elastic/service/ha/chaos — plus tools/ and bench.py
+tracker/relay/elastic/service/ha/chaos — plus tools/
 (the expected leak crop lives in chaos/bench helpers).
 """
 
@@ -56,7 +56,6 @@ GLOBS = [
     "rabit_tpu/ha/**/*.py",
     "rabit_tpu/chaos.py",
     "tools/*.py",
-    "bench.py",
 ]
 
 
