@@ -62,6 +62,22 @@ int RabitLoadCheckPoint(char** out_global, trt_ulong* out_global_len,
                         char** out_local, trt_ulong* out_local_len);
 int RabitCheckPoint(const char* global_data, trt_ulong global_len,
                     const char* local_data, trt_ulong local_len);
+/* The gather form of RabitCheckPoint: a model arrives as `n` pieces that,
+ * read in order, are its blob — a small header and the memory of the
+ * caller's arrays, say (rabit_tpu.checkpoint pickles out of band and hands
+ * over exactly that; doc/guide.md "Checkpoint blobs").  The engine copies
+ * the pieces into its own storage before the call returns — the only copy a
+ * commit makes of the model — and keeps no pointer into them: the caller
+ * may overwrite or free its memory at once.  What LoadCheckPoint and a
+ * recovering peer get back is the pieces joined, as opaque as ever.
+ * n_local == 0 (or no byte in any local piece) means no local model, as
+ * local_len == 0 does above. */
+typedef struct TrtBlobPiece {
+  const void* data;
+  trt_ulong len;
+} TrtBlobPiece;
+int TrtCheckPointPieces(const TrtBlobPiece* global_pieces, trt_ulong n_global,
+                        const TrtBlobPiece* local_pieces, trt_ulong n_local);
 int RabitLazyCheckPoint(const char* global_data, trt_ulong global_len);
 /* True lazy checkpoint: `serialize_fn` is invoked only if a failure needs
  * the blob (reference global_lazycheck, allreduce_robust.cc:527-535).  It

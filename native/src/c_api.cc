@@ -163,6 +163,14 @@ int RabitCheckPoint(const char* global_data, trt_ulong global_len,
   });
 }
 
+int TrtCheckPointPieces(const TrtBlobPiece* global_pieces, trt_ulong n_global,
+                        const TrtBlobPiece* local_pieces, trt_ulong n_local) {
+  return Guard([&] {
+    GetEngine()->CheckPoint(BlobView{global_pieces, n_global},
+                            BlobView{local_pieces, n_local});
+  });
+}
+
 int RabitLazyCheckPoint(const char* global_data, trt_ulong global_len) {
   return Guard([&] { GetEngine()->LazyCheckPoint(global_data, global_len); });
 }

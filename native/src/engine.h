@@ -11,6 +11,7 @@
 #include <memory>
 #include <string>
 
+#include "../include/tpurabit/c_api.h"
 #include "comm.h"
 #include "common.h"
 
@@ -34,6 +35,36 @@ using PrepareFn = void (*)(void* arg);
 // returns (the engine copies immediately).  Non-zero = serialization failed.
 using SerializeFn = int (*)(void* ctx, const char** out_data,
                             uint64_t* out_len);
+
+// One piece of a checkpoint blob, in the caller's memory: {data, len}, as
+// it crosses the C ABI.
+using BlobPiece = TrtBlobPiece;
+
+// A checkpoint blob as its caller holds it: `n` pieces that, read in
+// order, are the blob.  An engine copies them into storage of its own
+// before CheckPoint returns and keeps no pointer into them — that copy is
+// the only one a commit makes of a model handed over in pieces (PERF.md,
+// PR 30).  No pieces, or none with a byte in it, is "no model".
+struct BlobView {
+  const BlobPiece* pieces = nullptr;
+  size_t n = 0;
+
+  size_t size() const {
+    size_t total = 0;
+    for (size_t i = 0; i < n; ++i) total += pieces[i].len;
+    return total;
+  }
+  bool present() const { return size() > 0; }
+  // `out` keeps its capacity from commit to commit, so a model that does
+  // not grow is copied into pages that are already mapped.
+  void CopyTo(std::string* out) const {
+    out->clear();
+    out->reserve(size());
+    for (size_t i = 0; i < n; ++i) {
+      out->append(static_cast<const char*>(pieces[i].data), pieces[i].len);
+    }
+  }
+};
 
 class Engine {
  public:
@@ -64,8 +95,15 @@ class Engine {
 
   virtual int LoadCheckPoint(std::string* global_blob,
                              std::string* local_blob) = 0;
-  virtual void CheckPoint(const char* gdata, size_t glen, const char* ldata,
-                          size_t llen) = 0;
+  virtual void CheckPoint(const BlobView& global, const BlobView& local) = 0;
+  // The same for two contiguous blobs (the C ABI's RabitCheckPoint); a
+  // null or empty local blob is no local model.
+  void CheckPoint(const char* gdata, size_t glen, const char* ldata,
+                  size_t llen) {
+    BlobPiece g{gdata, glen}, l{ldata, llen};
+    CheckPoint(BlobView{&g, 1},
+               ldata != nullptr && llen > 0 ? BlobView{&l, 1} : BlobView{});
+  }
   // Stores only the pointer; caller keeps the buffer alive and unchanged
   // until the next checkpoint (reference LazyCheckPoint contract,
   // rabit.h:311-332).
@@ -118,9 +156,10 @@ class EmptyEngine : public Engine {
     }
     return version_;
   }
-  void CheckPoint(const char* gd, size_t gl, const char* ld, size_t ll) override {
-    global_.assign(gd, gd + gl);
-    local_ = ld != nullptr ? std::string(ld, ld + ll) : std::string();
+  using Engine::CheckPoint;
+  void CheckPoint(const BlobView& g, const BlobView& l) override {
+    g.CopyTo(&global_);
+    l.CopyTo(&local_);
     ++version_;
   }
   void LazyCheckPoint(const char* gd, size_t gl) override {
@@ -175,9 +214,10 @@ class BaseEngine : public Engine {
     }
     return version_;
   }
-  void CheckPoint(const char* gd, size_t gl, const char* ld, size_t ll) override {
-    global_.assign(gd, gd + gl);
-    local_ = ld != nullptr ? std::string(ld, ld + ll) : std::string();
+  using Engine::CheckPoint;
+  void CheckPoint(const BlobView& g, const BlobView& l) override {
+    g.CopyTo(&global_);
+    l.CopyTo(&local_);
     ++version_;
   }
   void LazyCheckPoint(const char* gd, size_t gl) override {

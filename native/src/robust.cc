@@ -348,19 +348,21 @@ class RobustEngine : public Engine {
     return version_;
   }
 
-  void CheckPoint(const char* gdata, size_t glen, const char* ldata,
-                  size_t llen) override {
-    CheckPointImpl(gdata, glen, ldata, llen, /*lazy=*/false);
+  using Engine::CheckPoint;
+  void CheckPoint(const BlobView& global, const BlobView& local) override {
+    CheckPointImpl(global, local, /*lazy=*/false);
   }
 
   void LazyCheckPoint(const char* gdata, size_t glen) override {
-    CheckPointImpl(gdata, glen, nullptr, 0, /*lazy=*/true);
+    // one piece: the pointer StoreGlobal keeps is the caller's, not the view's
+    BlobPiece g{gdata, glen};
+    CheckPointImpl(BlobView{&g, 1}, BlobView{}, /*lazy=*/true);
   }
 
   void LazyCheckPointFn(SerializeFn fn, void* ctx) override {
     // True lazy: not even serialization happens unless a failure needs the
     // blob (reference global_lazycheck, allreduce_robust.cc:527-535).
-    CheckPointImpl(nullptr, 0, nullptr, 0, /*lazy=*/true, fn, ctx);
+    CheckPointImpl(BlobView{}, BlobView{}, /*lazy=*/true, fn, ctx);
   }
 
   int VersionNumber() const override { return version_; }
@@ -921,14 +923,18 @@ class RobustEngine : public Engine {
 
   // --- checkpoint ---------------------------------------------------------
 
-  void CheckPointImpl(const char* gdata, size_t glen, const char* ldata,
-                      size_t llen, bool lazy, SerializeFn fn = nullptr,
-                      void* fn_ctx = nullptr) {
+  // The models arrive as views of the caller's memory (engine.h): the
+  // copies below, into global_ckpt_ and local_ckpt_ (and the ring's first
+  // send buffer), are the only ones made of them, and no pointer into the
+  // caller's memory outlives the call unless `lazy` asks for exactly that.
+  void CheckPointImpl(const BlobView& g, const BlobView& l, bool lazy,
+                      SerializeFn fn = nullptr, void* fn_ctx = nullptr) {
     double t0 = NowSec();
+    const bool has_local = l.present();
     if (!comm_.distributed()) {
-      StoreGlobal(gdata, glen, lazy, fn, fn_ctx);
-      if (ldata != nullptr) {
-        local_ckpt_.assign(ldata, ldata + llen);
+      StoreGlobal(g, lazy, fn, fn_ctx);
+      if (has_local) {
+        l.CopyTo(&local_ckpt_);
         local_ckpt_version_ = version_ + 1;
       }
       ++version_;
@@ -939,10 +945,10 @@ class RobustEngine : public Engine {
       // LocalModelCheck, allreduce_robust.cc:455-471).  The replica count
       // is a separate knob: rabit_local_replica=0 keeps the local model
       // un-replicated (lost if this process dies) but still checkpointed.
-      has_local_model_ = ldata != nullptr ? 1 : 0;
+      has_local_model_ = has_local ? 1 : 0;
       num_local_replica_ = has_local_model_ == 1 ? local_replica_cfg_ : 0;
     } else {
-      TRT_CHECK((ldata != nullptr) == (has_local_model_ == 1),
+      TRT_CHECK(has_local == (has_local_model_ == 1),
                 "checkpoint local-model usage must be consistent across "
                 "iterations");
     }
@@ -951,15 +957,15 @@ class RobustEngine : public Engine {
       RecoverExec(nullptr, kStInCheckPoint);
       TestHookAfterBarrier();
       if (num_local_replica_ == 0 || skip_replicate_) break;
-      if (ReplicateLocal(ldata, llen) == IoResult::kOk) break;
+      if (ReplicateLocal(l) == IoResult::kOk) break;
       CheckAndRecover();
     }
     // Commit: everything between the barriers is local, so every rank that
     // reaches a consensus round afterwards is observably pre- or
     // post-commit, never in between.
-    StoreGlobal(gdata, glen, lazy, fn, fn_ctx);
+    StoreGlobal(g, lazy, fn, fn_ctx);
     if (has_local_model_ == 1) {
-      local_ckpt_.assign(ldata, ldata + llen);
+      l.CopyTo(&local_ckpt_);
       local_ckpt_version_ = version_ + 1;
       if (skip_replicate_) {
         // A released straggler merges whatever staging completed before the
@@ -991,21 +997,22 @@ class RobustEngine : public Engine {
   // post-barrier / pre-commit window (see MockEngine, seqno spec -3).
   virtual void TestHookAfterBarrier() {}
 
-  void StoreGlobal(const char* gdata, size_t glen, bool lazy,
-                   SerializeFn fn = nullptr, void* fn_ctx = nullptr) {
+  void StoreGlobal(const BlobView& g, bool lazy, SerializeFn fn = nullptr,
+                   void* fn_ctx = nullptr) {
     if (lazy) {
       // Defer the copy — or, with a serializer callback, serialization
       // itself — until a failure actually needs the blob (reference
       // LazyCheckPoint/global_lazycheck, rabit.h:311-332): caller keeps the
-      // model alive and unchanged until the next checkpoint.
-      lazy_ptr_ = gdata;
-      lazy_len_ = glen;
+      // model alive and unchanged until the next checkpoint.  A lazy blob
+      // is one piece (LazyCheckPoint) or none (LazyCheckPointFn).
+      lazy_ptr_ = g.n > 0 ? static_cast<const char*>(g.pieces[0].data) : nullptr;
+      lazy_len_ = g.n > 0 ? g.pieces[0].len : 0;
       lazy_fn_ = fn;
       lazy_ctx_ = fn_ctx;
       has_lazy_ = true;
       global_ckpt_.clear();
     } else {
-      global_ckpt_.assign(gdata, gdata + glen);
+      g.CopyTo(&global_ckpt_);
       has_lazy_ = false;
       lazy_fn_ = nullptr;
     }
@@ -1032,10 +1039,11 @@ class RobustEngine : public Engine {
   // Staged, not committed: a loader served mid-checkpoint must see the
   // previous version's replicas (the reference double-buffers local_chkpt[2]
   // for the same reason).
-  IoResult ReplicateLocal(const char* ldata, size_t llen) {
+  IoResult ReplicateLocal(const BlobView& local) {
     const int n = comm_.world();
     staged_replicas_.clear();
-    std::string prev(ldata, ldata + llen);
+    std::string prev;
+    local.CopyTo(&prev);
     for (int k = 1; k <= num_local_replica_ && k < n; ++k) {
       uint64_t out_size = prev.size(), in_size = 0;
       IoResult r = comm_.RingExchange(&out_size, sizeof(out_size), &in_size,
@@ -1168,18 +1176,15 @@ class MockEngine : public RobustEngine {
     return RobustEngine::LoadCheckPoint(g, l);
   }
 
-  void CheckPoint(const char* gdata, size_t glen, const char* ldata,
-                  size_t llen) override {
+  using Engine::CheckPoint;
+  void CheckPoint(const BlobView& global, const BlobView& local) override {
     VerifyAt(kSeqCheckPoint, "CheckPoint");
-    ReportCheckpointStats(glen);
-    if (force_local_ && ldata == nullptr) {
-      // Reroute the global model through the local ring-replication path
-      // (reference force_local + DummySerializer/ComboSerializer,
-      // allreduce_mock.h:143-168).
-      RobustEngine::CheckPoint(gdata, glen, gdata, glen);
-    } else {
-      RobustEngine::CheckPoint(gdata, glen, ldata, llen);
-    }
+    ReportCheckpointStats(global.size());
+    // force_local reroutes the global model through the local
+    // ring-replication path (reference force_local +
+    // DummySerializer/ComboSerializer, allreduce_mock.h:143-168).
+    RobustEngine::CheckPoint(
+        global, force_local_ && !local.present() ? global : local);
   }
 
   void LazyCheckPoint(const char* gdata, size_t glen) override {

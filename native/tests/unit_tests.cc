@@ -313,6 +313,36 @@ TEST(solo_checkpoint_roundtrip) {
   Finalize();
 }
 
+TEST(checkpoint_pieces_are_joined_and_not_kept) {
+  // The gather entry: the engine's copy is the pieces in order, whatever
+  // the caller does to its memory afterwards; no local piece, or none with
+  // a byte in it, is no local model (as RabitCheckPoint's local_len == 0).
+  for (const char* kind : {"rabit_engine=empty", "rabit_engine=robust"}) {
+    const char* argv[] = {kind};
+    Init(1, const_cast<char**>(argv));
+    std::string head = "head|", body(100000, 'b'), tail = "|tail";
+    TrtBlobPiece g[3] = {{head.data(), head.size()},
+                         {body.data(), body.size()},
+                         {tail.data(), tail.size()}};
+    TrtBlobPiece l[2] = {{"", 0}, {"local", 5}};
+    CHECK_EQ(TrtCheckPointPieces(g, 3, l, 2), 0);
+    body.assign(body.size(), 'x');  // the caller's array, overwritten at once
+    char *gp = nullptr, *lp = nullptr;
+    trt_ulong gn = 0, ln = 0;
+    CHECK_EQ(RabitLoadCheckPoint(&gp, &gn, &lp, &ln), 1);
+    CHECK_TRUE(std::string(gp, gn) ==
+               "head|" + std::string(100000, 'b') + "|tail");
+    CHECK_TRUE(std::string(lp, ln) == "local");
+    TrtBlobPiece empty = {"", 0};
+    CHECK_EQ(TrtCheckPointPieces(g, 1, &empty, 1), 0);
+    CHECK_EQ(RabitLoadCheckPoint(&gp, &gn, &lp, &ln), 2);
+    CHECK_TRUE(std::string(gp, gn) == "head|");
+    CHECK_EQ(TrtCheckPointPieces(g, 1, nullptr, 0), 0);
+    CHECK_EQ(VersionNumber(), 3);
+    Finalize();
+  }
+}
+
 struct Pair {
   double sum;
   int64_t n;

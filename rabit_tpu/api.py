@@ -16,21 +16,24 @@ Python equivalent reads the caller frame via ``sys._getframe`` and passes
 from __future__ import annotations
 
 import pickle
+import struct
 import sys
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import numpy as np
 
 from rabit_tpu import compress, obs, quorum
 from rabit_tpu.config import Config
 from rabit_tpu.engine import create_engine
-from rabit_tpu.engine.base import MAX, MIN, SUM, BITOR, DTYPE_ENUM, Engine
+from rabit_tpu.engine.base import (
+    MAX, MIN, SUM, BITOR, DTYPE_ENUM, Engine, join_blob,
+)
 from rabit_tpu.profile import GLOBAL_STATS, CollectiveStats
 
 _engine: Engine | None = None
 # Durable-spill state (rabit_checkpoint_dir): the store, and the user-visible
 # version base when this job resumed a previous job's disk checkpoints.  The
-# base also travels inside every wrapped global blob (_wrap/_unwrap), so a
+# base also travels inside every checkpoint frame (_dumps/_unwrap), so a
 # worker restarted mid-job recovers it from the peer-served blob rather than
 # from process memory.
 _ckpt_store = None
@@ -48,15 +51,106 @@ _publisher = None
 _world_epoch: dict = {"epoch": 0, "world_size": 1}
 _rebalance_cbs: list[Callable[[dict, dict], None]] = []
 
+# A checkpointed model is a FRAME (doc/guide.md, "Checkpoint blobs"): the
+# model pickled with protocol 5, its large contiguous buffers (a numpy
+# array's memory) left OUT OF BAND.  In order:
+#
+#   magic "\xffRTF" | buffer count u32 | job's base version u64 |
+#   pickle length u64 | each buffer's length u64 ... | pickle | buffers ...
+#
+# (little-endian; 0xff opens no pickle of any protocol — one of protocol 2
+# and later starts with 0x80 — so a frame is never taken for a plain pickle
+# nor a plain pickle for a frame).  ``checkpoint`` never joins it: the engine
+# and the store take it as pieces (the head packed below, the pickle, each
+# buffer where the caller's array holds it) and the engine's copy into its
+# own storage is the only copy of the model's bytes a commit makes.  A
+# buffer under _OOB_MIN_BYTES stays in band, inside the pickle: below that a
+# piece (a PickleBuffer, a pointer/length pair across the C ABI, an append)
+# costs more than the copy it saves.
+_FRAME_MAGIC = b"\xffRTF"
+_FRAME_HEAD = struct.Struct("<4sIQQ")  # magic, buffers, base, pickle length
+_OOB_MIN_BYTES = 64 << 10
+
+# What an older build's spill put around the global blob to carry the base
+# (a second pickle, copying it); still read, never written.
 _WRAP_TAG = "__rabit_tpu_ckpt1__"
 
 
-def _wrap(base: int, gblob: bytes) -> bytes:
-    return pickle.dumps((_WRAP_TAG, base, gblob), protocol=pickle.HIGHEST_PROTOCOL)
+class _Frame(NamedTuple):
+    pieces: tuple  # the head, the pickle, then each out-of-band buffer
+    nbytes: int    # of all the pieces
+    oob: int       # of the buffers
+
+    @property
+    def buffers(self) -> int:
+        return len(self.pieces) - 2
 
 
-def _unwrap(blob: bytes) -> tuple[int, bytes]:
-    """Returns (base, inner_blob); plain blobs (store off) pass through."""
+def _dumps(model: Any, base: int = 0) -> _Frame:
+    """``model`` as the pieces of a frame.  The buffers are views of the
+    caller's memory, not copies."""
+    bufs: list[memoryview] = []
+
+    def in_band(pb: pickle.PickleBuffer) -> bool:
+        try:
+            view = pb.raw()
+        except BufferError:  # not contiguous: the pickler's to deal with
+            return True
+        if view.nbytes < _OOB_MIN_BYTES:
+            return True
+        bufs.append(view)
+        return False
+
+    body = pickle.dumps(model, protocol=5, buffer_callback=in_band)
+    sizes = [b.nbytes for b in bufs]
+    head = (_FRAME_HEAD.pack(_FRAME_MAGIC, len(bufs), base, len(body))
+            + struct.pack(f"<{len(bufs)}Q", *sizes))
+    oob = sum(sizes)
+    return _Frame((head, body, *bufs), len(head) + len(body) + oob, oob)
+
+
+def _frame_parts(blob) -> tuple[int, memoryview, list[memoryview]] | None:
+    """``(base, pickle, buffers)`` of a frame, as views of ``blob``; None
+    for anything else (a plain pickle)."""
+    view = memoryview(blob)
+    if view[:4] != _FRAME_MAGIC:
+        return None
+    try:
+        _magic, nbuf, base, nbody = _FRAME_HEAD.unpack_from(view)
+        sizes = struct.unpack_from(f"<{nbuf}Q", view, _FRAME_HEAD.size)
+    except struct.error as exc:
+        raise ValueError("rabit_tpu: truncated checkpoint frame") from exc
+    at = _FRAME_HEAD.size + 8 * nbuf
+    if at + nbody + sum(sizes) != len(view):
+        raise ValueError("rabit_tpu: checkpoint frame of the wrong length")
+    body = view[at:at + nbody]
+    at += nbody
+    bufs = []
+    for n in sizes:
+        bufs.append(view[at:at + n])
+        at += n
+    return base, body, bufs
+
+
+def _loads(blob) -> Any:
+    """The model of a blob: a frame, or the plain pickle of a lazy
+    checkpoint or an older build.  Each buffer is copied once, into a
+    bytearray of its own, so the arrays that come back are writable and
+    share nothing with the blob or with each other."""
+    parts = _frame_parts(blob)
+    if parts is None:
+        return pickle.loads(blob)
+    _base, body, bufs = parts
+    return pickle.loads(body, buffers=[bytearray(b) for b in bufs])
+
+
+def _unwrap(blob) -> tuple[int, Any]:
+    """``(base, blob to unpickle)`` of a global blob written with the spill
+    on: a frame carries the base in its head; an older build's wrapper is
+    opened; plain blobs (store off) pass through."""
+    parts = _frame_parts(blob)
+    if parts is not None:
+        return parts[0], blob
     try:
         obj = pickle.loads(blob)
     except Exception:  # noqa: BLE001 — not a pickle we wrote
@@ -507,9 +601,9 @@ def load_checkpoint(with_local: bool = False):
             with obs.span("rabit.load.unpickle",
                           nbytes=len(gblob or b"") + len(lblob or b"")):
                 if gblob is not None:
-                    gmodel = pickle.loads(gblob)
+                    gmodel = _loads(gblob)
                 if with_local and lblob is not None:
-                    lmodel = pickle.loads(lblob)
+                    lmodel = _loads(lblob)
     if with_local:
         return version, gmodel, lmodel
     return version, gmodel
@@ -531,25 +625,36 @@ def checkpoint(global_model: Any, local_model: Any = None) -> None:
     ``local_model`` (rank-specific state) costs ring replication; prefer
     ``global_model`` (reference notes, python/rabit.py:320-351).  With
     ``rabit_checkpoint_dir`` configured, the committed blobs are also
-    spilled to disk (whole-job preemption durability)."""
+    spilled to disk (whole-job preemption durability).
+
+    A model's large arrays are not copied on the way: each is pickled out
+    of band and read where it lies by the engine's copy (and the store's
+    write), which is done when this returns — the caller may overwrite its
+    arrays at once."""
     engine = _get_engine()
     # the spans' label: the version this commit makes (what is saved below
     # is named by the engine's own count, read after the commit)
     version = _ckpt_base + engine.version_number() + 1
     with obs.span("rabit.checkpoint", version=version) as sp:
         with obs.span("rabit.checkpoint.pickle") as pk:
-            gblob = pickle.dumps(global_model, protocol=pickle.HIGHEST_PROTOCOL)
-            lblob = None if local_model is None else pickle.dumps(
-                local_model, protocol=pickle.HIGHEST_PROTOCOL)
-            if _ckpt_store is not None:
-                gblob = _wrap(_ckpt_base, gblob)
-            n_local = 0 if lblob is None else len(lblob)
-            nbytes = len(gblob) + n_local
-            pk.set(nbytes=nbytes)
-        sp.set(nbytes_global=len(gblob), nbytes_local=n_local)
+            gframe = _dumps(global_model, _ckpt_base)
+            lframe = (None if local_model is None
+                      else _dumps(local_model, _ckpt_base))
+            frames = (gframe,) if lframe is None else (gframe, lframe)
+            gblob = gframe.pieces
+            lblob = None if lframe is None else lframe.pieces
+            nbytes = sum(f.nbytes for f in frames)
+            oob = sum(f.oob for f in frames)
+            pk.set(nbytes=nbytes, nbytes_oob=oob,
+                   buffers=sum(f.buffers for f in frames))
+        reg = obs.get_registry()
+        reg.counter("checkpoint_bytes_oob_total").inc(oob)
+        reg.counter("checkpoint_bytes_inband_total").inc(nbytes - oob)
+        sp.set(nbytes_global=gframe.nbytes,
+               nbytes_local=nbytes - gframe.nbytes)
         with obs.span("rabit.checkpoint.commit", nbytes=nbytes):
             engine.checkpoint(gblob, lblob)
-            _note_commit(engine, len(gblob))
+            _note_commit(engine, gframe.nbytes)
         if _ckpt_store is not None:
             # Persist AFTER the commit barrier: live ranks' disk versions
             # can then skew by at most one, which the store's keep-2
@@ -561,20 +666,24 @@ def checkpoint(global_model: Any, local_model: Any = None) -> None:
                 _ckpt_store.save(_ckpt_base + engine.version_number(), gblob,
                                  lblob, epoch=_world_epoch["epoch"])
         _publish_commit(engine, gblob)
-        # The pickles go back to the allocator here, under a name, and not
-        # at the return, under none: milliseconds for a 42 MB margin.
+        # The frames go back to the allocator here, under a name, and not
+        # at the return, under none.  Before PR 30 they were whole pickles
+        # and a 42 MB one was a munmap of milliseconds; now they are heads
+        # and views, and this should read about nothing.
         with obs.span("rabit.checkpoint.release", nbytes=nbytes):
-            del gblob, lblob
+            del gframe, lframe, frames, gblob, lblob
 
 
-def _publish_commit(engine: Engine, blob: bytes) -> None:
+def _publish_commit(engine: Engine, pieces: tuple) -> None:
     """Delivery-plane publish seam (doc/delivery.md): register the
     committed blob with the tracker AFTER commit (and after the durable
     spill, when on) so the plane only ever advertises bytes a resume
     could also serve.  Publishing is best-effort — a delivery outage
-    must never fail the training job's commit."""
+    must never fail the training job's commit.  The frame is joined into
+    one ``bytes`` here, and only with a publisher configured."""
     if _publisher is None:
         return
+    blob = join_blob(pieces)
     version = _ckpt_base + engine.version_number()
     with obs.span("rabit.checkpoint.publish", version=version,
                   nbytes=len(blob)):

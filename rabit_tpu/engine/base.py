@@ -46,6 +46,24 @@ DTYPE_ENUM = {
 }
 
 
+def blob_pieces(blob) -> tuple:
+    """A checkpoint blob as its pieces.  A blob crosses the engine seam
+    either whole (``bytes``, ``bytearray``, ``memoryview``) or as a sequence
+    of such pieces that, read in order, are the blob: ``rabit_tpu.checkpoint``
+    pickles out of band and hands over a small head plus the memory of the
+    caller's arrays, so that the engine's copy is the only one a commit
+    makes (doc/guide.md, "Checkpoint blobs")."""
+    if isinstance(blob, (bytes, bytearray, memoryview)):
+        return (blob,)
+    return tuple(blob)
+
+
+def join_blob(blob) -> bytes:
+    """The blob as one ``bytes``: the one copy an engine that keeps its
+    checkpoints as ``bytes`` makes of it."""
+    return b"".join(blob_pieces(blob))
+
+
 def numpy_reduce(op: int, dst: np.ndarray, src: np.ndarray) -> np.ndarray:
     """Apply a builtin reduction op elementwise (reference: op::Reducer,
     rabit-inl.h:95-102)."""
@@ -207,8 +225,12 @@ class Engine(ABC):
         checkpoint exists yet."""
 
     @abstractmethod
-    def checkpoint(self, global_blob: bytes, local_blob: bytes | None = None) -> None:
-        """Commit an iteration: store blobs, bump version."""
+    def checkpoint(self, global_blob, local_blob=None) -> None:
+        """Commit an iteration: store blobs, bump version.  Each blob is
+        bytes-like or a sequence of bytes-like pieces (:func:`blob_pieces`).
+        The engine copies it into storage of its own and, once this
+        returns, holds no reference into the caller's memory: the caller
+        may overwrite its arrays at once."""
 
     def lazy_checkpoint(self, get_global_blob: Callable[[], bytes]) -> None:
         """Defer serialization until a failure actually needs the blob

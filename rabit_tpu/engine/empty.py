@@ -16,7 +16,7 @@ from typing import Callable
 
 import numpy as np
 
-from rabit_tpu.engine.base import Engine
+from rabit_tpu.engine.base import Engine, join_blob
 
 
 class SoloEngine(Engine):
@@ -60,9 +60,9 @@ class SoloEngine(Engine):
             self._global_blob = bytes(self._lazy_thunk())
         return self._version, self._global_blob, self._local_blob
 
-    def checkpoint(self, global_blob: bytes, local_blob: bytes | None = None) -> None:
-        self._global_blob = bytes(global_blob)
-        self._local_blob = None if local_blob is None else bytes(local_blob)
+    def checkpoint(self, global_blob, local_blob=None) -> None:
+        self._global_blob = join_blob(global_blob)
+        self._local_blob = None if local_blob is None else join_blob(local_blob)
         self._version += 1
 
     def lazy_checkpoint(self, get_global_blob: Callable[[], bytes]) -> None:

@@ -19,7 +19,7 @@ from typing import Callable
 
 import numpy as np
 
-from rabit_tpu.engine.base import DTYPE_ENUM, Engine
+from rabit_tpu.engine.base import DTYPE_ENUM, Engine, blob_pieces
 
 _NATIVE_DIR = Path(__file__).resolve().parents[2] / "native"
 _LIB_PATH = _NATIVE_DIR / "libtpurabit.so"
@@ -34,6 +34,27 @@ _SERIALIZE_CB = ctypes.CFUNCTYPE(
     ctypes.c_int, ctypes.c_void_p,
     ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_uint64),
 )
+
+
+class _BlobPiece(ctypes.Structure):
+    """``TrtBlobPiece`` of the C ABI: one piece of a checkpoint blob."""
+
+    _fields_ = [("data", ctypes.c_void_p), ("len", ctypes.c_uint64)]
+
+
+def _piece_array(blob):
+    """``(TrtBlobPiece array, count)`` over the memory of ``blob``'s pieces,
+    none of it copied; (None, 0) for no blob.  The array keeps the pieces
+    alive as long as it lives itself: until the call that reads it returns."""
+    if blob is None:
+        return None, 0
+    # frombuffer reads any bytes-like piece, read-only ones included, and
+    # says where its memory lies
+    views = [np.frombuffer(p, np.uint8) for p in blob_pieces(blob)]
+    arr = (_BlobPiece * len(views))(
+        *((v.ctypes.data, v.nbytes) for v in views))
+    arr._views = views
+    return arr, len(views)
 
 
 def _build_lib() -> None:
@@ -75,6 +96,10 @@ def load_lib() -> ctypes.CDLL:
         ] * 3 + [ctypes.c_char_p]
         lib.RabitCheckPoint.argtypes = [
             ctypes.c_char_p, ctypes.c_uint64, ctypes.c_char_p, ctypes.c_uint64
+        ]
+        lib.TrtCheckPointPieces.argtypes = [
+            ctypes.POINTER(_BlobPiece), ctypes.c_uint64,
+            ctypes.POINTER(_BlobPiece), ctypes.c_uint64,
         ]
         lib.RabitLazyCheckPoint.argtypes = [ctypes.c_char_p, ctypes.c_uint64]
         lib.TrtLazyCheckPointFn.argtypes = [_SERIALIZE_CB, ctypes.c_void_p]
@@ -323,13 +348,13 @@ class NativeEngine(Engine):
         return version, gblob, lblob
 
     def checkpoint(self, global_blob, local_blob=None):
-        self._check(
-            self._lib.RabitCheckPoint(
-                global_blob, len(global_blob),
-                local_blob, 0 if local_blob is None else len(local_blob),
-            ),
-            "checkpoint",
-        )
+        # The gather entry: the engine's copy into its own strings reads
+        # the caller's memory piece by piece and is the only copy made;
+        # it holds nothing of the caller's once the call has returned.
+        garr, ng = _piece_array(global_blob)
+        larr, nl = _piece_array(local_blob)
+        self._check(self._lib.TrtCheckPointPieces(garr, ng, larr, nl),
+                    "checkpoint")
         self.obs_event("version_bump", version=self.version_number())
 
     def lazy_checkpoint(self, get_global_blob: Callable[[], bytes]) -> None:

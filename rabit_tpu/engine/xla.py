@@ -27,7 +27,9 @@ from typing import Callable
 
 import numpy as np
 
-from rabit_tpu.engine.base import BITOR, MAX, MIN, SUM, Engine, numpy_reduce
+from rabit_tpu.engine.base import (
+    BITOR, MAX, MIN, SUM, Engine, join_blob, numpy_reduce,
+)
 
 
 class XlaEngine(Engine):
@@ -387,8 +389,8 @@ class XlaEngine(Engine):
         # Host-memory checkpoint per process; multi-host recovery of a
         # preempted VM is the native robust engine's job (hybrid deployment:
         # XLA data plane + robust TCP control plane).
-        self._global_blob = bytes(global_blob)
-        self._local_blob = None if local_blob is None else bytes(local_blob)
+        self._global_blob = join_blob(global_blob)
+        self._local_blob = None if local_blob is None else join_blob(local_blob)
         self._lazy_thunk = None
         self._version += 1
 

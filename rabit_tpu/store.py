@@ -22,9 +22,12 @@ import os
 import re
 import struct
 import zlib
+from bisect import bisect_right
+from itertools import accumulate
 from pathlib import Path
 
 from rabit_tpu import obs
+from rabit_tpu.engine.base import blob_pieces
 
 _GLOBAL_RE = re.compile(r"^global_r(\d+)_v(\d+)\.bin$")
 _KEEP = 2  # two-phase commit skews live ranks by at most one version
@@ -70,14 +73,32 @@ _PROBE_SLICES = 16
 _PROBE_MAX_RATIO = 0.75
 
 
-def _probe_sample(blob: bytes) -> bytes:
-    """The probe's sample of a blob larger than ``_PROBE_BYTES``, cut
-    through a memoryview: the blob itself is not copied."""
-    view = memoryview(blob)
+def _byte_views(blob) -> tuple:
+    """A blob, whole or in pieces (``engine.base.blob_pieces``), as views
+    of bytes: a length is a byte count and a slice copies nothing."""
+    return tuple(memoryview(p).cast("B") for p in blob_pieces(blob))
+
+
+def _probe_sample(blob) -> bytes:
+    """The probe's sample of a blob larger than ``_PROBE_BYTES``, whole or
+    in pieces, cut through memoryviews: the blob itself is neither joined
+    nor copied, and the sample is the one its joined bytes would give."""
+    pieces = _byte_views(blob)
+    ends = list(accumulate(len(p) for p in pieces))
     size = _PROBE_BYTES // _PROBE_SLICES
-    last = len(view) - size
-    starts = (i * last // (_PROBE_SLICES - 1) for i in range(_PROBE_SLICES))
-    return b"".join(view[a:a + size] for a in starts)
+    last = ends[-1] - size
+    out = []
+    for i in range(_PROBE_SLICES):
+        a = i * last // (_PROBE_SLICES - 1)
+        b = a + size
+        k = bisect_right(ends, a)  # the piece that holds byte a
+        while a < b:
+            at = a - (ends[k] - len(pieces[k]))
+            cut = pieces[k][at:at + b - a]
+            out.append(cut)
+            a += len(cut)
+            k += 1
+    return b"".join(out)
 
 
 class CheckpointStore:
@@ -126,9 +147,12 @@ class CheckpointStore:
 
     # -- writes -------------------------------------------------------------
 
-    def save(self, version: int, gblob: bytes, lblob: bytes | None,
-             epoch: int = 0) -> None:
+    def save(self, version: int, gblob, lblob=None, epoch: int = 0) -> None:
         """Persist one committed checkpoint atomically; prune old versions.
+        Each blob is bytes-like or a sequence of bytes-like pieces
+        (``engine.base.blob_pieces``): the probe samples, the crc runs
+        over and the file is written from the pieces where they lie, and
+        nothing of them is referenced once this returns.
         A nonzero ``epoch`` (elastic worlds) is recorded in the frame
         header (RTC3) and read back by :meth:`epoch_of`."""
         self._write(self._gpath(version), gblob, epoch=epoch)
@@ -159,37 +183,47 @@ class CheckpointStore:
                 p.unlink(missing_ok=True)
                 self._cache.pop(p, None)
 
-    def _encode(self, blob: bytes) -> tuple[int, bytes, float | None]:
-        """``(codec id, payload, probe)``: the configured codec applied to
-        ``blob`` where the probe says it pays, else the blob as it is
-        under codec id 0.  ``probe`` is the encoded/raw ratio the decision
-        was taken on — a sample's, or the whole blob's where the blob is
-        no larger than the probe — and None with no codec configured."""
+    def _encode(self, pieces: tuple,
+                raw: int) -> tuple[int, tuple, float | None]:
+        """``(codec id, payload pieces, probe)``: the configured codec
+        applied to the blob (``pieces``, ``raw`` bytes in all) where the
+        probe says it pays — the one place the pieces are joined, beside a
+        codec that costs thirty times the join — else the pieces as they
+        are under codec id 0.  ``probe`` is the encoded/raw ratio the
+        decision was taken on — a sample's, or the whole blob's where the
+        blob is no larger than the probe — and None with no codec
+        configured."""
         codec = self._codec
         if codec is None:
-            return 0, blob, None
-        if len(blob) <= _PROBE_BYTES:
+            return 0, pieces, None
+        if raw <= _PROBE_BYTES:
             # no dearer than the probe: encode it whole, keep the smaller
-            payload = codec.encode_bytes(blob)
-            probe = len(payload) / max(len(blob), 1)
-            keep = len(payload) < len(blob)
+            payload = codec.encode_bytes(b"".join(pieces))
+            probe = len(payload) / max(raw, 1)
+            keep = len(payload) < raw
         else:
-            probe = len(codec.encode_bytes(_probe_sample(blob))) / _PROBE_BYTES
+            probe = len(codec.encode_bytes(
+                _probe_sample(pieces))) / _PROBE_BYTES
             keep = probe <= _PROBE_MAX_RATIO
-            payload = codec.encode_bytes(blob) if keep else blob
+            payload = codec.encode_bytes(b"".join(pieces)) if keep else None
         if not keep:
-            return 0, blob, probe
+            return 0, pieces, probe
         from rabit_tpu.compress import observe
 
-        observe(codec.name, raw=len(blob), wire=len(payload))
-        return codec.codec_id, payload, probe
+        observe(codec.name, raw=raw, wire=len(payload))
+        return codec.codec_id, (payload,), probe
 
-    def _write(self, path: Path, blob: bytes, epoch: int = 0) -> None:
-        with obs.span("rabit.spill.encode", raw=len(blob)) as sp:
-            codec_id, payload, probe = self._encode(blob)
-            crc = zlib.crc32(payload)
+    def _write(self, path: Path, blob, epoch: int = 0) -> None:
+        pieces = _byte_views(blob)
+        raw = sum(len(p) for p in pieces)
+        with obs.span("rabit.spill.encode", raw=raw) as sp:
+            codec_id, payload, probe = self._encode(pieces, raw)
+            crc, encoded = 0, 0
+            for p in payload:
+                crc = zlib.crc32(p, crc)
+                encoded += len(p)
             # the codec actually applied to this frame, and what decided it
-            sp.set(encoded=len(payload),
+            sp.set(encoded=encoded,
                    codec=self._codec.name if codec_id else "identity")
             if probe is not None:
                 sp.set(probe=round(probe, 4))
@@ -200,21 +234,25 @@ class CheckpointStore:
             # Elastic job: the frame carries the committing world epoch.
             # Codec id 0 (identity) keeps the layout uniform when the
             # store is configured uncompressed.
-            header = _HDR3.pack(_MAGIC3, codec_id, crc, len(payload), epoch)
+            header = _HDR3.pack(_MAGIC3, codec_id, crc, encoded, epoch)
         elif self._codec is None:
-            header = _HDR.pack(_MAGIC, crc, len(blob))
+            header = _HDR.pack(_MAGIC, crc, encoded)
         else:
-            header = _HDR2.pack(_MAGIC2, codec_id, crc, len(payload))
+            header = _HDR2.pack(_MAGIC2, codec_id, crc, encoded)
         tmp = path.with_suffix(".tmp")
-        with obs.span("rabit.spill.write",
-                      bytes=len(header) + len(payload)):
+        with obs.span("rabit.spill.write", bytes=len(header) + encoded):
             with open(tmp, "wb") as f:
                 f.write(header)
-                f.write(payload)
+                for p in payload:
+                    f.write(p)
                 f.flush()
                 os.fsync(f.fileno())
             os.replace(tmp, path)  # atomic: readers see old or new, never torn
-        self._cache[path] = blob
+        # The memo of verified reads holds nothing of a write: the pieces
+        # are the caller's memory, which it may overwrite, and a copy kept
+        # here would be a model a file.  A read in this life (the delivery
+        # plane's; none on a trainer's path) goes to the disk and is checked.
+        self._cache.pop(path, None)
         # The rename itself must survive a host crash too — fsync the
         # directory entry, or the "durable" newest version can vanish on
         # power loss while the prune of the older one persisted.
@@ -243,8 +281,8 @@ class CheckpointStore:
     def _read_checked(self, path: Path) -> bytes | None:
         """The DECODED payload, or None when missing/torn/corrupt.
         Verified reads are memoized so the resume path (latest_valid ->
-        has -> load) does not re-read multi-MB blobs; writes/prunes keep
-        the memo fresh.  Both frame generations read back: RTC2 carries a
+        has -> load) does not re-read multi-MB blobs; writes/prunes drop
+        what they make stale.  Both frame generations read back: RTC2 carries a
         codec byte (decode after the crc passes), RTC1 is the legacy
         uncompressed layout — a new job resumes an old job's spill
         unchanged."""

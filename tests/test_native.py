@@ -40,6 +40,38 @@ def test_native_solo_roundtrip(native_lib):
     rt.finalize()
 
 
+def test_native_checkpoint_takes_pieces(native_lib):
+    """`NativeEngine.checkpoint` through the gather entry of the C ABI
+    (`TrtCheckPointPieces`): pieces of any bytes-like kind, read-only and
+    empty ones included, are copied once, in order, into the engine's own
+    strings; a whole blob is one piece; no local piece is no local model;
+    and nothing of the caller's is referenced once the call has returned."""
+    import sys
+
+    import rabit_tpu as rt
+    from rabit_tpu import api
+
+    rt.init(rabit_engine="native")
+    eng = api._engine
+    arr = np.arange(50_000, dtype=np.float32)
+    ro = np.arange(7, dtype=np.int64)
+    ro.flags.writeable = False
+    refs = sys.getrefcount(arr), sys.getrefcount(ro)
+    pieces = (b"head", bytearray(b"|body|"), memoryview(arr).cast("B"), b"",
+              memoryview(ro).cast("B"))
+    eng.checkpoint(pieces, [b"lo", b"cal"])
+    del pieces
+    assert (sys.getrefcount(arr), sys.getrefcount(ro)) == refs
+    want = b"head|body|" + arr.tobytes() + ro.tobytes()
+    arr[:] = 0                                    # the caller's, overwritten
+    assert eng.load_checkpoint() == (1, want, b"local")
+    eng.checkpoint(b"whole", None)
+    assert eng.load_checkpoint() == (2, b"whole", None)
+    eng.checkpoint([b"g"], [b"", b""])            # no byte in it: no local model
+    assert eng.load_checkpoint() == (3, b"g", None)
+    rt.finalize()
+
+
 def run_cluster(num_workers, worker_args=(), max_restarts=0, timeout=90,
                 extra_env=None):
     import os

@@ -85,9 +85,28 @@ def test_ring_path_recovery():
 
 def test_local_checkpoint_recovery():
     """Per-rank local models ring-replicate and restore (reference
-    local_recover_10_10k)."""
-    cluster = run_cluster(4, ["niter=4", "local=1", "mock=2,2,3,0"])
+    local_recover_10_10k).  Both models carry a 256 KiB ndarray, pickled
+    out of band: the ring replicates a frame's pieces as the engine joined
+    them, and the restarted rank unpickles what its successors held."""
+    cluster = run_cluster(4, ["niter=4", "local=1", "array_kb=256",
+                              "mock=2,2,3,0"])
     assert cluster.restarts["2"] == 1
+
+
+@pytest.mark.parametrize("args", [
+    ["mock=1,2,1,0"],
+    ["local=1", "mock=0,1,4,0;3,3,0,0"],
+    ["local=1", "mock=1,1,-3,0"],
+    ["array_kb=32", "mock=2,2,0,0"],
+], ids=["global", "local, two deaths", "commit window", "arrays in band"])
+def test_restarted_worker_is_served_a_frame(args):
+    """A restarted worker gets its peers' copy of a checkpoint frame — the
+    head, the pickle and the out-of-band buffers the committing rank handed
+    the engine in pieces — and unpickles it: arrays equal, float32 and
+    writable (the worker checks), after the committing rank has overwritten
+    its own."""
+    cluster = run_cluster(4, ["niter=4", "array_kb=256", *args])
+    assert sum(cluster.restarts.values()) == args[-1].count(";") + 1
 
 
 def test_local_model_zero_replicas():

@@ -281,3 +281,88 @@ def test_api_checkpoint_spills_margin_raw_and_forest_deflated(tmp_path):
     assert version == 1
     assert np.array_equal(got_forest["trees"], forest["trees"])
     assert got_margin.tobytes() == margin.tobytes()
+
+
+# -- a blob handed over in pieces (PR 30) -----------------------------------
+
+
+def in_pieces(blob: bytes) -> list:
+    """The blob cut where a frame's pieces would not be so unkind: a
+    one-byte head, a cut inside the probe's first slice, an empty piece, a
+    piece that ends one byte before the blob does; kinds mixed."""
+    cuts = sorted({0, min(1, len(blob)), min(1000, len(blob)),
+                   len(blob) // 3, max(len(blob) - 1, 0), len(blob)})
+    cuts.insert(len(cuts) // 2, cuts[len(cuts) // 2])     # an empty piece
+    kinds = (bytes, bytearray, memoryview,
+             lambda b: np.frombuffer(b, np.uint8).data)   # as PickleBuffer.raw()
+    return [kinds[i % 4](blob[a:b])
+            for i, (a, b) in enumerate(zip(cuts, cuts[1:]))]
+
+
+@pytest.mark.parametrize("blob,codec_id,calls", BLOBS)
+def test_pieces_are_framed_as_their_joined_bytes(tmp_path, blob, codec_id,
+                                                 calls):
+    """The probe samples, the crc runs over and the write takes the pieces
+    where they lie; the file is the one the joined bytes give — same codec
+    byte, same crc, same payload — the codec never sees the whole of a blob
+    it leaves raw, and the span's fields are the same."""
+    whole = CheckpointStore(str(tmp_path / "whole"), 0)
+    whole.save(1, blob, blob, epoch=2)
+    want = encode_spans()[-1]
+    s = counting_store(tmp_path / "pieces")
+    s.save(1, in_pieces(blob), tuple(in_pieces(blob)), epoch=2)
+    assert s._codec.calls == calls * 2
+    got = encode_spans()[-1]
+    assert {k: got[k] for k in ("raw", "encoded", "codec", "probe")} == \
+        {k: want[k] for k in ("raw", "encoded", "codec", "probe")}
+    for kind in ("global", "local"):
+        raw = frame(tmp_path / "pieces", 1, kind)
+        assert raw == frame(tmp_path / "whole", 1, kind)
+        assert raw[4] == codec_id
+    fresh = CheckpointStore(str(tmp_path / "pieces"), 0)
+    assert fresh.load_global(1) == blob and fresh.load_local(1) == blob
+
+
+def test_probe_sample_of_pieces_is_the_joined_blobs():
+    blob = noise(300_000) + bytes(200_000) + noise(524_289, seed=2)
+    assert store._probe_sample(in_pieces(blob)) == store._probe_sample(blob)
+    many = [blob[a:a + 997] for a in range(0, len(blob), 997)]
+    assert store._probe_sample(many) == store._probe_sample(blob)
+
+
+def test_a_write_keeps_nothing_of_the_callers_memory(tmp_path):
+    """The caller overwrites its array when `save` has returned: what reads
+    back, in this life too, is what was written (the memo of verified reads
+    holds no view of a caller's memory)."""
+    s = CheckpointStore(str(tmp_path), 0)
+    margin = np.frombuffer(noise(1 << 20), np.float32).copy()
+    want = margin.tobytes()
+    s.save(1, (b"head", margin.data), [b"head", margin.data])
+    margin[:] = 0.0
+    assert s.load_global(1) == s.load_local(1) == b"head" + want
+    assert s.has(1) and s.latest_valid() == 1
+
+
+def test_api_spill_is_the_frame_the_engine_holds(tmp_path):
+    """Through ``rabit_tpu.checkpoint`` with the spill on: each file's
+    payload, its crc and the codec chosen are what the frame's joined bytes
+    give — the bytes the engine keeps for a peer."""
+    import rabit_tpu as rt
+    from rabit_tpu import api
+
+    forest = (np.zeros(40_000, np.int32), np.zeros(40_000, np.float32))
+    margin = np.frombuffer(noise(1 << 20), np.float32)
+    rt.init(rabit_checkpoint_dir=str(tmp_path / "job"))
+    try:
+        rt.checkpoint(forest, margin)
+        _v, gblob, lblob = api._engine.load_checkpoint()
+    finally:
+        rt.finalize()
+    assert gblob[:4] == lblob[:4] == api._FRAME_MAGIC
+    CheckpointStore(str(tmp_path / "joined"), 0).save(1, gblob, lblob)
+    for kind, codec_id in (("global", 1), ("local", 0)):
+        raw = frame(tmp_path / "job", 1, kind)
+        assert raw == frame(tmp_path / "joined", 1, kind) and raw[4] == codec_id
+        _magic, _id, crc, n = store._HDR2.unpack_from(raw)
+        assert zlib.crc32(raw[store._HDR2.size:]) == crc
+    assert frame(tmp_path / "job", 1, "local")[store._HDR2.size:] == lblob

@@ -32,6 +32,13 @@ Worker args (k=v on the command line, all also forwarded to the engine):
                    --blob-mb; the reference streams recovery through its
                    chunked data loops for exactly this regime,
                    allreduce_robust.cc:861-973)
+    array_kb=K     carry a float32 ndarray of K KiB in the global model
+                   ("weights") and, with local=1, another in the local one
+                   ("state"), closed-form per version (and rank): large
+                   enough, they are pickled out of band, so the frame a
+                   peer serves a restarted worker has buffers behind its
+                   pickle (rabit_tpu/api.py); what loads must be equal,
+                   float32 and writable
     stop_at=K      every worker exits cleanly right after checkpoint K —
                    simulates a whole-job preemption for the durable-spill
                    resume tests (pair with rabit_checkpoint_dir=...)
@@ -86,6 +93,19 @@ def main() -> int:
         # Deterministic per-version content: recovery must reproduce the
         # exact bytes, so a truncated/corrupted serve cannot pass.
         return bytes([ver & 0xFF]) * int(blob_mb * (1 << 20))
+    array_kb = int(getarg("array_kb", "0"))
+
+    def array_for(ver: int, who: int) -> np.ndarray:
+        return (np.arange(array_kb * 256) % 1000 + 1000 * ver + who
+                ).astype(np.float32)
+
+    def check_array(got, ver: int, who: int, what: str) -> None:
+        want = array_for(ver, who)
+        check(isinstance(got, np.ndarray) and got.dtype == want.dtype
+              and np.array_equal(got, want), f"{what} at version {ver}")
+        check(got.flags.writeable, f"{what} came back read-only")
+        got += 1.0  # ours to write: nothing else reads this memory
+
     stop_at = int(getarg("stop_at", "0"))
     use_local = getarg("local", "0") == "1"
     use_lazy = getarg("lazy", "0") == "1"
@@ -129,6 +149,10 @@ def main() -> int:
               f"blob mismatch at version {version}")
     if use_local:
         check(lmodel["rank"] == rank, f"local model {lmodel} not mine")
+    if array_kb and version > 0:
+        check_array(model["weights"], version, -1, "global weights")
+        if use_local and "state" in lmodel:
+            check_array(lmodel["state"], version, rank, "local state")
     if not first_life:
         # Restarted life: stamp the moment state was recovered from peers
         # (tools/recovery_bench.py diffs this against the launcher's
@@ -198,13 +222,23 @@ def main() -> int:
         model = {"iter": it + 1, "history": model["history"] + [it]}
         if blob_mb:
             model["blob"] = blob_for(it + 1)
+        if array_kb:
+            model["weights"] = array_for(it + 1, -1)
         if use_local:
             lmodel = {"rank": rank, "iter": it + 1}
+            if array_kb:
+                lmodel["state"] = array_for(it + 1, rank)
             rt.checkpoint(model, lmodel)
         elif use_lazy:
             rt.lazy_checkpoint(model)
         else:
             rt.checkpoint(model)
+        if array_kb and not use_lazy:
+            # the caller may overwrite its arrays as soon as the commit has
+            # returned: a peer must be served what was committed
+            model["weights"][:] = -7.0
+            if use_local:
+                lmodel["state"][:] = -7.0
         check(rt.version_number() == it + 1, "version after checkpoint")
         if stop_at and it + 1 == stop_at:
             # Whole-job preemption simulation: every worker reaches this
