@@ -318,10 +318,13 @@ def train_round_fused(
     """One boosting round via the fused Pallas kernels (ops.boost): routing,
     split lookup, and histogram accumulation run in one streaming pass per
     level, so rows cross HBM depth+1 times per round (depth histogram
-    passes + one routing-only leaf pass) instead of ~3x depth.  When the
+    passes + one routing-only leaf pass) instead of ~3x depth.  A matrix
+    wider than one tile of codes (``ops.boost.TILE_FEATS``) is swept once a
+    feature tile a level, and routed in a pass of its own a level.  When the
     round is lowered, each level leaves one ``gbdt.hist_plan`` span with
     what ``ops.boost.hist_plan`` reckoned for its kernel, and the gauge
-    ``gbdt_hist_rows_streamed_per_round`` takes rows x passes.
+    ``gbdt_hist_rows_streamed_per_round`` takes rows x (tile sweeps of every
+    level + routing passes).
 
     ``xb3`` is the pre-blocked quantized matrix from ``ops.boost.block_rows``
     (built once per fit).  ``combine`` is the histogram allreduce hook
@@ -368,6 +371,7 @@ def train_round_fused(
         with jax.named_scope(f"level{d}"), obs.span(
                 "gbdt.hist_plan", level=d, nodes_built=plan.nodes_built,
                 m_rows=plan.m_rows, m_tiles=plan.m_tiles,
+                feat_tiles=plan.feat_tiles, tile_feats=plan.tile_feats,
                 acc_block_bytes=plan.acc_block_bytes,
                 vmem_bytes=plan.vmem_bytes):
             local, node3 = boost.hist_level(xb3, node3, g3, h3, feat, thr,
@@ -379,9 +383,13 @@ def train_round_fused(
             feat, thr, _ = best_splits(hist, cfg)
         feats.append(jnp.zeros(max_nodes, jnp.int32).at[: 2 ** d].set(feat))
         thrs.append(jnp.zeros(max_nodes, jnp.int32).at[: 2 ** d].set(thr))
-    # the root's pass, one a level, the leaves' routing pass
+    # a sweep a feature tile a level, the root's included; the leaves'
+    # routing pass, and one a level below the root where routing is a pass
+    # of its own (more tiles than one)
+    tiles = boost.hist_plan(xb3.shape[2], cfg.n_bins, 0, block).feat_tiles
+    passes = cfg.depth * tiles + (1 if tiles == 1 else cfg.depth)
     obs.get_registry().gauge("gbdt_hist_rows_streamed_per_round").set(
-        (cfg.depth + 1) * xb3.shape[0] * block)
+        passes * xb3.shape[0] * block)
     # Leaf (g, h) masses come straight off the final combined histogram
     # (split_child_masses) — already globally reduced, so no leaf collective
     # and no histogram work in the last row pass (depth collectives per

@@ -18,11 +18,17 @@ row block's gradient matrix L (one g column + one h column per node) is
 contracted against per-feature bin indicators built in VMEM; f32 gradients
 are split hi/lo into two bfloat16 matmuls (error ~2^-16-relative).
 
-``hist_plan`` reckons what a level asks of the chip from the shape alone:
-the accumulator ``(2 * 2**level, F * B_eff)`` float32 is ONE block held
-across the row grid, and the plan asks Mosaic for the scoped VMEM that
-block and the row blocks take where they pass its default, or refuses the
-shape by name where they pass what the kernel may ask for.
+``hist_plan`` reckons what a level asks of the chip from the shape alone.
+Up to ``TILE_FEATS`` features (one 128-lane tile of blocked codes) the
+accumulator ``(2 * 2**level, F * B_eff)`` float32 is ONE block held across
+the row grid, and routing rides the histogram's sweep.  A wider matrix is
+walked in feature tiles: a grid over (feature tile, row block), the row
+axis innermost, one tile's accumulator block resident across its row
+sweep; the feature a node splits on may lie in any tile, so routing is a
+pass of its own there (``route_level``, its code block tiled too), once a
+level.  The plan asks Mosaic for the scoped VMEM that the blocks take
+where they pass its default, or refuses the shape by name where no tiling
+holds it.
 
 All wrappers take pre-blocked arrays (nb, R, ...) so padding/reshaping
 happens once per fit, not once per level.  ``interpret=True`` runs the
@@ -65,6 +71,18 @@ def _pick_fc(n_feat: int, n_bins: int) -> int:
     return min(n_feat, max(1, 1792 // _bins_eff(n_bins)))
 
 
+def _pick_tile_fc(n_bins: int) -> int:
+    """Features per matmul group inside a ``TILE_FEATS``-feature tile (N =
+    2048 lanes: 16 at up to 128 bins, 8 at 256), a power of two so that the
+    groups of a tile are all whole.  At F = 2000 x 64 bins groups of 16
+    were 6 % faster than of 8 at levels 0-4 and 4 % at level 7, groups of
+    4 12 % slower (my chip run, PR 31)."""
+    return max(1, 2048 // _bins_eff(n_bins))
+
+
+#: features of one tile of blocked codes (its 128 lanes): up to here a level
+#: is one block, beyond it the kernels walk the features a tile at a time
+TILE_FEATS = 128
 #: rows of one MXU tile: the stacked gradient matrix (hi and lo plane, g and
 #: h, a node: 4 * 2**level rows) fills it at level 5
 MXU_ROWS = 128
@@ -83,8 +101,10 @@ class HistPlan(NamedTuple):
 
     level: int
     m_pad: int            # accumulator rows: g and h of every node
-    acc_block_bytes: int  # the one (m_pad, F * B_eff) float32 block
-    vmem_bytes: int       # that block, the row blocks twice, VMEM_STACK
+    acc_block_bytes: int  # ONE tile's (m_pad, tile_feats * B_eff) float32 block
+    vmem_bytes: int       # what one tile's kernel takes: hist_plan's text
+    tile_feats: int       # features a tile: all of F where one tile holds them
+    feat_tiles: int       # tiles a level; each sweeps every row block
 
     @property
     def nodes_built(self) -> int:
@@ -101,26 +121,41 @@ class HistPlan(NamedTuple):
 
 
 def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int) -> HistPlan:
-    """A level's accumulator block and the scoped VMEM its kernel takes,
-    from the shape alone; ValueError for a shape the kernel cannot hold.
+    """A level's feature tiles, one tile's accumulator block and the scoped
+    VMEM its kernel takes, from the shape alone; ValueError for a shape no
+    tiling holds.
 
-    ``vmem_bytes`` bounds what Mosaic calls the kernel's scoped allocation:
-    the accumulator once (its block index never changes along the row grid),
-    the row blocks twice — codes at 128 lanes, node in and out, g and h at
-    one lane padded to 128 — and ``VMEM_STACK``.  (F = 67, level 7: 16.75 +
-    5 MiB of blocks, Mosaic's own "21.75M", 24.75 with its stack.)  The MXU
-    walks M a 128-row tile at a time by itself."""
+    Up to ``TILE_FEATS`` features are one tile.  ``vmem_bytes`` then bounds
+    what Mosaic calls the kernel's scoped allocation: the accumulator once
+    (its block index never changes along the row grid), the row blocks twice
+    — codes at 128 lanes, node in and out, g and h at one lane padded to 128
+    — and ``VMEM_STACK``.  (F = 67, level 7: 16.75 + 5 MiB of blocks,
+    Mosaic's own "21.75M", 24.75 with its stack.)  The MXU walks M a 128-row
+    tile at a time by itself.
+
+    A wider matrix goes in tiles of ``TILE_FEATS`` features, the last one
+    ragged.  A tile's accumulator block is counted twice (its index moves
+    with the tile, so one is written back while the next fills), the row
+    blocks twice — a tile's codes, node, g and h; routing is a pass of its
+    own — and ``VMEM_STACK``.  (F = 2000 at 64 bins, level 7: 16 MiB a
+    block, 44 MiB.)"""
     m_pad = _round_up(2 * 2 ** level, 8)
-    acc = m_pad * n_feat * _bins_eff(n_bins) * 4
-    need = (acc + 2 * 4 * block_rows * (_round_up(n_feat, 128) + 4 * 128)
-            + VMEM_STACK)
+    tile = min(n_feat, TILE_FEATS)
+    tiles = -(-n_feat // tile)
+    acc = m_pad * tile * _bins_eff(n_bins) * 4
+    if tiles == 1:
+        blocks = acc + 2 * 4 * block_rows * (_round_up(n_feat, 128) + 4 * 128)
+    else:
+        blocks = 2 * acc + 2 * 4 * block_rows * (tile + 3 * 128)
+    need = blocks + VMEM_STACK
     if need > VMEM_MOST:
         raise ValueError(
             f"hist_plan: level {level} of F={n_feat} features x {n_bins} bins "
-            f"at {block_rows}-row blocks does not fit: its accumulator block "
-            f"is {acc} bytes and the kernel needs {need} of {VMEM_MOST} bytes "
-            f"of VMEM (a depth of {level + 1} is one level too many)")
-    return HistPlan(level, m_pad, acc, need)
+            f"at {block_rows}-row blocks does not fit: the accumulator block "
+            f"of one {tile}-feature tile is {acc} bytes and the kernel needs "
+            f"{need} of {VMEM_MOST} bytes of VMEM (a depth of {level + 1} is "
+            "one level too many)")
+    return HistPlan(level, m_pad, acc, need, tile, tiles)
 
 
 def _encode_bf16(L):
@@ -175,7 +210,7 @@ def _encode_i8(L):
 
 
 def _accum(xb_blk, L, out_ref, *, n_bins: int, n_feat: int, fc: int, i8: bool,
-           r_split: int = 1):
+           r_split: int = 1, feats_left=None):
     """out_ref[m, f*Beff+b] += sum_r L[r, m] * [xb_blk[r, f] == b], via the
     MXU: the encoded gradient planes are contracted against per-feature-
     group bin-indicator matrices built in VMEM.
@@ -188,7 +223,13 @@ def _accum(xb_blk, L, out_ref, *, n_bins: int, n_feat: int, fc: int, i8: bool,
     build (VPU), giving Mosaic's scheduler explicit room to run them
     concurrently.  The indicator rebuild was modeled as co-dominant with
     the int8-rate matmul (older chip figure, not re-measured); the
-    ablation's rsplit rows (RESULTS/final_pass.jsonl) measured no gain."""
+    ablation's rsplit rows (RESULTS/final_pass.jsonl) measured no gain.
+
+    ``feats_left`` is for a feature tile (``xb_blk`` holds its ``n_feat``
+    code lanes): the matrix's features from this tile's first one on, a
+    scalar of the grid.  A group that starts at or past it — the ragged
+    last tile's tail, whose code lanes hold nothing — is skipped; lanes
+    past it inside a group fall into columns the wrapper cuts off."""
     be = _bins_eff(n_bins)
     l2, onehot_dtype, acc_dtype, decode = (_encode_i8 if i8 else _encode_bf16)(L)
     r = xb_blk.shape[0]
@@ -202,8 +243,8 @@ def _accum(xb_blk, L, out_ref, *, n_bins: int, n_feat: int, fc: int, i8: bool,
     # (RESULTS/narrow_compare_rejection.txt; the local jax.export gate
     # accepts both, so only on-chip compiles catch this).
     b_iota = lax.broadcasted_iota(jnp.int32, (rs, be), 1)
-    for gi in range(0, n_feat, fc):
-        k = min(fc, n_feat - gi)
+
+    def group(gi, k):
         # Sum the RAW accumulators across sub-blocks and decode once:
         # decode is linear, so this is bitwise identical to the unsplit
         # path for i8 (int32 adds commute exactly) and costs one decode
@@ -221,6 +262,13 @@ def _accum(xb_blk, L, out_ref, *, n_bins: int, n_feat: int, fc: int, i8: bool,
             acc2 = part if acc2 is None else acc2 + part
         out_ref[:, gi * be : (gi + k) * be] += decode(acc2)
 
+    for gi in range(0, n_feat, fc):
+        k = min(fc, n_feat - gi)
+        if feats_left is None:
+            group(gi, k)
+        else:
+            pl.when(gi < feats_left)(functools.partial(group, gi, k))
+
 
 def _gradient_matrix(node, g, h, *, n_nodes: int, m_pad: int):
     """L[r, m]: g_r at column node_r, h_r at column n_nodes+node_r."""
@@ -233,15 +281,22 @@ def _gradient_matrix(node, g, h, *, n_nodes: int, m_pad: int):
     return jnp.where(sel, val, 0.0)
 
 
-def _route(xb_blk, node, feat_row, thr_row, *, p_pad: int, n_feat: int):
-    """node' = 2*node + [x[feat[node]] > thr[node]] — split-table lookup and
-    feature select via lane-masked reductions (no gathers)."""
+def _split_of(node, feat_row, thr_row, *, p_pad: int):
+    """(feat[node], thr[node]) — the split-table lookup via lane-masked
+    reductions (no gathers)."""
     r = node.shape[0]
     p_iota = lax.broadcasted_iota(jnp.int32, (r, p_pad), 1)
     pm = node == p_iota  # (R, P) one-hot over parent nodes
     fsel = jnp.sum(jnp.where(pm, feat_row, 0), axis=1, keepdims=True)
     tsel = jnp.sum(jnp.where(pm, thr_row, 0), axis=1, keepdims=True)
-    f_iota = lax.broadcasted_iota(jnp.int32, (r, n_feat), 1)
+    return fsel, tsel
+
+
+def _route(xb_blk, node, feat_row, thr_row, *, p_pad: int, n_feat: int):
+    """node' = 2*node + [x[feat[node]] > thr[node]] — split-table lookup and
+    feature select via lane-masked reductions (no gathers)."""
+    fsel, tsel = _split_of(node, feat_row, thr_row, p_pad=p_pad)
+    f_iota = lax.broadcasted_iota(jnp.int32, (node.shape[0], n_feat), 1)
     xv = jnp.sum(jnp.where(f_iota == fsel, xb_blk, 0), axis=1, keepdims=True)
     return node * 2 + (xv > tsel).astype(jnp.int32)
 
@@ -280,6 +335,28 @@ def _level_kernel(xb_ref, node_ref, g_ref, h_ref, feat_ref, thr_ref,
            r_split=r_split)
 
 
+# -- one feature tile of a level wider than TILE_FEATS: histogram only ------
+
+
+def _tile_kernel(xb_ref, *refs, n_feat, tile, n_nodes, n_bins, m_pad, fc, i8,
+                 r_split=1):
+    """Grid (feature tile, row block), the rows innermost: ``out_ref`` is
+    this tile's accumulator block, zeroed at its first row block.  The rows
+    come routed: ``refs`` is their node ids (not at the root, where every
+    row is at node 0), g, h and the output."""
+    *node_ref, g_ref, h_ref, out_ref = refs
+
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    node = (node_ref[0][0] if node_ref
+            else jnp.zeros((g_ref.shape[1], 1), jnp.int32))
+    L = _gradient_matrix(node, g_ref[0], h_ref[0], n_nodes=n_nodes, m_pad=m_pad)
+    _accum(xb_ref[0], L, out_ref, n_bins=n_bins, n_feat=tile, fc=fc, i8=i8,
+           r_split=r_split, feats_left=n_feat - pl.program_id(0) * tile)
+
+
 # -- routing-only pass (leaf assignment without histogramming) -------------
 
 
@@ -287,6 +364,34 @@ def _route_kernel(xb_ref, node_ref, feat_ref, thr_ref, node_out_ref, *,
                   p_pad, n_feat):
     node_out_ref[0] = _route(xb_ref[0], node_ref[0], feat_ref[0:1],
                              thr_ref[0:1], p_pad=p_pad, n_feat=n_feat)
+
+
+def _route_tile_kernel(xb_ref, node_ref, feat_ref, thr_ref, node_out_ref,
+                       fsel_ref, tsel_ref, *, p_pad, tile):
+    """Grid (row block, feature tile), the tiles innermost: the code of the
+    feature a row's node splits on lies in one tile, so the lane-masked sums
+    of all tiles add up to it in ``node_out_ref``, which the last tile turns
+    into the node one level down.  The split-table lookup is the row
+    block's, made at its first tile and kept in scratch.  (Lanes past F in
+    the ragged last tile match no feature.)"""
+    t = pl.program_id(1)
+    node = node_ref[0]
+
+    @pl.when(t == 0)
+    def _first():
+        fsel_ref[...], tsel_ref[...] = _split_of(
+            node, feat_ref[0:1], thr_ref[0:1], p_pad=p_pad)
+        node_out_ref[0] = jnp.zeros_like(node)
+
+    f_iota = lax.broadcasted_iota(jnp.int32, (node.shape[0], tile), 1)
+    node_out_ref[0] += jnp.sum(
+        jnp.where(f_iota == fsel_ref[...] - t * tile, xb_ref[0], 0), axis=1,
+        keepdims=True)
+
+    @pl.when(t == pl.num_programs(1) - 1)
+    def _turn():
+        node_out_ref[0] = node * 2 + (
+            node_out_ref[0] > tsel_ref[...]).astype(jnp.int32)
 
 
 # -- final pass: route to leaves + margin update in one kernel -------------
@@ -319,6 +424,12 @@ def route_margin_level(xb3, node3, margin3, feat, thr, leaf, *, depth: int,
     from a 2**depth-entry table poorly on TPU, while the in-kernel
     lane-masked sum is a few VPU ops per row."""
     nb, R, F = xb3.shape
+    if F > TILE_FEATS:
+        # wider than one tile of codes: the tiled routing pass, and the
+        # leaf lookup left to XLA
+        node3 = route_level(xb3, node3, feat, thr, depth=depth,
+                            interpret=interpret)
+        return margin3 + leaf[node3], node3
     n_prev = 2 ** (depth - 1)
     n_leaves = 2 ** depth
     p_pad = _round_up(n_prev, 128)
@@ -357,6 +468,22 @@ def route_level(xb3, node3, feat, thr, *, depth: int, interpret: bool = False):
     p_pad = _round_up(n_prev, 128)
     featp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(feat)
     thrp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(thr)
+    if F > TILE_FEATS:
+        tab = pl.BlockSpec((8, p_pad), lambda i, t: (0, 0))
+        row = pl.BlockSpec((1, R, 1), lambda i, t: (i, 0, 0))
+        return pl.pallas_call(
+            functools.partial(_route_tile_kernel, p_pad=p_pad, tile=TILE_FEATS),
+            grid=(nb, -(-F // TILE_FEATS)),
+            in_specs=[
+                pl.BlockSpec((1, R, TILE_FEATS), lambda i, t: (i, 0, t)),
+                row, tab, tab,
+            ],
+            out_specs=row,
+            out_shape=jax.ShapeDtypeStruct((nb, R, 1), jnp.int32),
+            scratch_shapes=[pltpu.VMEM((R, 1), jnp.int32)] * 2,
+            interpret=interpret,
+            name=f"route_level_d{depth}",
+        )(xb3, node3, featp, thrp)
     return pl.pallas_call(
         functools.partial(_route_kernel, p_pad=p_pad, n_feat=F),
         grid=(nb,),
@@ -378,6 +505,42 @@ def route_level(xb3, node3, feat, thr, *, depth: int, interpret: bool = False):
 _blk = lambda R, k: pl.BlockSpec((1, R, k), lambda i: (i, 0, 0))
 
 
+def _vmem_params(plan: HistPlan):
+    """Ask Mosaic for the plan's scoped VMEM where its default may not hold
+    the kernel; nothing where it does."""
+    if plan.vmem_bytes <= VMEM_DEFAULT:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=plan.vmem_bytes)
+
+
+def _hist_tiles(plan: HistPlan, xb3, node3, g3, h3, *, n_bins, interpret,
+                mxu_i8, r_split, name):
+    """A level wider than one tile: the ``(m_pad, F, B)`` sums of rows that
+    come routed (``node3`` None at the root), a sweep of every row block a
+    feature tile.  The kernel's output is whole tiles wide; the ragged last
+    tile's tail is cut off here."""
+    nb, R, F = xb3.shape
+    be = _bins_eff(n_bins)
+    tile, tiles, m_pad = plan.tile_feats, plan.feat_tiles, plan.m_pad
+    row = pl.BlockSpec((1, R, 1), lambda t, i: (i, 0, 0))
+    rows = [a for a in (node3, g3, h3) if a is not None]
+    out = pl.pallas_call(
+        functools.partial(
+            _tile_kernel, n_feat=F, tile=tile, n_nodes=plan.nodes_built,
+            n_bins=n_bins, m_pad=m_pad, fc=_pick_tile_fc(n_bins), i8=mxu_i8,
+            r_split=r_split),
+        grid=(tiles, nb),
+        in_specs=[pl.BlockSpec((1, R, tile), lambda t, i: (i, 0, t))]
+        + [row] * len(rows),
+        out_specs=pl.BlockSpec((m_pad, tile * be), lambda t, i: (0, t)),
+        out_shape=jax.ShapeDtypeStruct((m_pad, tiles * tile * be), jnp.float32),
+        interpret=interpret,
+        name=name,
+        compiler_params=_vmem_params(plan),
+    )(xb3, *rows)
+    return out.reshape(m_pad, tiles * tile, be)[:, :F, :n_bins]
+
+
 @functools.partial(
     jax.jit, static_argnames=("n_bins", "interpret", "mxu_i8", "r_split")
 )
@@ -386,6 +549,12 @@ def hist_level0(xb3, g3, h3, *, n_bins: int, interpret: bool = False,
     """Root histogram; [1, F, B, 2].  ``r_split``: see _accum."""
     nb, R, F = xb3.shape
     _check_r_split(R, r_split)
+    plan = hist_plan(F, n_bins, 0, R)
+    if plan.feat_tiles > 1:
+        out = _hist_tiles(plan, xb3, None, g3, h3, n_bins=n_bins,
+                          interpret=interpret, mxu_i8=mxu_i8, r_split=r_split,
+                          name="hist_level0")
+        return jnp.stack([out[0:1], out[1:2]], axis=-1)
     be = _bins_eff(n_bins)
     fc = _pick_fc(F, n_bins)
     out = pl.pallas_call(
@@ -411,7 +580,9 @@ def hist_level(xb3, node3, g3, h3, feat, thr, *, depth: int, n_bins: int,
                r_split: int = 1):
     """Route one level down and histogram; returns
     ([2**depth, F, B, 2], node3').  ``feat``/``thr`` are the level-(depth-1)
-    split tables, shape [2**(depth-1)].  ``r_split``: see _accum.  The
+    split tables, shape [2**(depth-1)].  ``r_split``: see _accum.  Wider
+    than ``TILE_FEATS`` features the two are two kernels: ``route_level``'s
+    tiled pass, then a histogram sweep a feature tile (``hist_plan``).  The
     kernel asks for ``hist_plan``'s scoped VMEM where Mosaic's default might
     not hold it (F = 67 from level 5 on), and is the same kernel elsewhere.
     Compiled on its own, level 7 at F = 67 is refused at the default, on the
@@ -422,6 +593,14 @@ def hist_level(xb3, node3, g3, h3, feat, thr, *, depth: int, n_bins: int,
     plan = hist_plan(F, n_bins, depth, R)
     be = _bins_eff(n_bins)
     n_nodes, m_pad = plan.nodes_built, plan.m_pad
+    if plan.feat_tiles > 1:
+        node_out = route_level(xb3, node3, feat, thr, depth=depth,
+                               interpret=interpret)
+        out = _hist_tiles(plan, xb3, node_out, g3, h3, n_bins=n_bins,
+                          interpret=interpret, mxu_i8=mxu_i8, r_split=r_split,
+                          name=f"hist_level_d{depth}")
+        hist = jnp.stack([out[:n_nodes], out[n_nodes : 2 * n_nodes]], axis=-1)
+        return hist, node_out
     n_prev = 2 ** (depth - 1)
     p_pad = _round_up(n_prev, 128)
     fc = _pick_fc(F, n_bins)
@@ -448,9 +627,7 @@ def hist_level(xb3, node3, g3, h3, feat, thr, *, depth: int, n_bins: int,
         ],
         interpret=interpret,
         name=f"hist_level_d{depth}",
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=plan.vmem_bytes,
-        ) if plan.vmem_bytes > VMEM_DEFAULT else None,
+        compiler_params=_vmem_params(plan),
     )(xb3, node3, g3, h3, featp, thrp)
     out = out.reshape(m_pad, F, be)[..., :n_bins]
     hist = jnp.stack([out[:n_nodes], out[n_nodes : 2 * n_nodes]], axis=-1)

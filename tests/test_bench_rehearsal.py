@@ -22,6 +22,19 @@ REPO = Path(__file__).resolve().parents[1]
 BENCH = REPO / "benchmark"
 M = json.loads((REPO / "BENCHMARK.json").read_text())
 CPU_TRACE = {"device_plane": "^/host:CPU$", "op_lines": ["^tf_XLA"]}
+#: rows of a rehearsal.  The width is the configuration's, so that at 2,000
+#: features all sixteen feature tiles and the routing passes are rehearsed;
+#: there an interpreted round is 4 s a row block on a CPU, and two blocks
+#: (several row blocks a tile sweep) keep the case about a minute
+ROWS = {"epsilon-400k": 2048}
+#: the window of the case with a kill in it.  The second life has to come up
+#: (the launcher's respawn, jax, the data, the restore, the round loaded from
+#: the cache) and commit a round before the window closes, or nothing of it
+#: can be compared.  Alone that takes 4 s; beside five other xdist workers on
+#: eight cores it took 13.2 s, a 12 s window closed before the first resumed
+#: round and the case failed on ``correct`` (``resume_mismatch``: no trees
+#: digest at restore) at the driver and here alike (PR 30, PR 31)
+KILL_WINDOW_S = 30
 
 CALL = """
 import json, sys
@@ -50,9 +63,9 @@ def test_cell_rehearsal(cell, tmp_path):
     kill = {"kill_after_commit": 5} if "kill" in cell["traffic"] else {}
     cache = tmp_path / "cache"
     r = run_main(cell["name"],
-                 {"rows": 6000, "traffic": kill,
+                 {"rows": ROWS.get(cell["config"], 6000), "traffic": kill,
                   "plant": {"trace_rules": CPU_TRACE}},
-                 seconds=12 if kill else 6,
+                 seconds=KILL_WINDOW_S if kill else 6,
                  env_extra={"JAX_COMPILATION_CACHE_DIR": str(cache)})
     assert r.returncode == 0, r.stdout[-3000:] + r.stderr[-3000:]
     line = json.loads(r.stdout.strip().splitlines()[-1])

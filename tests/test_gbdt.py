@@ -174,9 +174,13 @@ def _bench_like(rng, n, f, bins):
 #: node.  From a warm margin candidate splits that part a node's rows alike
 #: tie exactly in real numbers and each path's rounding picks its own:
 #: test_fused_round_from_a_warm_margin_follows_float64 covers those rounds.
+#: "tiled" is wider than one tile of codes (ops.boost.TILE_FEATS): three
+#: feature tiles, the last one ragged (300 = 2 x 128 + 44), eight row blocks
+#: a tile sweep, routing a pass of its own a level; one round, as "criteo".
 FUSED_SHAPES = {
     "small": (600, 5, 16, 3, 256, 3, 0.3),
     "criteo": (2500, 67, 256, 8, 1024, 1, 0.1),
+    "tiled": (2000, 300, 64, 5, 256, 1, 0.1),
 }
 
 
@@ -254,18 +258,21 @@ def _follow_round(cfg, xb, g, h, feature, threshold):
     return gap, leaf, node
 
 
-@pytest.mark.parametrize("f,depth", [(28, 6), (67, 8)], ids=["higgs", "criteo"])
-def test_fused_round_from_a_warm_margin_follows_float64(f, depth):
+@pytest.mark.parametrize("f,depth,bins", [(28, 6, 256), (67, 8, 256),
+                                          (300, 6, 64)],
+                         ids=["higgs", "criteo", "tiled"])
+def test_fused_round_from_a_warm_margin_follows_float64(f, depth, bins):
     """Three rounds on sixteen row blocks, so the second and third start
     from a margin that is not zero and no sum is exact: every split the
     fused round takes is the float64 scatter histogram's best along the
     same tree (an exact tie apart), and its leaves and margin are that
     tree's to the hi/lo-bf16 error.  Readings at this seed: gain gap 0.0,
-    leaves 2.7e-6 of their rms, margin 9.4e-7."""
+    leaves 2.7e-6 of their rms, margin 9.4e-7.  "tiled" is three feature
+    tiles with a ragged last one (ops.boost.TILE_FEATS)."""
     from rabit_tpu.ops import boost
 
     rng = np.random.RandomState(3)
-    n, bins, rounds = 16384, 256, 3
+    n, rounds = 16384, 3
     xb, y = _bench_like(rng, n, f, bins)
     cfg = gbdt.GBDTConfig(n_features=f, n_trees=rounds, depth=depth,
                           n_bins=bins, learning_rate=0.1)
@@ -342,6 +349,128 @@ def test_hist_plan():
     with pytest.raises(ValueError, match=r"level 9 of F=67 .*bytes") as e:
         boost.hist_plan(67, 256, 9, 1024)
     assert str(boost.VMEM_MOST) in str(e.value)
+    for f, d in ((28, 5), (67, 7), (128, 3)):
+        p = boost.hist_plan(f, 256, d, 1024)
+        assert (p.tile_feats, p.feat_tiles) == (f, 1)
+
+
+def test_hist_plan_tiles_a_wide_matrix():
+    """Wider than one tile of codes the plan walks the features in tiles of
+    128, the last one ragged, and reckons ONE tile's accumulator block,
+    counted twice: Epsilon (F 2000, 64 bins padded to 128 lanes, depth 8)
+    is sixteen tiles a level, a 16 MiB block and 44 MiB asked at level
+    7 — a width whose one-block accumulator (250 MiB there) nothing holds.
+    Level 8 is refused by name, with the tile's block in the text."""
+    from rabit_tpu.ops import boost
+
+    assert boost._pick_tile_fc(64) == boost._pick_tile_fc(128) == 16
+    assert boost._pick_tile_fc(256) == 8
+    assert boost.hist_plan(129, 64, 3, 1024).feat_tiles == 2
+    for d in range(8):
+        p = boost.hist_plan(2000, 64, d, 1024)
+        assert (p.tile_feats, p.feat_tiles) == (128, 16)
+        assert p.m_pad == max(8, 2 * 2 ** d)
+        assert p.acc_block_bytes == p.m_pad * 128 * 128 * 4
+        assert p.vmem_bytes == (2 * p.acc_block_bytes + 2 * 4 * 1024 * 4 * 128
+                                + boost.VMEM_STACK)
+    assert p.acc_block_bytes == 16 << 20 and p.vmem_bytes == 44 << 20
+    assert boost.VMEM_DEFAULT < p.vmem_bytes <= boost.VMEM_MOST
+    assert boost.hist_plan(2000, 64, 5, 1024).vmem_bytes > boost.VMEM_DEFAULT
+    assert boost.hist_plan(2000, 64, 4, 1024).vmem_bytes <= boost.VMEM_DEFAULT
+    with pytest.raises(ValueError, match=r"level 8 of F=2000 .*128-feature "
+                                         r"tile is 33554432 bytes"):
+        boost.hist_plan(2000, 64, 8, 1024)
+
+
+@pytest.mark.parametrize("d", [0, 3])
+def test_tiled_histogram_is_bitwise_the_one_block_histogram(d, monkeypatch):
+    """F = 300 at 64 bins, eight row blocks: three feature tiles, the last
+    one of 44 features, against the same level as ONE accumulator block
+    (what the plan gives with a tile as wide as the matrix).  A lane's sum
+    over the rows does not depend on which tile holds the lane, nor on how
+    many features share a matmul, so the two are equal bit for bit; and the
+    tiled routing pass moves every row where the one-block kernel does."""
+    from rabit_tpu.ops import boost
+
+    rng = np.random.RandomState(29 + d)
+    n, F, B, block = 2048, 300, 64, 256
+    xb = jnp.asarray(rng.randint(0, B, size=(n, F)), jnp.int32)
+    g = jnp.asarray(rng.randn(n), jnp.float32)
+    h = jnp.asarray(rng.rand(n), jnp.float32)
+    xb3, g3, h3 = (boost.block_rows(a, block)[0] for a in (xb, g, h))
+    if d == 0:
+        level = functools.partial(boost.hist_level0.__wrapped__, xb3, g3, h3,
+                                  n_bins=B, interpret=True)
+    else:
+        n_prev = 1 << (d - 1)
+        node3, _ = boost.block_rows(
+            jnp.asarray(rng.randint(0, n_prev, size=n), jnp.int32), block)
+        # splits in every tile, the ragged one's last feature among them
+        feat = jnp.asarray([0, 299, 130, 255][:n_prev], jnp.int32)
+        thr = jnp.asarray(rng.randint(8, B - 8, size=n_prev), jnp.int32)
+        level = functools.partial(boost.hist_level.__wrapped__, xb3, node3, g3,
+                                  h3, feat, thr, depth=d, n_bins=B,
+                                  interpret=True)
+    assert boost.hist_plan(F, B, d, block).feat_tiles == 3
+    tiled = level()
+    monkeypatch.setattr(boost, "TILE_FEATS", 512)
+    assert boost.hist_plan(F, B, d, block).feat_tiles == 1
+    one_block = level()
+    for a, b in zip(jax.tree.leaves(tiled), jax.tree.leaves(one_block)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    hist = tiled if d == 0 else tiled[0]
+    assert hist.shape == (2 ** d, F, B, 2)
+    assert np.asarray(hist)[..., 1].sum(-1).min() > 0     # every node got rows
+
+
+def test_tiled_round_at_the_child_weight_floor_follows_the_reference():
+    """``min_child_weight`` 100 (Epsilon's min_sum_hessian_in_leaf) on
+    4,096 rows x 300 features: from a zero margin a row weighs 0.25, so the
+    root's extreme bins are invalid candidates, at level 3 a node of some
+    512 rows has no valid candidate at all, and the floor decides every
+    level.  The first round is the plain reference's
+    (benchmark/harness/reference.py, numpy float64, the same floor) node for
+    node; a second, from a warm margin, is followed by it within the
+    benchmark's limits."""
+    import importlib.util
+    import pathlib
+
+    from rabit_tpu.ops import boost
+
+    path = pathlib.Path(__file__).resolve().parents[1] / \
+        "benchmark" / "harness" / "reference.py"
+    spec = importlib.util.spec_from_file_location("bench_reference", path)
+    reference = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reference)
+
+    rng = np.random.RandomState(5)
+    n, f, bins, depth, rounds = 4096, 300, 64, 4, 2
+    xb, y = _bench_like(rng, n, f, bins)
+    cfg = gbdt.GBDTConfig(n_features=f, n_trees=rounds, depth=depth,
+                          n_bins=bins, learning_rate=0.1,
+                          min_child_weight=100.0)
+    xb3, _ = boost.block_rows(xb, 1024)
+    step = jax.jit(functools.partial(gbdt.train_round_fused, cfg=cfg,
+                                     interpret=True))
+    s = gbdt.init_state(cfg, n)
+    for _ in range(rounds):
+        s = step(s, xb3, y)
+    forest = jax.tree.map(np.asarray, s.forest)
+    params = reference.Params(depth, bins, 0.1, 1.0, 100.0)
+    codes, y64 = np.asarray(xb).astype(np.uint8), np.asarray(y, np.float64)
+    free = reference.boost_rounds(codes, y64, params, 1, procs=1)
+    np.testing.assert_array_equal(forest.feature[0], free.feature[0])
+    np.testing.assert_array_equal(forest.threshold[0], free.threshold[0])
+    # the floor bit: the root split off the extreme bins, level 3 not at all
+    assert 8 <= forest.threshold[0, 0, 0] < bins - 8
+    assert not forest.feature[0, 3].any() and not forest.threshold[0, 3].any()
+    followed = reference.boost_rounds(codes, y64, params, rounds, procs=1,
+                                      follow=tuple(forest))
+    assert max(followed.gain_gap) <= 2e-5 and max(followed.leaf_gap) <= 1e-4
+    np.testing.assert_allclose(
+        np.linalg.norm(np.asarray(s.margin, np.float64)),
+        followed.margin_norm[-1], rtol=5e-6)
+
 
 
 @pytest.mark.parametrize("d", [6, 7])
@@ -375,35 +504,43 @@ def test_hist_level_at_the_criteo_width_matches_scatter(d):
                                rtol=1e-4, atol=1e-4)
 
 
-def test_hist_plan_span_and_rows_streamed_gauge():
+@pytest.mark.parametrize("F,bins,tiles", [(67, 256, 1), (2000, 64, 16)],
+                         ids=["criteo", "epsilon"])
+def test_hist_plan_span_and_rows_streamed_gauge(F, bins, tiles):
     """Lowering the round at the Criteo shape leaves one ``gbdt.hist_plan``
     span a level with what the plan reckoned, and the gauge takes rows x
-    passes over the row grid: depth histogram passes and the leaves'."""
+    passes over the row grid: depth histogram passes and the leaves'.  At
+    the Epsilon shape (lowering only) the span says sixteen tiles of 128
+    features and ONE tile's block, and the gauge counts a sweep a tile a
+    level and a routing pass a level."""
     import time
 
     from rabit_tpu import obs
     from rabit_tpu.ops import boost
 
-    n, F, depth, block = 2048, 67, 8, 1024
-    cfg = gbdt.GBDTConfig(n_features=F, n_trees=1, depth=depth, n_bins=256)
+    n, depth, block = 2048, 8, 1024
+    cfg = gbdt.GBDTConfig(n_features=F, n_trees=1, depth=depth, n_bins=bins)
     t0 = time.time()
     jax.jit(functools.partial(gbdt.train_round_fused, cfg=cfg, interpret=True)
             ).lower(gbdt.init_state(cfg, n),
                     jnp.zeros((n // block, block, F), jnp.int32),
                     jnp.zeros(n, jnp.float32))
     gauge = obs.get_registry().gauge("gbdt_hist_rows_streamed_per_round")
-    assert gauge.value == (depth + 1) * n
+    assert gauge.value == (depth + 1 if tiles == 1 else depth * tiles + depth) * n
     spans = [e.fields for e in obs.get_recorder().snapshot()
              if e.ts >= t0 and e.kind == "span"
              and e.fields.get("name") == "gbdt.hist_plan"]
     assert [s["level"] for s in spans] == list(range(1, depth))
     for s in spans:
-        plan = boost.hist_plan(F, 256, s["level"], block)
+        plan = boost.hist_plan(F, bins, s["level"], block)
         assert (s["nodes_built"], s["m_rows"], s["m_tiles"],
                 s["acc_block_bytes"], s["vmem_bytes"]) == (
             plan.nodes_built, plan.m_rows, plan.m_tiles,
             plan.acc_block_bytes, plan.vmem_bytes)
+        assert (s["feat_tiles"], s["tile_feats"]) == (tiles, min(F, 128))
     assert spans[-1]["nodes_built"] == 128 and spans[-1]["m_tiles"] == 4
+    if tiles > 1:
+        assert spans[-1]["acc_block_bytes"] == 256 * 128 * 128 * 4
 
 
 def test_train_round_fused_i8_matches_reference():

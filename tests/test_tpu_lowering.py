@@ -223,6 +223,36 @@ def test_criteo_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d):
     assert (str(plan.vmem_bytes) in text) == (plan.vmem_bytes > boost.VMEM_DEFAULT)
 
 
+# benchmark/configs/epsilon-400k.json: 400,000 rows (391 row blocks) x 2,000
+# features at 64 bins, depth 8: sixteen feature tiles a level, the last one
+# of 80 features, and routing a pass of its own.
+NB_EPSILON, F_EPSILON, B_EPSILON = 391, 2000, 64
+
+
+@pytest.mark.parametrize("d", (0, 5, 6, 7))
+def test_epsilon_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d):
+    """The tiled level kernels at the cell's block count: the root, the
+    first level that asks for its VMEM (5: two 4 MiB blocks of one tile)
+    and the two whose gradient matrix passes one MXU tile; level 7 asks for
+    44 MiB.  Each is two custom calls below the root: the routing pass and
+    the sweep of the feature tiles."""
+    plan = boost.hist_plan(F_EPSILON, B_EPSILON, d, R)
+    assert (plan.feat_tiles, plan.tile_feats) == (16, 128)
+    xb3, g3, node3 = _blocked(one_chip, NB_EPSILON, F_EPSILON)
+    if d == 0:
+        c = _compile(functools.partial(boost.hist_level0, n_bins=B_EPSILON),
+                     xb3, g3, g3)
+    else:
+        tab = _sds((1 << (d - 1),), jnp.int32, one_chip)
+        c = _compile(functools.partial(boost.hist_level, depth=d,
+                                       n_bins=B_EPSILON),
+                     xb3, node3, g3, g3, tab, tab)
+    text = c.as_text()
+    assert text.count("tpu_custom_call") >= (2 if d else 1)
+    assert (str(plan.vmem_bytes) in text) == (plan.vmem_bytes > boost.VMEM_DEFAULT)
+    assert plan.vmem_bytes > boost.VMEM_DEFAULT or d == 0
+
+
 def test_route_level_compiles_for_v5e(one_chip, no_compile_cache):
     xb3, _g3, node3 = _blocked(one_chip)
     tab = _sds((1 << (DEPTH - 1),), jnp.int32, one_chip)
@@ -286,7 +316,7 @@ def _state_shapes(cfg, n, sh, margin_sh=None):
 @pytest.mark.slow
 @pytest.mark.parametrize("rows,f,depth", (
     (ROWS, F, DEPTH), (1_024_000, F, DEPTH), (256_000, F, DEPTH),
-    (NB_CRITEO * R, F_CRITEO, 8)))
+    (NB_CRITEO * R, F_CRITEO, 8), (400_000, F_EPSILON, 8)))
 def test_whole_fused_round_compiles_for_v5e(one_chip, no_compile_cache, rows,
                                             f, depth):
     """The round of the benchmark's ``fused-armed`` cells.  At exactly
@@ -294,8 +324,11 @@ def test_whole_fused_round_compiles_for_v5e(one_chip, no_compile_cache, rows,
     generates six times the code of the 1000-block program; cause not
     established (ROADMAP S3).  The last case is the benchmark's
     criteo-1tb-share round (22-32 s and 9.4 GB of temporaries as compiled
-    here; on the chip the allocator's peak is 7.49 GB; PR 27)."""
-    cfg = gbdt.GBDTConfig(n_features=f, n_trees=8, depth=depth, n_bins=B)
+    here; on the chip the allocator's peak is 7.49 GB; PR 27), and the one
+    after it the epsilon-400k round (28 s, 47.6 MB of code, 4.29 GB of
+    temporaries beside 3.21 GB of codes; PR 31)."""
+    bins = B_EPSILON if f == F_EPSILON else B
+    cfg = gbdt.GBDTConfig(n_features=f, n_trees=8, depth=depth, n_bins=bins)
     xb3, _, _ = _blocked(one_chip, -(-rows // R), f)
     y = _sds((rows,), jnp.float32, one_chip)
     c = _compile(functools.partial(gbdt.train_round_fused, cfg=cfg),
