@@ -27,6 +27,7 @@ with ``engine.allreduce`` over the native TCP engine.
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -211,6 +212,22 @@ def split_child_masses(hist: jax.Array, feat: jax.Array, thr: jax.Array) -> jax.
     return jnp.stack([left, right], axis=1).reshape(2 * n_nodes, 2)
 
 
+def smaller_child(hist: jax.Array, feat: jax.Array, thr: jax.Array) -> jax.Array:
+    """Which child of each node to BUILD at a level that derives the other
+    from ``parent - built`` (ops.boost.hist_plan): 1 where the right child's
+    hessian mass at the chosen split, ``H - HL``, is under the left's
+    (ties: left), ``[n_nodes]`` int32.  Read off the COMBINED histogram, so
+    every shard and a replay choose alike.  The lighter child is built
+    because a derived node carries its parent's absolute summation error: on
+    the heavier child that is at most its own error to a small factor, on a
+    child of a few rows it would be many times its own."""
+    h = hist[jnp.arange(hist.shape[0]), feat, :, 1]           # [nodes, B]
+    left = jnp.arange(h.shape[1]) <= thr[:, None]
+    hl = jnp.sum(jnp.where(left, h, 0.0), -1)
+    hr = jnp.sum(jnp.where(left, 0.0, h), -1)
+    return (hr < hl).astype(jnp.int32)
+
+
 # -- training --------------------------------------------------------------
 
 
@@ -320,18 +337,32 @@ def train_round_fused(
     level, so rows cross HBM depth+1 times per round (depth histogram
     passes + one routing-only leaf pass) instead of ~3x depth.  A matrix
     wider than one tile of codes (``ops.boost.TILE_FEATS``) is swept once a
-    feature tile a level, and routed in a pass of its own a level.  When the
-    round is lowered, each level leaves one ``gbdt.hist_plan`` span with
-    what ``ops.boost.hist_plan`` reckoned for its kernel, and the gauge
+    feature tile a level, and routed in a pass of its own a level.
+
+    Up to level 4 every node's histogram is accumulated.  From level 5 on
+    (where every node built would stack a full MXU tile of gradient matrix:
+    ``ops.boost.hist_plan``, from the shape alone) the kernel accumulates
+    ONE child of every parent — the one with the smaller hessian mass at the
+    parent's split, by the combined histogram (``smaller_child``) — that
+    half crosses ``combine``, and the siblings are the previous level's
+    combined histogram less it, in float32 (``ops.boost.derive_siblings``);
+    ``best_splits`` then scans every node as before.
+
+    When the round is lowered, each level leaves one ``gbdt.hist_plan`` span
+    with what ``ops.boost.hist_plan`` reckoned for its kernel
+    (``nodes_built``, ``nodes_derived``), the gauge
     ``gbdt_hist_rows_streamed_per_round`` takes rows x (tile sweeps of every
-    level + routing passes).
+    level + routing passes), and ``gbdt_hist_nodes_derived_per_round`` the
+    nodes a round reads off a subtraction (16 at depth 6, 112 at depth 8).
 
     ``xb3`` is the pre-blocked quantized matrix from ``ops.boost.block_rows``
     (built once per fit).  ``combine`` is the histogram allreduce hook
     (one call per level; leaf masses derive from the last combined
     histogram via split_child_masses, so there is no leaf collective)
     (e.g. ``lambda a: lax.psum(a, 'dp')`` under shard_map) — the same single
-    communication point per level as the reference workload.
+    communication point per level as the reference workload.  It is handed
+    ``[2**d, F, B, 2]`` at a level built whole and ``[2**(d-1), F, B, 2]``,
+    the built children in parent order, at a derived one: half the bytes.
 
     ``combine_leaf``, where given, is one more collective a round, for a
     deployment whose collective sequence has a leaf hop (train_round_hybrid):
@@ -339,7 +370,8 @@ def train_round_fused(
     ``[2**depth, 2]`` — linear in the histogram, so the sum over shards is
     what the combined histogram gives — and returns the global ones.
     Sending the combined histogram's masses would count every shard's
-    world times.
+    world times.  (The local histogram of a derived level is the local
+    parents' less the local built children's, kept only for this hook.)
     """
     from rabit_tpu.ops import boost
 
@@ -366,20 +398,32 @@ def train_round_fused(
     feats = [jnp.zeros(max_nodes, jnp.int32).at[:1].set(feat)]
     thrs = [jnp.zeros(max_nodes, jnp.int32).at[:1].set(thr)]
     node3 = jnp.zeros_like(g3, shape=g3.shape, dtype=jnp.int32)
+    nodes_derived = 0
     for d in range(1, cfg.depth):
         plan = boost.hist_plan(xb3.shape[2], cfg.n_bins, d, block)
+        nodes_derived += plan.nodes_derived
+        level = functools.partial(
+            boost.hist_level, xb3, node3, g3, h3, feat, thr, depth=d,
+            n_bins=cfg.n_bins, interpret=interpret, mxu_i8=cfg.mxu_i8,
+            r_split=cfg.r_split)
         with jax.named_scope(f"level{d}"), obs.span(
                 "gbdt.hist_plan", level=d, nodes_built=plan.nodes_built,
+                nodes_derived=plan.nodes_derived,
                 m_rows=plan.m_rows, m_tiles=plan.m_tiles,
                 feat_tiles=plan.feat_tiles, tile_feats=plan.tile_feats,
                 acc_block_bytes=plan.acc_block_bytes,
                 vmem_bytes=plan.vmem_bytes):
-            local, node3 = boost.hist_level(xb3, node3, g3, h3, feat, thr,
-                                            depth=d, n_bins=cfg.n_bins,
-                                            interpret=interpret,
-                                            mxu_i8=cfg.mxu_i8,
-                                            r_split=cfg.r_split)
-            hist = combine(local)
+            if plan.nodes_derived:
+                # one child a parent through the kernel and the collective,
+                # its sibling from the parents' combined histogram after it
+                built_right = smaller_child(hist, feat, thr)
+                built, node3 = level(built_right)
+                hist = boost.derive_siblings(hist, combine(built), built_right)
+                if combine_leaf is not None:
+                    local = boost.derive_siblings(local, built, built_right)
+            else:
+                local, node3 = level()
+                hist = combine(local)
             feat, thr, _ = best_splits(hist, cfg)
         feats.append(jnp.zeros(max_nodes, jnp.int32).at[: 2 ** d].set(feat))
         thrs.append(jnp.zeros(max_nodes, jnp.int32).at[: 2 ** d].set(thr))
@@ -390,6 +434,8 @@ def train_round_fused(
     passes = cfg.depth * tiles + (1 if tiles == 1 else cfg.depth)
     obs.get_registry().gauge("gbdt_hist_rows_streamed_per_round").set(
         passes * xb3.shape[0] * block)
+    obs.get_registry().gauge("gbdt_hist_nodes_derived_per_round").set(
+        nodes_derived)
     # Leaf (g, h) masses come straight off the final combined histogram
     # (split_child_masses) — already globally reduced, so no leaf collective
     # and no histogram work in the last row pass (depth collectives per
@@ -450,9 +496,10 @@ def train_round_hybrid(
     histogram and the next level's routing.  The callbacks are ordered by
     data dependence — level d's combined histogram feeds level d+1's
     routing — so every worker issues the identical deterministic collective
-    sequence, depth + 1 hops a round (a histogram a level, then the
-    leaves' masses), which is exactly what lets the robust engine's replay
-    log serve byte-identical results to a worker recovering mid-round.
+    sequence, depth + 1 hops a round (a histogram a level — from level 5
+    on the built half of it, ``train_round_fused`` — then the leaves'
+    masses), which is exactly what lets the robust engine's replay log
+    serve byte-identical results to a worker recovering mid-round.
 
     Which kernels do the local work is read from the call, no option:
 
@@ -511,9 +558,11 @@ def train_round_hybrid(
 
     if fused:
         xb3 = xb if xb.ndim == 3 else boost.block_rows(xb)[0]
-        # the tag is the level's node count, 2**level: unique per level
+        # the tag is the level's node count, 2**level: unique per level (the
+        # payload's own node count is not: a derived level sends half)
+        levels = itertools.count()
         return train_round_fused(
-            state, xb3, y, cfg, combine=lambda a: cross(a, a.shape[0]),
+            state, xb3, y, cfg, combine=lambda a: cross(a, 2 ** next(levels)),
             interpret=interpret, combine_leaf=cross_leaf)
     if xb.ndim == 3:
         xb = boost.unblock_rows(xb, y.shape[0])

@@ -9,7 +9,10 @@ into a single streaming pass per row block:
 * ``hist_level``    — route rows one level down through the parent split
   table (split lookup + feature select + compare, all in VMEM) and
   histogram at the new nodes, emitting the updated node ids as a second
-  output.
+  output.  From level 5 on it histograms ONE child of every parent, the one
+  a table names, and ``derive_siblings`` reads the other off the parents'
+  histogram — XGBoost's and LightGBM's subtraction trick, at the levels
+  where it shortens the matmul.
 * ``route_level``   — route to the leaves, no histogram: the leaves' (g, h)
   masses are read off the last level's histogram.
 
@@ -19,9 +22,15 @@ contracted against per-feature bin indicators built in VMEM; f32 gradients
 are split hi/lo into two bfloat16 matmuls (error ~2^-16-relative).
 
 ``hist_plan`` reckons what a level asks of the chip from the shape alone.
-Up to ``TILE_FEATS`` features (one 128-lane tile of blocked codes) the
-accumulator ``(2 * 2**level, F * B_eff)`` float32 is ONE block held across
-the row grid, and routing rides the histogram's sweep.  A wider matrix is
+The stacked gradient matrix has 4 rows a node built (g and h, hi and lo
+plane); up to 64 of them a level costs the same, the indicator build hiding
+the MXU, and from a full 128-row tile on the cost follows the rows.  So a
+level whose nodes would stack a tile or more — 4 * 2**level >= ``MXU_ROWS``:
+level 5, at any width — builds half of them, one child a parent, with the
+matmul, the accumulator block and the VMEM of the level above; no option
+chooses.  Up to ``TILE_FEATS`` features (one 128-lane tile of blocked codes)
+the accumulator ``(2 * nodes built, F * B_eff)`` float32 is ONE block held
+across the row grid, and routing rides the histogram's sweep.  A wider matrix is
 walked in feature tiles: a grid over (feature tile, row block), the row
 axis innermost, one tile's accumulator block resident across its row
 sweep; the feature a node splits on may lie in any tile, so routing is a
@@ -97,10 +106,16 @@ VMEM_STACK = 8 << 20
 
 
 class HistPlan(NamedTuple):
-    """What one level's ``hist_level`` asks of the chip."""
+    """What one level's ``hist_level`` asks of the chip.  A level whose
+    nodes, all built, would stack a full MXU tile of gradient matrix or more
+    is *derived*: one child of every parent is accumulated and its sibling
+    is the parent's histogram less the built one's (``derive_siblings``), so
+    level d runs the matmul, the accumulator block and the VMEM of level
+    d - 1."""
 
     level: int
-    m_pad: int            # accumulator rows: g and h of every node
+    nodes_derived: int    # nodes read off parent - sibling: half a derived level, else 0
+    m_pad: int            # accumulator rows: g and h of every node BUILT
     acc_block_bytes: int  # ONE tile's (m_pad, tile_feats * B_eff) float32 block
     vmem_bytes: int       # what one tile's kernel takes: hist_plan's text
     tile_feats: int       # features a tile: all of F where one tile holds them
@@ -108,7 +123,7 @@ class HistPlan(NamedTuple):
 
     @property
     def nodes_built(self) -> int:
-        return 2 ** self.level
+        return 2 ** self.level - self.nodes_derived
 
     @property
     def m_rows(self) -> int:
@@ -137,9 +152,15 @@ def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int) -> HistPlan
     ragged.  A tile's accumulator block is counted twice (its index moves
     with the tile, so one is written back while the next fills), the row
     blocks twice — a tile's codes, node, g and h; routing is a pass of its
-    own — and ``VMEM_STACK``.  (F = 2000 at 64 bins, level 7: 16 MiB a
-    block, 44 MiB.)"""
-    m_pad = _round_up(2 * 2 ** level, 8)
+    own — and ``VMEM_STACK``.  (F = 2000 at 64 bins, level 7: 8 MiB a
+    block, 28 MiB.)
+
+    From the level whose every node, built, stacks ``MXU_ROWS`` rows of
+    gradient matrix (4 * 2**level: level 5 at any width) half the nodes are
+    built and half derived; below it M is padded to the tile anyway and
+    every node is built."""
+    derived = 2 ** (level - 1) if level >= 1 and 4 * 2 ** level >= MXU_ROWS else 0
+    m_pad = _round_up(2 * (2 ** level - derived), 8)
     tile = min(n_feat, TILE_FEATS)
     tiles = -(-n_feat // tile)
     acc = m_pad * tile * _bins_eff(n_bins) * 4
@@ -155,7 +176,7 @@ def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int) -> HistPlan
             f"of one {tile}-feature tile is {acc} bytes and the kernel needs "
             f"{need} of {VMEM_MOST} bytes of VMEM (a depth of {level + 1} is "
             "one level too many)")
-    return HistPlan(level, m_pad, acc, need, tile, tiles)
+    return HistPlan(level, derived, m_pad, acc, need, tile, tiles)
 
 
 def _encode_bf16(L):
@@ -165,9 +186,10 @@ def _encode_bf16(L):
     full 128-row tile anyway, and the stack fills one at level 5 (4 * 32
     rows), so up to there two separate matmuls each waste >= half the tile
     — packing them halves the level's MXU passes (~1.4x whole-round at
-    1,000,000 rows x 28 on an older chip).  Level 6 stacks two tiles and
-    level 7 four (``HistPlan.m_tiles``).  The result splits back and sums
-    in f32, bitwise identical to the two-matmul form."""
+    1,000,000 rows x 28 on an older chip).  From level 5 on one child a
+    parent is built (``hist_plan``), so level 5 stacks half a tile again,
+    level 6 one and level 7 two (``HistPlan.m_tiles``).  The result splits
+    back and sums in f32, bitwise identical to the two-matmul form."""
     lhi = L.astype(jnp.bfloat16)
     llo = (L - lhi.astype(jnp.float32)).astype(jnp.bfloat16)
     l2 = jnp.concatenate([lhi, llo], axis=1)
@@ -270,15 +292,35 @@ def _accum(xb_blk, L, out_ref, *, n_bins: int, n_feat: int, fc: int, i8: bool,
             pl.when(gi < feats_left)(functools.partial(group, gi, k))
 
 
-def _gradient_matrix(node, g, h, *, n_nodes: int, m_pad: int):
-    """L[r, m]: g_r at column node_r, h_r at column n_nodes+node_r."""
+def _gradient_matrix(node, g, h, *, n_nodes: int, m_pad: int, built_row=None):
+    """L[r, m]: g_r at column node_r, h_r at column n_nodes+node_r.
+
+    At a derived level ``built_row`` is the ``(1, m_pad)`` lane table of
+    ``_built_table`` and the columns are the PARENTS: a row lies in column
+    ``node_r >> 1`` iff it went to the child its parent builds
+    (``node_r & 1 == built_right[node_r >> 1]``), else in none — a compare
+    against the table along the lanes, no lookup a row."""
     r = node.shape[0]
     m_iota = lax.broadcasted_iota(jnp.int32, (r, m_pad), 1)
     is_g = m_iota < n_nodes
     idx = jnp.where(is_g, m_iota, m_iota - n_nodes)
-    sel = (node == idx) & (m_iota < 2 * n_nodes)
+    if built_row is None:
+        sel = node == idx
+    else:
+        sel = ((node >> 1) == idx) & ((node & 1) == built_row)
+    sel = sel & (m_iota < 2 * n_nodes)
     val = jnp.where(is_g, g, h)  # (R,1) -> (R, m_pad)
     return jnp.where(sel, val, 0.0)
+
+
+def _built_table(built_right, m_pad: int):
+    """``built_right`` ``[n]`` (1 where a parent builds its right child) as
+    the kernels take it: row 0 of an ``(8, lanes)`` int32 table holds it
+    twice, once under the g columns of the gradient matrix and once under
+    the h columns."""
+    n = built_right.shape[0]
+    tab = jnp.zeros((8, _round_up(m_pad, 128)), jnp.int32)
+    return tab.at[0, : 2 * n].set(jnp.tile(built_right.astype(jnp.int32), 2))
 
 
 def _split_of(node, feat_row, thr_row, *, p_pad: int):
@@ -320,9 +362,12 @@ def _level0_kernel(xb_ref, g_ref, h_ref, out_ref, *, n_bins, n_feat, fc, i8,
 # -- level d >= 1: route + histogram ---------------------------------------
 
 
-def _level_kernel(xb_ref, node_ref, g_ref, h_ref, feat_ref, thr_ref,
-                  out_ref, node_out_ref, *,
+def _level_kernel(xb_ref, node_ref, g_ref, h_ref, feat_ref, thr_ref, *refs,
                   n_nodes, n_bins, n_feat, m_pad, p_pad, fc, i8, r_split=1):
+    """``refs``: at a derived level the built-child table, then the
+    accumulator (``n_nodes`` built nodes) and the routed node ids."""
+    *built_ref, out_ref, node_out_ref = refs
+
     @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
@@ -330,7 +375,9 @@ def _level_kernel(xb_ref, node_ref, g_ref, h_ref, feat_ref, thr_ref,
     node = _route(xb_ref[0], node_ref[0], feat_ref[0:1], thr_ref[0:1],
                   p_pad=p_pad, n_feat=n_feat)
     node_out_ref[0] = node
-    L = _gradient_matrix(node, g_ref[0], h_ref[0], n_nodes=n_nodes, m_pad=m_pad)
+    L = _gradient_matrix(
+        node, g_ref[0], h_ref[0], n_nodes=n_nodes, m_pad=m_pad,
+        built_row=built_ref[0][0:1, :m_pad] if built_ref else None)
     _accum(xb_ref[0], L, out_ref, n_bins=n_bins, n_feat=n_feat, fc=fc, i8=i8,
            r_split=r_split)
 
@@ -343,16 +390,19 @@ def _tile_kernel(xb_ref, *refs, n_feat, tile, n_nodes, n_bins, m_pad, fc, i8,
     """Grid (feature tile, row block), the rows innermost: ``out_ref`` is
     this tile's accumulator block, zeroed at its first row block.  The rows
     come routed: ``refs`` is their node ids (not at the root, where every
-    row is at node 0), g, h and the output."""
-    *node_ref, g_ref, h_ref, out_ref = refs
+    row is at node 0), at a derived level the built-child table, then g, h
+    and the output."""
+    *lead, g_ref, h_ref, out_ref = refs
 
     @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    node = (node_ref[0][0] if node_ref
+    node = (lead[0][0] if lead
             else jnp.zeros((g_ref.shape[1], 1), jnp.int32))
-    L = _gradient_matrix(node, g_ref[0], h_ref[0], n_nodes=n_nodes, m_pad=m_pad)
+    L = _gradient_matrix(
+        node, g_ref[0], h_ref[0], n_nodes=n_nodes, m_pad=m_pad,
+        built_row=lead[1][0:1, :m_pad] if len(lead) > 1 else None)
     _accum(xb_ref[0], L, out_ref, n_bins=n_bins, n_feat=tile, fc=fc, i8=i8,
            r_split=r_split, feats_left=n_feat - pl.program_id(0) * tile)
 
@@ -514,24 +564,27 @@ def _vmem_params(plan: HistPlan):
 
 
 def _hist_tiles(plan: HistPlan, xb3, node3, g3, h3, *, n_bins, interpret,
-                mxu_i8, r_split, name):
+                mxu_i8, r_split, name, built=()):
     """A level wider than one tile: the ``(m_pad, F, B)`` sums of rows that
-    come routed (``node3`` None at the root), a sweep of every row block a
-    feature tile.  The kernel's output is whole tiles wide; the ragged last
-    tile's tail is cut off here."""
+    come routed (``node3`` None at the root; ``built`` holds the built-child
+    table of a derived level), a sweep of every row block a feature tile.
+    The kernel's output is whole tiles wide; the ragged last tile's tail is
+    cut off here."""
     nb, R, F = xb3.shape
     be = _bins_eff(n_bins)
     tile, tiles, m_pad = plan.tile_feats, plan.feat_tiles, plan.m_pad
     row = pl.BlockSpec((1, R, 1), lambda t, i: (i, 0, 0))
     rows = [a for a in (node3, g3, h3) if a is not None]
+    specs = [row] * len(rows)
+    rows[1:1] = built
+    specs[1:1] = [pl.BlockSpec(b.shape, lambda t, i: (0, 0)) for b in built]
     out = pl.pallas_call(
         functools.partial(
             _tile_kernel, n_feat=F, tile=tile, n_nodes=plan.nodes_built,
             n_bins=n_bins, m_pad=m_pad, fc=_pick_tile_fc(n_bins), i8=mxu_i8,
             r_split=r_split),
         grid=(tiles, nb),
-        in_specs=[pl.BlockSpec((1, R, tile), lambda t, i: (i, 0, t))]
-        + [row] * len(rows),
+        in_specs=[pl.BlockSpec((1, R, tile), lambda t, i: (i, 0, t))] + specs,
         out_specs=pl.BlockSpec((m_pad, tile * be), lambda t, i: (0, t)),
         out_shape=jax.ShapeDtypeStruct((m_pad, tiles * tile * be), jnp.float32),
         interpret=interpret,
@@ -575,33 +628,52 @@ def hist_level0(xb3, g3, h3, *, n_bins: int, interpret: bool = False,
     jax.jit,
     static_argnames=("depth", "n_bins", "interpret", "mxu_i8", "r_split"),
 )
-def hist_level(xb3, node3, g3, h3, feat, thr, *, depth: int, n_bins: int,
-               interpret: bool = False, mxu_i8: bool = False,
+def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, *, depth: int,
+               n_bins: int, interpret: bool = False, mxu_i8: bool = False,
                r_split: int = 1):
     """Route one level down and histogram; returns
-    ([2**depth, F, B, 2], node3').  ``feat``/``thr`` are the level-(depth-1)
-    split tables, shape [2**(depth-1)].  ``r_split``: see _accum.  Wider
-    than ``TILE_FEATS`` features the two are two kernels: ``route_level``'s
-    tiled pass, then a histogram sweep a feature tile (``hist_plan``).  The
-    kernel asks for ``hist_plan``'s scoped VMEM where Mosaic's default might
-    not hold it (F = 67 from level 5 on), and is the same kernel elsewhere.
-    Compiled on its own, level 7 at F = 67 is refused at the default, on the
-    chip too; inside the whole round XLA keeps the accumulator in VMEM
-    itself and the default holds, and asking costs the round nothing (PR 27)."""
+    ([nodes built, F, B, 2], node3').  ``feat``/``thr`` are the level-(depth-1)
+    split tables, shape [2**(depth-1)].  ``r_split``: see _accum.
+
+    Up to level 4 every node is built: ``[2**depth, F, B, 2]``.  From the
+    level ``hist_plan`` derives (5, at any width) the histogram is of ONE
+    child a parent, ``[2**(depth-1), F, B, 2]`` in parent order:
+    ``built_right`` ``[2**(depth-1)]`` says which (1: the right one;
+    ``models.gbdt.smaller_child`` picks the lighter), and a row that went to
+    the other child is routed and summed nowhere.  The caller gets the
+    siblings from the parents' histogram (``derive_siblings``), after its
+    collective.  The routed node ids and the kernel's name are a built
+    level's.
+
+    Wider than ``TILE_FEATS`` features routing and histogram are two
+    kernels: ``route_level``'s tiled pass, then a histogram sweep a feature
+    tile (``hist_plan``).  The kernel asks for ``hist_plan``'s scoped VMEM
+    where Mosaic's default might not hold it (F = 67 from level 6 on), and
+    is the same kernel elsewhere.  Compiled on its own, a 16.75 MiB
+    accumulator block at F = 67 (level 8 now, level 7 with every node built)
+    is refused at the default, on the chip too; inside the whole round XLA
+    keeps the accumulator in VMEM itself and the default holds, and asking
+    costs the round nothing (PR 27)."""
     nb, R, F = xb3.shape
     _check_r_split(R, r_split)
     plan = hist_plan(F, n_bins, depth, R)
     be = _bins_eff(n_bins)
     n_nodes, m_pad = plan.nodes_built, plan.m_pad
+    n_prev = 2 ** (depth - 1)
+    if bool(plan.nodes_derived) != (built_right is not None):
+        raise ValueError(
+            f"hist_level: level {depth} builds {n_nodes} of {2 ** depth} nodes, "
+            "so it takes built_right " + (
+                f"[{n_prev}]" if plan.nodes_derived else "None"))
+    built = [] if built_right is None else [_built_table(built_right, m_pad)]
     if plan.feat_tiles > 1:
         node_out = route_level(xb3, node3, feat, thr, depth=depth,
                                interpret=interpret)
         out = _hist_tiles(plan, xb3, node_out, g3, h3, n_bins=n_bins,
                           interpret=interpret, mxu_i8=mxu_i8, r_split=r_split,
-                          name=f"hist_level_d{depth}")
+                          name=f"hist_level_d{depth}", built=built)
         hist = jnp.stack([out[:n_nodes], out[n_nodes : 2 * n_nodes]], axis=-1)
         return hist, node_out
-    n_prev = 2 ** (depth - 1)
     p_pad = _round_up(n_prev, 128)
     fc = _pick_fc(F, n_bins)
     featp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(feat)
@@ -616,7 +688,7 @@ def hist_level(xb3, node3, g3, h3, feat, thr, *, depth: int, n_bins: int,
             _blk(R, F), _blk(R, 1), _blk(R, 1), _blk(R, 1),
             pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
             pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
-        ],
+        ] + [pl.BlockSpec(b.shape, lambda i: (0, 0)) for b in built],
         out_specs=[
             pl.BlockSpec((m_pad, F * be), lambda i: (0, 0)),
             _blk(R, 1),
@@ -628,10 +700,25 @@ def hist_level(xb3, node3, g3, h3, feat, thr, *, depth: int, n_bins: int,
         interpret=interpret,
         name=f"hist_level_d{depth}",
         compiler_params=_vmem_params(plan),
-    )(xb3, node3, g3, h3, featp, thrp)
+    )(xb3, node3, g3, h3, featp, thrp, *built)
     out = out.reshape(m_pad, F, be)[..., :n_bins]
     hist = jnp.stack([out[:n_nodes], out[n_nodes : 2 * n_nodes]], axis=-1)
     return hist, node_out
+
+
+def derive_siblings(parents, built, built_right):
+    """A derived level whole: ``built`` ``[n, F, B, 2]`` is one child of each
+    of the ``n`` parents (``built_right``: 1 where it is the right one) and
+    its sibling is ``parents - built``, in float32 on the accumulators'
+    decoded sums; ``[2 * n, F, B, 2]`` in node order (2 * parent + went
+    right).  Both operands are sums over the same rows' hi/lo planes, so the
+    split's round-off cancels and the difference carries float32 summation
+    error alone, the parent's in absolute terms."""
+    right = built_right.astype(bool)[:, None, None, None]
+    other = parents - built
+    return jnp.stack([jnp.where(right, other, built),
+                      jnp.where(right, built, other)],
+                     axis=1).reshape(2 * built.shape[0], *built.shape[1:])
 
 
 # -- blocking helpers -------------------------------------------------------

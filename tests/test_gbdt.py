@@ -258,25 +258,31 @@ def _follow_round(cfg, xb, g, h, feature, threshold):
     return gap, leaf, node
 
 
-@pytest.mark.parametrize("f,depth,bins", [(28, 6, 256), (67, 8, 256),
-                                          (300, 6, 64)],
-                         ids=["higgs", "criteo", "tiled"])
-def test_fused_round_from_a_warm_margin_follows_float64(f, depth, bins):
+@pytest.mark.parametrize("f,depth,bins,n,rounds", [
+    (28, 6, 256, 16384, 3), (67, 8, 256, 16384, 3), (300, 6, 64, 16384, 3),
+    (2000, 8, 64, 2048, 2)], ids=["higgs", "criteo", "tiled", "epsilon"])
+def test_fused_round_from_a_warm_margin_follows_float64(f, depth, bins, n,
+                                                        rounds):
     """Three rounds on sixteen row blocks, so the second and third start
     from a margin that is not zero and no sum is exact: every split the
     fused round takes is the float64 scatter histogram's best along the
     same tree (an exact tie apart), and its leaves and margin are that
     tree's to the hi/lo-bf16 error.  Readings at this seed: gain gap 0.0,
     leaves 2.7e-6 of their rms, margin 9.4e-7.  "tiled" is three feature
-    tiles with a ragged last one (ops.boost.TILE_FEATS)."""
+    tiles with a ragged last one (ops.boost.TILE_FEATS).  From level 5 on
+    half of each level's nodes are read off parent - sibling
+    (ops.boost.hist_plan): level 5 in "higgs" and "tiled", 5 to 7 in
+    "criteo" and "epsilon" — the benchmark's widths; "epsilon" (sixteen
+    feature tiles, an interpreted round 10 s) is two rounds on two row
+    blocks."""
     from rabit_tpu.ops import boost
 
     rng = np.random.RandomState(3)
-    n, rounds = 16384, 3
     xb, y = _bench_like(rng, n, f, bins)
     cfg = gbdt.GBDTConfig(n_features=f, n_trees=rounds, depth=depth,
                           n_bins=bins, learning_rate=0.1)
     xb3, _ = boost.block_rows(xb, 1024)
+    assert boost.hist_plan(f, bins, depth - 1, 1024).nodes_derived
     step = jax.jit(functools.partial(gbdt.train_round_fused, cfg=cfg,
                                      interpret=True))
     s = gbdt.init_state(cfg, n)
@@ -293,6 +299,110 @@ def test_fused_round_from_a_warm_margin_follows_float64(f, depth, bins):
         np.testing.assert_allclose(np.asarray(s.margin), before + leaf[node],
                                    rtol=0, atol=1e-5)
     assert np.abs(before).max() > 0.05      # the last round started warm
+
+
+def _rare_flags(rng, n, f_rare, f_noise, bins):
+    """Codes whose best splits are lopsided: ``f_rare`` features are 0 on
+    99 % of the rows and 1..bins-1 on the rest, and the label leans hard on
+    each flag, so down the tree's main chain every level cuts about 1 % of
+    a node's rows off to the right; ``f_noise`` uniform features for the
+    small nodes to split on.  (benchmark/harness/data.py draws uniform
+    codes alone: children come out balanced there.)"""
+    flag = rng.rand(n, f_rare) < 0.01
+    rare = np.where(flag, rng.randint(1, bins, size=(n, f_rare)), 0)
+    xb = np.concatenate([rare, rng.randint(0, bins, size=(n, f_noise))], 1)
+    logit = -0.5 + flag @ (3.0 * (-1.0) ** np.arange(f_rare))
+    y = (rng.rand(n) < 1 / (1 + np.exp(-logit))).astype(np.float32)
+    return jnp.asarray(xb, jnp.int32), jnp.asarray(y)
+
+
+def test_derived_levels_build_the_smaller_child_on_lopsided_splits():
+    """Depth 7 on sixteen row blocks of ``_rare_flags`` codes from a warm
+    margin, the third round eager so that the level hook sees what crosses
+    it.  Levels 5 and 6 send ONE child a parent through the hook, the one
+    with the smaller hessian mass by the float64 scatter histogram along
+    the same tree — 1 % of the parent's rows down the main chain, a right
+    child there.  Every node's histogram at those levels, built or
+    parent - built, is the float64 one to the hi/lo split's round-off of
+    the node's OWN mass (5.6e-6 read); built the other way round (the
+    heavier child through the kernel, exact), the main chain's light child
+    is off by 80 times what it is built (5.3e-5 against 6.6e-7 of its mass,
+    on sixteen row blocks; the error is the parent's float32 summation
+    error, and grows with the rows).
+    The leaves are the tree's own within the bound of
+    test_fused_round_from_a_warm_margin_follows_float64."""
+    from rabit_tpu.ops import boost
+
+    rng = np.random.RandomState(41)
+    n, f_rare, f, bins, depth, block = 16384, 6, 8, 32, 7, 1024
+    xb, y = _rare_flags(rng, n, f_rare, f - f_rare, bins)
+    cfg = gbdt.GBDTConfig(n_features=f, n_trees=3, depth=depth, n_bins=bins,
+                          learning_rate=0.3)
+    xb3, _ = boost.block_rows(xb, block)
+    warm = jax.jit(functools.partial(gbdt.train_round_fused, cfg=cfg,
+                                     interpret=True))
+    s = gbdt.init_state(cfg, n)
+    for _ in range(2):
+        s = warm(s, xb3, y)
+    assert np.abs(np.asarray(s.margin)).max() > 0.05
+    g, h = gbdt.gradients(cfg, s.margin, y)
+    crossed = []
+
+    def hook(a):
+        crossed.append(np.asarray(a))
+        return a
+
+    s = gbdt.train_round_fused(s, xb3, y, cfg, combine=hook, interpret=True)
+    forest = jax.tree.map(np.asarray, s.forest)
+    assert [a.shape[0] for a in crossed] == [1, 2, 4, 8, 16, 16, 32]
+
+    gap, leaf, _ = _follow_round(cfg, xb, g, h, forest.feature[2],
+                                 forest.threshold[2])
+    assert gap <= 1e-6
+    assert np.sqrt(np.mean((forest.leaf[2] - leaf) ** 2)) <= \
+        2e-5 * np.sqrt(np.mean(leaf ** 2))
+
+    # the same tree in float64: each level's nodes, histograms and masses
+    xn, g64, h64 = np.asarray(xb), np.asarray(g, np.float64), \
+        np.asarray(h, np.float64)
+    node, whole = np.zeros(n, np.int64), crossed[0]
+    for d in range(1, depth):
+        ft, th = forest.feature[2, d - 1], forest.threshold[2, d - 1]
+        node = 2 * node + (xn[np.arange(n), ft[node]] > th[node])
+        k = 2 ** d
+        ref = np.stack([np.stack([
+            np.bincount(node * bins + xn[:, j], weights=w,
+                        minlength=k * bins).reshape(k, bins)
+            for j in range(f)], 1) for w in (g64, h64)], -1)   # [k, F, B, 2]
+        mass = np.bincount(node, weights=h64, minlength=k)
+        if d < 5:
+            whole = crossed[d]
+            continue
+        pairs = mass.reshape(-1, 2)
+        want_right = pairs[:, 1] < pairs[:, 0]
+        built_right = np.asarray(gbdt.smaller_child(
+            jnp.asarray(whole), jnp.asarray(ft[: k // 2]),
+            jnp.asarray(th[: k // 2])))
+        np.testing.assert_array_equal(built_right, want_right)
+        built = crossed[d]
+        np.testing.assert_allclose(built[:, 0, :, 1].sum(-1), pairs.min(1),
+                                   rtol=1e-5)
+        light = 2 * np.arange(k // 2) + built_right
+        light = light[(pairs.min(1) < 0.02 * pairs.sum(1)) & (pairs.min(1) >= 1)]
+        assert len(light)                     # the main chain's light child
+        parents = whole
+        whole = np.asarray(boost.derive_siblings(
+            jnp.asarray(parents), jnp.asarray(built), jnp.asarray(built_right)))
+        err = np.abs(whole - ref).max((1, 2, 3)) / np.maximum(mass, 1.0)
+        assert err.max() <= 1e-5
+        # the other way round: the heavier child built, the lighter derived
+        heavy = ref[2 * np.arange(k // 2) + (1 - built_right)].astype(np.float32)
+        flipped = np.asarray(boost.derive_siblings(
+            jnp.asarray(parents), jnp.asarray(heavy),
+            jnp.asarray(1 - built_right)))
+        bad = np.abs(flipped - ref).max((1, 2, 3)) / np.maximum(mass, 1.0)
+        assert (bad[light] >= 10 * err[light]).all()
+    assert want_right[0]                      # the main chain cuts to the right
 
 
 def test_fused_round_at_the_higgs_shape_is_bitwise_what_it_was():
@@ -322,32 +432,46 @@ def test_fused_round_at_the_higgs_shape_is_bitwise_what_it_was():
 
 
 def test_hist_plan():
-    """The plan alone.  HIGGS (F 28, depth 6): today's fc and m_pad, every
-    level inside Mosaic's default scoped VMEM, so no kernel asks for more.
-    Criteo (F 67, depth 8): one accumulator block a level, 16.75 MiB at
-    level 7 in four MXU tiles of M, every kernel inside what it may ask
-    for.  Level 9 is refused by name."""
+    """The plan alone.  HIGGS (F 28, depth 6): today's fc, every level inside
+    Mosaic's default scoped VMEM, so no kernel asks for more.  Every node is
+    built up to level 4; from level 5 on (4 * 2**d rows of stacked gradient
+    matrix: a full MXU tile) one child a parent is, and m_pad, the
+    accumulator block and the VMEM ask are those of the level above.  Criteo
+    (F 67, depth 8): one accumulator block a level, 8.4 MiB at level 7 in
+    two MXU tiles of M, every kernel inside what it may ask for.  The halved
+    block lets levels 8 and 9 through (16.75 and 33.5 MiB: depths 9 and 10);
+    level 10 is refused by name."""
     from rabit_tpu.ops import boost
 
     assert boost._pick_fc(28, 256) == 7
     for d in range(1, 6):
         p = boost.hist_plan(28, 256, d, 1024)
-        assert p.nodes_built == 2 ** d and p.m_pad == max(8, 2 * 2 ** d)
+        built = 2 ** d if d < 5 else 2 ** (d - 1)
+        assert (p.nodes_built, p.nodes_derived) == (built, 2 ** d - built)
+        assert p.m_pad == max(8, 2 * built)
         assert p.acc_block_bytes == p.m_pad * 28 * 256 * 4
         assert p.vmem_bytes <= boost.VMEM_DEFAULT
-    assert boost.hist_plan(28, 256, 5, 1024).m_rows == boost.MXU_ROWS
+    assert boost.hist_plan(28, 256, 4, 1024).m_rows == boost.MXU_ROWS // 2
+    assert boost.hist_plan(28, 256, 5, 1024).m_rows == boost.MXU_ROWS // 2
+    assert boost.hist_plan(28, 256, 0, 1024).nodes_derived == 0
 
-    want = {5: (32, 64, 1), 6: (64, 128, 2), 7: (128, 256, 4)}
-    for d in range(1, 8):
+    # level: (nodes built, nodes derived, m_pad, MXU tiles of M)
+    want = {4: (16, 0, 32, 1), 5: (16, 16, 32, 1), 6: (32, 32, 64, 1),
+            7: (64, 64, 128, 2), 8: (128, 128, 256, 4)}
+    for d in range(1, 10):
         p = boost.hist_plan(67, 256, d, 1024)
         assert p.acc_block_bytes == p.m_pad * 67 * 256 * 4
         assert p.acc_block_bytes + (5 << 20) < p.vmem_bytes <= boost.VMEM_MOST
         if d in want:
-            assert (p.nodes_built, p.m_pad, p.m_tiles) == want[d]
+            assert (p.nodes_built, p.nodes_derived, p.m_pad, p.m_tiles) == want[d]
+        if d >= 5:      # the rows every node of the level above stacks
+            assert (p.m_pad, p.nodes_built) == (2 * 2 ** (d - 1), 2 ** (d - 1))
+    assert boost.hist_plan(67, 256, 7, 1024).acc_block_bytes == 128 * 67 * 1024
     assert boost.hist_plan(67, 256, 7, 1024).vmem_bytes > boost.VMEM_DEFAULT
+    assert boost.hist_plan(67, 256, 5, 1024).vmem_bytes <= boost.VMEM_DEFAULT
 
-    with pytest.raises(ValueError, match=r"level 9 of F=67 .*bytes") as e:
-        boost.hist_plan(67, 256, 9, 1024)
+    with pytest.raises(ValueError, match=r"level 10 of F=67 .*bytes") as e:
+        boost.hist_plan(67, 256, 10, 1024)
     assert str(boost.VMEM_MOST) in str(e.value)
     for f, d in ((28, 5), (67, 7), (128, 3)):
         p = boost.hist_plan(f, 256, d, 1024)
@@ -358,28 +482,34 @@ def test_hist_plan_tiles_a_wide_matrix():
     """Wider than one tile of codes the plan walks the features in tiles of
     128, the last one ragged, and reckons ONE tile's accumulator block,
     counted twice: Epsilon (F 2000, 64 bins padded to 128 lanes, depth 8)
-    is sixteen tiles a level, a 16 MiB block and 44 MiB asked at level
-    7 — a width whose one-block accumulator (250 MiB there) nothing holds.
-    Level 8 is refused by name, with the tile's block in the text."""
+    is sixteen tiles a level, and with one child a parent built from level
+    5 on an 8 MiB block and 28 MiB asked at level 7 (16 and 44 with every
+    node built) — a width whose one-block accumulator nothing holds.  The
+    halved block lets level 8 through (a 16 MiB block, 44 MiB: depth 9);
+    level 9 is refused by name, with the tile's block in the text."""
     from rabit_tpu.ops import boost
 
     assert boost._pick_tile_fc(64) == boost._pick_tile_fc(128) == 16
     assert boost._pick_tile_fc(256) == 8
     assert boost.hist_plan(129, 64, 3, 1024).feat_tiles == 2
-    for d in range(8):
+    for d in range(9):
         p = boost.hist_plan(2000, 64, d, 1024)
         assert (p.tile_feats, p.feat_tiles) == (128, 16)
-        assert p.m_pad == max(8, 2 * 2 ** d)
+        built = 2 ** d if d < 5 else 2 ** (d - 1)
+        assert (p.nodes_built, p.nodes_derived) == (built, 2 ** d - built)
+        assert p.m_pad == max(8, 2 * built)
         assert p.acc_block_bytes == p.m_pad * 128 * 128 * 4
         assert p.vmem_bytes == (2 * p.acc_block_bytes + 2 * 4 * 1024 * 4 * 128
                                 + boost.VMEM_STACK)
     assert p.acc_block_bytes == 16 << 20 and p.vmem_bytes == 44 << 20
+    p = boost.hist_plan(2000, 64, 7, 1024)
+    assert p.acc_block_bytes == 8 << 20 and p.vmem_bytes == 28 << 20
     assert boost.VMEM_DEFAULT < p.vmem_bytes <= boost.VMEM_MOST
-    assert boost.hist_plan(2000, 64, 5, 1024).vmem_bytes > boost.VMEM_DEFAULT
-    assert boost.hist_plan(2000, 64, 4, 1024).vmem_bytes <= boost.VMEM_DEFAULT
-    with pytest.raises(ValueError, match=r"level 8 of F=2000 .*128-feature "
+    assert boost.hist_plan(2000, 64, 6, 1024).vmem_bytes > boost.VMEM_DEFAULT
+    assert boost.hist_plan(2000, 64, 5, 1024).vmem_bytes <= boost.VMEM_DEFAULT
+    with pytest.raises(ValueError, match=r"level 9 of F=2000 .*128-feature "
                                          r"tile is 33554432 bytes"):
-        boost.hist_plan(2000, 64, 8, 1024)
+        boost.hist_plan(2000, 64, 9, 1024)
 
 
 @pytest.mark.parametrize("d", [0, 3])
@@ -473,12 +603,16 @@ def test_tiled_round_at_the_child_weight_floor_follows_the_reference():
 
 
 
-@pytest.mark.parametrize("d", [6, 7])
+@pytest.mark.parametrize("d", [4, 6, 7])
 def test_hist_level_at_the_criteo_width_matches_scatter(d):
-    """Levels 6 and 7 at 67 features x 256 bins: 256 and 512 stacked rows
-    of gradient matrix (two and four MXU tiles), one accumulator block of
-    17,152 lanes.  The kernel routes as the tables say and its histogram
-    is the scatter reference's at the routed nodes."""
+    """Levels 4, 6 and 7 at 67 features x 256 bins, one accumulator block of
+    17,152 lanes.  The kernel routes as the tables say.  Level 4 builds
+    every node: its histogram is the scatter reference's at the routed
+    nodes.  Levels 6 and 7 build ONE child a parent (128 and 256 stacked
+    rows of gradient matrix, one and two MXU tiles), the one ``built_right``
+    names: the histogram is the scatter reference's at those children, in
+    parent order, and ``derive_siblings`` with the parents' histogram gives
+    the level whole, in node order."""
     from rabit_tpu.ops import boost, hist as H
 
     rng = np.random.RandomState(13 + d)
@@ -491,34 +625,57 @@ def test_hist_level_at_the_criteo_width_matches_scatter(d):
     feat = jnp.asarray(rng.randint(0, F, size=n_prev), jnp.int32)
     thr = jnp.asarray(rng.randint(0, B, size=n_prev), jnp.int32)
     blocked = [boost.block_rows(a, block)[0] for a in (xb, node, g, h)]
-    hist, node_out = boost.hist_level(*blocked, feat, thr, depth=d, n_bins=B,
-                                      interpret=True)
-    assert hist.shape == (2 * n_prev, F, B, 2)
+    derived = boost.hist_plan(F, B, d, block).nodes_derived
+    assert derived == (n_prev if d >= 5 else 0)
+    built_right = rng.randint(0, 2, size=n_prev) if derived else None
+    hist, node_out = boost.hist_level(
+        *blocked, feat, thr,
+        None if built_right is None else jnp.asarray(built_right, jnp.int32),
+        depth=d, n_bins=B, interpret=True)
+    assert hist.shape == (2 * n_prev - derived, F, B, 2)
     routed = boost.unblock_rows(node_out, n)
     went_right = np.asarray(xb)[np.arange(n), np.asarray(feat)[node]] > \
         np.asarray(thr)[node]
     np.testing.assert_array_equal(np.asarray(routed),
                                   2 * np.asarray(node) + went_right)
-    ref = H.node_histograms_scatter(xb, g, h, routed, 2 * n_prev, B)
-    np.testing.assert_allclose(np.asarray(hist), np.asarray(ref),
-                               rtol=1e-4, atol=1e-4)
+    ref = np.asarray(H.node_histograms_scatter(xb, g, h, routed, 2 * n_prev, B))
+    if not derived:
+        np.testing.assert_allclose(np.asarray(hist), ref, rtol=1e-4, atol=1e-4)
+        with pytest.raises(ValueError, match="level 4 builds 16 of 16 nodes"):
+            boost.hist_level(*blocked, feat, thr, jnp.zeros(n_prev, jnp.int32),
+                             depth=d, n_bins=B, interpret=True)
+        return
+    assert 0 < built_right.sum() < n_prev
+    np.testing.assert_allclose(
+        np.asarray(hist), ref[2 * np.arange(n_prev) + built_right],
+        rtol=1e-4, atol=1e-4)
+    parents = H.node_histograms_scatter(xb, g, h, node, n_prev, B)
+    whole = boost.derive_siblings(parents, hist, jnp.asarray(built_right))
+    np.testing.assert_allclose(np.asarray(whole), ref, rtol=1e-4, atol=2e-4)
+    with pytest.raises(ValueError, match=rf"builds {n_prev} of {2 * n_prev} "
+                                         rf"nodes.*built_right \[{n_prev}\]"):
+        boost.hist_level(*blocked, feat, thr, depth=d, n_bins=B, interpret=True)
 
 
-@pytest.mark.parametrize("F,bins,tiles", [(67, 256, 1), (2000, 64, 16)],
-                         ids=["criteo", "epsilon"])
-def test_hist_plan_span_and_rows_streamed_gauge(F, bins, tiles):
+@pytest.mark.parametrize("F,bins,depth,tiles",
+                         [(67, 256, 8, 1), (2000, 64, 8, 16), (28, 256, 6, 1)],
+                         ids=["criteo", "epsilon", "higgs"])
+def test_hist_plan_span_and_rows_streamed_gauge(F, bins, depth, tiles):
     """Lowering the round at the Criteo shape leaves one ``gbdt.hist_plan``
     span a level with what the plan reckoned, and the gauge takes rows x
     passes over the row grid: depth histogram passes and the leaves'.  At
     the Epsilon shape (lowering only) the span says sixteen tiles of 128
     features and ONE tile's block, and the gauge counts a sweep a tile a
-    level and a routing pass a level."""
+    level and a routing pass a level.  ``nodes_derived`` is 0 up to level
+    4 and half the level's nodes from level 5 on, and the gauge
+    ``gbdt_hist_nodes_derived_per_round`` their sum: 16 at the HIGGS shape,
+    16 + 32 + 64 at depth 8."""
     import time
 
     from rabit_tpu import obs
     from rabit_tpu.ops import boost
 
-    n, depth, block = 2048, 8, 1024
+    n, block = 2048, 1024
     cfg = gbdt.GBDTConfig(n_features=F, n_trees=1, depth=depth, n_bins=bins)
     t0 = time.time()
     jax.jit(functools.partial(gbdt.train_round_fused, cfg=cfg, interpret=True)
@@ -527,6 +684,8 @@ def test_hist_plan_span_and_rows_streamed_gauge(F, bins, tiles):
                     jnp.zeros(n, jnp.float32))
     gauge = obs.get_registry().gauge("gbdt_hist_rows_streamed_per_round")
     assert gauge.value == (depth + 1 if tiles == 1 else depth * tiles + depth) * n
+    derived = obs.get_registry().gauge("gbdt_hist_nodes_derived_per_round")
+    assert derived.value == {6: 16, 8: 112}[depth]
     spans = [e.fields for e in obs.get_recorder().snapshot()
              if e.ts >= t0 and e.kind == "span"
              and e.fields.get("name") == "gbdt.hist_plan"]
@@ -538,9 +697,13 @@ def test_hist_plan_span_and_rows_streamed_gauge(F, bins, tiles):
             plan.nodes_built, plan.m_rows, plan.m_tiles,
             plan.acc_block_bytes, plan.vmem_bytes)
         assert (s["feat_tiles"], s["tile_feats"]) == (tiles, min(F, 128))
-    assert spans[-1]["nodes_built"] == 128 and spans[-1]["m_tiles"] == 4
+        assert s["nodes_derived"] == (2 ** (s["level"] - 1)
+                                      if s["level"] >= 5 else 0)
+        assert s["nodes_built"] + s["nodes_derived"] == 2 ** s["level"]
+    assert spans[-1]["nodes_built"] == 2 ** (depth - 2)
+    assert spans[-1]["m_tiles"] == {6: 1, 8: 2}[depth]
     if tiles > 1:
-        assert spans[-1]["acc_block_bytes"] == 256 * 128 * 128 * 4
+        assert spans[-1]["acc_block_bytes"] == 128 * 128 * 128 * 4
 
 
 def test_train_round_fused_i8_matches_reference():
@@ -785,15 +948,18 @@ def test_hybrid_round_with_an_identity_hop_is_the_fused_round(codes):
     assert np.abs(got.forest.leaf).max() > 0
 
 
-def test_hybrid_round_hops_depth_plus_one_times_in_order():
+@pytest.mark.parametrize("depth", [3, 6])
+def test_hybrid_round_hops_depth_plus_one_times_in_order(depth):
     """The collective sequence the engine's replay log is written against:
     a histogram ``[2**d, F, B, 2]`` a level, then the leaves' masses
-    ``[2**depth, 2]``, each under a tag of its own."""
+    ``[2**depth, 2]``, each under a tag of its own, ``2**level``.  At depth
+    6 the sequence keeps its length, order and tags, and level 5 sends the
+    built half of its nodes, ``[16, F, B, 2]`` under tag 32."""
     import time
 
     from rabit_tpu import obs
 
-    cfg, xb, y = _hybrid_case(rounds=1)
+    cfg, xb, y = _hybrid_case(rounds=1, depth=depth)
     seen = []
 
     def hop(a):
@@ -806,21 +972,25 @@ def test_hybrid_round_hops_depth_plus_one_times_in_order():
         interpret=True))(gbdt.init_state(cfg, y.shape[0]), xb, y)
     jax.block_until_ready(s)
     d, f, b = cfg.depth, cfg.n_features, cfg.n_bins
-    assert seen == [(2 ** k, f, b, 2) for k in range(d)] + [(2 ** d, 2)]
+    assert seen == [(2 ** k if k < 5 else 2 ** (k - 1), f, b, 2)
+                    for k in range(d)] + [(2 ** d, 2)]
     tags = [e.fields["level"] for e in obs.get_recorder().snapshot()
             if e.ts >= t0 and e.kind == "span"
             and e.fields.get("name") == "gbdt.cross"]
     assert tags == [2 ** k for k in range(d)] + [-1]
 
 
-def test_hybrid_round_leaf_hop_is_right_in_a_world_of_two():
+@pytest.mark.parametrize("depth", [3, 6])
+def test_hybrid_round_leaf_hop_is_right_in_a_world_of_two(depth):
     """A hop that returns ``2 * a`` is a world of two identical shards.  The
     forest must be the fused round's on the rows twice over: the leaf hop
     carries the LOCAL children's masses, so the engine's sum is the global
-    mass and not the world's multiple of it."""
+    mass and not the world's multiple of it.  At depth 6 the last level is
+    derived: its local histogram is the local parents' less the local built
+    children's, and the built child is chosen off the combined one."""
     from rabit_tpu.ops import boost
 
-    cfg, xb, y = _hybrid_case(n=2048)
+    cfg, xb, y = _hybrid_case(n=2048, depth=depth)
     n = y.shape[0]
     got = _train(jax.jit(functools.partial(
         gbdt.train_round_hybrid, cfg=cfg, engine_allreduce=lambda a: 2 * a,

@@ -1,13 +1,19 @@
-"""One tile is the program it was: up to ``ops.boost.TILE_FEATS`` features
-the three rounds the benchmark's accepted cells run trace to the jaxpr they
-traced to before the histogram kernels learned to walk features in tiles
-(PR 31), character for character, at the HIGGS and the Criteo shape.
+"""The three rounds the benchmark's accepted cells run, as jaxprs, at the
+HIGGS and the Criteo shape.
 
-The digests are of ``str(jax.make_jaxpr(...))`` with addresses stripped,
-recorded on the parent of PR 31 (commit 934b79c) by this file's own
-``digest``; tracing needs shapes only, so the real row counts cost nothing.
-A change that is meant to alter those programs records new ones here and
-says so."""
+Levels 0 to 4 are the program they were before any level was derived: a
+depth-5 round at the HIGGS shape (every node of every level built) traces
+to the jaxpr it traced to on the parent of PR 32 (commit 335edd8), character
+for character — the ``higgs-d5`` digests, recorded there.  From level 5 on
+``ops.boost.hist_plan`` builds one child a parent and derives its sibling
+(PR 32), so the six whole rounds were re-recorded on PR 32's tree; before,
+they were the parent of PR 31's (934b79c: one tile of features is the
+program it was before the kernels walked features in tiles).
+
+The digests are of ``str(jax.make_jaxpr(...))`` with addresses stripped, by
+this file's own ``digest``; tracing needs shapes only, so the real row
+counts cost nothing.  A change that is meant to alter those programs
+records new ones here and says so."""
 
 from __future__ import annotations
 
@@ -26,14 +32,19 @@ from rabit_tpu.parallel import create_mesh
 
 #: rows a chip, features, depth: benchmark/configs/higgs-10m5-quarter.json
 #: (a chip's share of higgs-10m5-dp4.json) and criteo-1tb-share.json
-SHAPES = {"higgs": (2_625_000, 28, 6), "criteo": (2_621_440, 67, 8)}
+SHAPES = {"higgs": (2_625_000, 28, 6), "criteo": (2_621_440, 67, 8),
+          "higgs-d5": (2_625_000, 28, 5)}
 WANT = {
-    ("higgs", "fused"): "8e9b34c90f1135e0",
-    ("higgs", "hybrid"): "d6f63572b85c0b47",
-    ("higgs", "dp_fused"): "a0a2a9645a4735dd",
-    ("criteo", "fused"): "7c5b8649eb664600",
-    ("criteo", "hybrid"): "b57665db1b6a94e2",
-    ("criteo", "dp_fused"): "f2d5b964a5d4d24f",
+    ("higgs", "fused"): "3f3c80735c0daa3f",
+    ("higgs", "hybrid"): "ef40f4892ec7bab6",
+    ("higgs", "dp_fused"): "797a5f6d6d9030e5",
+    ("criteo", "fused"): "a9d2175949153e23",
+    ("criteo", "hybrid"): "0ad9a7e8afc1b35f",
+    ("criteo", "dp_fused"): "59f0703b1f50c556",
+    # levels 0-4 alone: the parent's, recorded on 335edd8
+    ("higgs-d5", "fused"): "db3f2cfb968b83b9",
+    ("higgs-d5", "hybrid"): "ffe80bf26b7e912b",
+    ("higgs-d5", "dp_fused"): "ff079d267d88a086",
 }
 
 
@@ -52,7 +63,7 @@ def traced(shape: str, which: str) -> str:
         mesh = create_mesh(("dp",), devices=jax.devices()[:4])
         spec = gbdt.TrainState(forest=gbdt.Forest(P(), P(), P()),
                                margin=P("dp"), round=P())
-        if shape == "higgs":
+        if shape.startswith("higgs"):
             n *= 4
         fn = jax.shard_map(
             functools.partial(gbdt.train_round_dp_fused, cfg=cfg), mesh=mesh,

@@ -28,6 +28,13 @@ def export_tpu(fn, *args):
     return jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
 
 
+def _tables(d, make=lambda n: jnp.zeros(n, jnp.int32)):
+    """``hist_level``'s split tables of level ``d - 1``: feat, thr and, from
+    the level ``hist_plan`` derives (one child a parent built), built_right."""
+    derived = boost.hist_plan(F, B, d, R).nodes_derived
+    return (make(1 << (d - 1)),) * (3 if derived else 2)
+
+
 @pytest.mark.parametrize("i8", I8)
 def test_hist_kernel_lowers(i8):
     n = NB * R
@@ -49,28 +56,25 @@ def test_fused_level_kernels_lower(i8):
     export_tpu(
         functools.partial(boost.hist_level0, n_bins=B, mxu_i8=i8), xb3, g3, h3
     )
-    for d in (1, 5):
-        tab = jnp.zeros(1 << (d - 1), jnp.int32)
+    for d in (1, 4, 5):     # 5: the first level that builds one child a parent
         export_tpu(
             functools.partial(boost.hist_level, depth=d, n_bins=B, mxu_i8=i8),
-            xb3, node3, g3, h3, tab, tab,
+            xb3, node3, g3, h3, *_tables(d),
         )
-    # the Criteo width (67 features, 17,152 lanes) at the two levels whose
-    # gradient matrix passes one MXU tile; level 7 asks for its VMEM
+    # the Criteo width (67 features, 17,152 lanes) at the two levels that
+    # stack one and two MXU tiles of built children; both ask for their VMEM
     xc3 = jnp.zeros((NB, R, 67), jnp.int32)
     for d in (6, 7):
-        tab = jnp.zeros(1 << (d - 1), jnp.int32)
         export_tpu(
             functools.partial(boost.hist_level, depth=d, n_bins=B, mxu_i8=i8),
-            xc3, node3, g3, h3, tab, tab,
+            xc3, node3, g3, h3, *_tables(d),
         )
     # The r_split overlap experiment must lower before anyone spends chip
     # time measuring it (the exact failure mode this file exists for).
-    tab = jnp.zeros(1 << 4, jnp.int32)
     export_tpu(
         functools.partial(boost.hist_level, depth=5, n_bins=B, mxu_i8=i8,
                           r_split=2),
-        xb3, node3, g3, h3, tab, tab,
+        xb3, node3, g3, h3, *_tables(5),
     )
 
 
@@ -192,35 +196,44 @@ def test_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d, i8):
         c = _compile(functools.partial(boost.hist_level0, n_bins=B,
                                        mxu_i8=i8), xb3, g3, g3)
     else:
-        tab = _sds((1 << (d - 1),), jnp.int32, one_chip)
         c = _compile(functools.partial(boost.hist_level, depth=d, n_bins=B,
                                        mxu_i8=i8),
-                     xb3, node3, g3, g3, tab, tab)
+                     xb3, node3, g3, g3,
+                     *_tables(d, lambda n: _sds((n,), jnp.int32, one_chip)))
     assert "tpu_custom_call" in c.as_text()
 
 
 # benchmark/configs/criteo-1tb-share.json: 2,621,440 rows x 67 features,
-# depth 8.  Levels 6 and 7 are where the stacked gradient matrix passes one
-# MXU tile, and level 7's accumulator block alone (16.75 MiB) passes
-# Mosaic's default scoped VMEM.
+# depth 8.  Levels 5 to 7 build one child a parent: 16, 32 and 64 nodes, the
+# stacked gradient matrix of levels 4 to 6 (half, one and two MXU tiles), an
+# accumulator block of 2.1, 4.2 and 8.4 MiB where every node built took 4.2,
+# 8.4 and 16.75.
 NB_CRITEO, F_CRITEO = 2560, 67
 
 
-@pytest.mark.parametrize("d", (5, 6, 7, 8))
+@pytest.mark.parametrize("d", (5, 6, 7, 8,
+                               pytest.param(9, marks=pytest.mark.slow)))
 def test_criteo_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d):
     """What ``hist_plan`` lets through, the chip's compiler takes, with the
-    scoped VMEM the plan asks for: level 7, which the default refuses for
-    the kernel on its own, here and on the chip alike ("Scoped allocation
-    with size 21.75M and limit 16.00M"; PR 27), and level 8, the deepest
-    the plan lets through at this width (527.8 ms on the chip)."""
+    scoped VMEM the plan asks for, at the halved block of a derived level:
+    level 7 asks 21.4 MiB for an 8.4 MiB block (24.75 for 16.75 with every
+    node built).  Level 8 is now that 16.75 MiB block, which the default
+    refuses for the kernel on its own, here and on the chip alike ("Scoped
+    allocation with size 21.75M and limit 16.00M"; PR 27), and level 9
+    (33.5 MiB, 46.5 asked; a 54 s compile, ``-m slow``) is the deepest the
+    plan lets through at this width."""
     plan = boost.hist_plan(F_CRITEO, B, d, R)
+    assert plan.nodes_built == plan.nodes_derived == 1 << (d - 1)
+    assert plan.acc_block_bytes == (1 << d) * F_CRITEO * B * 4
+    assert plan.vmem_bytes == plan.acc_block_bytes + (5 << 20) + boost.VMEM_STACK
     xb3, g3, node3 = _blocked(one_chip, NB_CRITEO, F_CRITEO)
-    tab = _sds((1 << (d - 1),), jnp.int32, one_chip)
     c = _compile(functools.partial(boost.hist_level, depth=d, n_bins=B),
-                 xb3, node3, g3, g3, tab, tab)
+                 xb3, node3, g3, g3,
+                 *_tables(d, lambda n: _sds((n,), jnp.int32, one_chip)))
     text = c.as_text()
     assert "tpu_custom_call" in text
     assert (str(plan.vmem_bytes) in text) == (plan.vmem_bytes > boost.VMEM_DEFAULT)
+    assert (plan.vmem_bytes > boost.VMEM_DEFAULT) == (d >= 6)
 
 
 # benchmark/configs/epsilon-400k.json: 400,000 rows (391 row blocks) x 2,000
@@ -229,28 +242,35 @@ def test_criteo_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d):
 NB_EPSILON, F_EPSILON, B_EPSILON = 391, 2000, 64
 
 
-@pytest.mark.parametrize("d", (0, 5, 6, 7))
+@pytest.mark.parametrize("d", (0, 4, 5, 6, 7))
 def test_epsilon_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d):
-    """The tiled level kernels at the cell's block count: the root, the
-    first level that asks for its VMEM (5: two 4 MiB blocks of one tile)
-    and the two whose gradient matrix passes one MXU tile; level 7 asks for
-    44 MiB.  Each is two custom calls below the root: the routing pass and
-    the sweep of the feature tiles."""
+    """The tiled level kernels at the cell's block count: the root, the last
+    level that builds every node (4: two 2 MiB blocks of one tile, the
+    default's 16 MiB to the byte) and the three that build one child a
+    parent: level 5 is level 4's block and asks nothing, level 6 (two 4 MiB
+    blocks) is the first to ask, level 7 asks 28 MiB for its 8 MiB block (44
+    for 16 with every node built).  Each is two custom calls below the root:
+    the routing pass and the sweep of the feature tiles."""
     plan = boost.hist_plan(F_EPSILON, B_EPSILON, d, R)
     assert (plan.feat_tiles, plan.tile_feats) == (16, 128)
+    built = 1 << (d - 1 if d >= 5 else d)
+    assert (plan.nodes_built, plan.nodes_derived) == (built, (1 << d) - built)
+    assert plan.acc_block_bytes == max(8, 2 * built) * 128 * 128 * 4
     xb3, g3, node3 = _blocked(one_chip, NB_EPSILON, F_EPSILON)
     if d == 0:
         c = _compile(functools.partial(boost.hist_level0, n_bins=B_EPSILON),
                      xb3, g3, g3)
     else:
-        tab = _sds((1 << (d - 1),), jnp.int32, one_chip)
         c = _compile(functools.partial(boost.hist_level, depth=d,
                                        n_bins=B_EPSILON),
-                     xb3, node3, g3, g3, tab, tab)
+                     xb3, node3, g3, g3,
+                     *_tables(d, lambda n: _sds((n,), jnp.int32, one_chip)))
     text = c.as_text()
     assert text.count("tpu_custom_call") >= (2 if d else 1)
     assert (str(plan.vmem_bytes) in text) == (plan.vmem_bytes > boost.VMEM_DEFAULT)
-    assert plan.vmem_bytes > boost.VMEM_DEFAULT or d == 0
+    assert (plan.vmem_bytes > boost.VMEM_DEFAULT) == (d >= 6)
+    if d == 7:
+        assert plan.vmem_bytes == 28 << 20
 
 
 def test_route_level_compiles_for_v5e(one_chip, no_compile_cache):
@@ -271,7 +291,8 @@ def _dp_mesh(topo):
 def test_dp_fused_level_compiles_on_four_chips(topo, no_compile_cache, f, d):
     """One level of the sharded round — fused kernel per shard + the
     per-level psum — over the four described chips, a quarter of the
-    blocks each; at the Criteo width and level 7 too."""
+    blocks each; at the Criteo width and level 7 too.  Both levels build
+    one child a parent, so the all-reduce is of half the level's nodes."""
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -281,20 +302,22 @@ def test_dp_fused_level_compiles_on_four_chips(topo, no_compile_cache, f, d):
     rows = NamedSharding(mesh, P("dp", None, None))
     rep = NamedSharding(mesh, P())
     xb3, g3, node3 = _blocked(rows, nb, f)
-    tab = _sds((1 << (d - 1),), jnp.int32, rep)
+    tabs = _tables(d, lambda n: _sds((n,), jnp.int32, rep))
 
-    def level(xb3, node3, g3, h3, feat, thr):
+    def level(xb3, node3, g3, h3, feat, thr, built_right):
         hist, node3 = boost.hist_level(xb3, node3, g3, h3, feat, thr,
-                                       depth=d, n_bins=B)
+                                       built_right, depth=d, n_bins=B)
         return lax.psum(hist, "dp"), node3
 
     fn = jax.shard_map(
         level, mesh=mesh,
-        in_specs=(P("dp", None, None),) * 4 + (P(), P()),
+        in_specs=(P("dp", None, None),) * 4 + (P(),) * 3,
         out_specs=(P(), P("dp", None, None)), check_vma=False)
-    c = _compile(fn, xb3, node3, g3, g3, tab, tab)
+    c = _compile(fn, xb3, node3, g3, g3, *tabs)
     text = c.as_text()
     assert "all-reduce" in text and "tpu_custom_call" in text
+    # the psum carries the built half of the level's nodes
+    assert f"f32[{1 << (d - 1)},{f},{B},2]" in text
     # each device holds a quarter of the rows, not all of them: the
     # int32 feature blocks alone are nb*R*F*4 bytes in total
     total = nb * R * f * 4
