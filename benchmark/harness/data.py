@@ -1,30 +1,28 @@
 """The benchmark's data: quantised features and labels from ``--seed``.
 
-The yardstick's own copy of ``bench.make_data`` (numpy only, so the parent
-and the device worker make the same bytes without sharing a file).  Bin
-codes are drawn uniformly; the label follows two of the features plus
-noise, so a tree finds one strong split, a smooth one and then noise —
-near-ties included, which is what the comparison has to live with.  The
-codes come back in the narrowest unsigned type that holds ``bins``; the
-worker widens them to the int32 the program's kernels take.
+Which data is the configuration's to say: its ``data`` group names a
+generator's file (``harness/datagen/``) and that generator's parameters, and
+``draw`` is the one way ``run.py``, ``worker.py`` and ``tools/control.py``
+come by codes and labels — numpy only, so the parent and the device worker
+make the same bytes without sharing a file.  ``make_data`` is the uniform
+generator under the name it had while it was the only one.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
+from harness import deployment
+from harness.datagen.uniform import make as make_data  # noqa: F401
 
-def make_data(rows: int, features: int, bins: int, seed: int):
-    """``(codes[rows, features], y[rows] float32)`` for any whole ``seed``
-    (the driver's run a little past 2**31)."""
-    rng = np.random.default_rng(int(seed) & 0xFFFFFFFFFFFFFFFF)
-    dtype = np.uint8 if bins <= 256 else np.uint16
-    codes = rng.integers(0, bins, size=(rows, features), dtype=dtype)
-    step = (codes[:, 0] > bins // 2).astype(np.float32)
-    slope = np.float32(2.56 / bins) * codes[:, 1].astype(np.float32)
-    noise = rng.standard_normal(rows, dtype=np.float32)
-    y = (step + slope + noise > 1.5).astype(np.float32)
-    return codes, y
+
+def draw(config: dict, seed: int):
+    """``(codes[rows, features], y[rows])`` of the configuration, from its own
+    generator: ``make(rows, features, bins, seed, **parameters)``."""
+    parameters = dict(deployment.named(config, "data"))
+    make = deployment.module_at(parameters.pop("file")).make
+    return make(config["rows"], config["features"], config["max_bin"], seed,
+                **parameters)
 
 
 def block_host(x: np.ndarray, block: int) -> np.ndarray:

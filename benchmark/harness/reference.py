@@ -18,6 +18,13 @@ It runs in two ways with the same code:
   far the leaves taken lie from its own.  Its margin moves by its OWN leaves,
   so it stays the exact result of the trees' structure.
 
+A configuration names this file under ``reference`` and the harness calls
+three functions of it, each given the configuration as it is run: ``follow``
+(``run.py``, after the window), ``free`` (``tools/control.py``: the reference
+in the program's place) and ``first_numbers`` (``worker.py``, after each of
+the first rounds).  A deployment with another schema brings a copy of this
+file with those three, and ``margins_of`` and ``logloss`` for the control.
+
 Features are spread over forked processes (the matrix is shared copy on
 write; each process routes the rows itself and only the per-node winners
 cross a pipe), because a level is 2 x features bincounts over every row.
@@ -248,6 +255,34 @@ def boost_rounds(codes: np.ndarray, y: np.ndarray, p: Params, rounds: int,
             if kid.is_alive():
                 kid.kill()
                 kid.join()
+
+
+def _params(config: dict) -> Params:
+    return Params(config["max_depth"], config["max_bin"], config["eta"],
+                  config["lambda"], config["min_child_weight"])
+
+
+def follow(config: dict, codes, y, forest, rounds: int) -> Followed:
+    """The reference following the first ``rounds`` trees of the timed path:
+    ``forest`` holds the tables of the program's ``Forest`` in its order,
+    here ``(feature, threshold, leaf)``."""
+    return boost_rounds(codes, y, _params(config), rounds, follow=tuple(forest))
+
+
+def free(config: dict, codes, y, rounds: int, gh_dtype=None) -> tuple:
+    """The trees the reference grows by itself (``gh_dtype``: with gradients
+    and hessians rounded to that type), as the program's ``Forest`` would
+    hold them: its tables in its order and its types."""
+    own = boost_rounds(codes, y, _params(config), rounds, gh_dtype=gh_dtype)
+    return own.feature, own.threshold, own.leaf.astype(np.float32)
+
+
+def first_numbers(margin: np.ndarray, y: np.ndarray) -> dict:
+    """What the worker notes after each of its first rounds, from the margin
+    it committed: the numbers ``Followed`` holds under the same names."""
+    m, y64 = margin.astype(np.float64), y.astype(np.float64)
+    return {"logloss": float(np.mean(np.logaddexp(0.0, m) - y64 * m)),
+            "margin_norm": float(np.sqrt(np.sum(m * m)))}
 
 
 def margins_of(codes: np.ndarray, feature, threshold, leaf):

@@ -2,7 +2,9 @@
 --seed <n> --seconds <s> --trace <0|1>`` from the root of a checkout.
 
 This process never imports jax.  It reads the cell from ``BENCHMARK.json``
-and the cell's configuration and traffic from their own files, builds the
+and the cell's configuration and traffic from their own files (the
+configuration names its data's generator, its reference and its program's
+switches: ``harness/deployment.py``), builds the
 native engine (``make -C native``), and starts ONE device process,
 ``benchmark/worker.py``, through ``python -m rabit_tpu.tracker.launcher
 -n 1`` — the entry a user's trainer takes.  When the window has closed and
@@ -20,7 +22,6 @@ import time
 T_CMD = time.time()   # the start of the command, before the heavier imports
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
@@ -34,7 +35,7 @@ HERE = Path(__file__).resolve().parent
 ROOT = HERE.parent
 sys.path.insert(0, str(HERE))
 
-from harness import compare, data as bdata, reference, work  # noqa: E402
+from harness import compare, data as bdata, deployment, work  # noqa: E402
 
 
 class RunFailure(Exception):
@@ -55,18 +56,15 @@ def load_cell(workload: str, manifest_path: Path | None = None):
     cell = cells[workload]
     entry = {c["name"]: c for c in manifest["configs"]}[cell["config"]]
     config = json.loads((ROOT / entry["file"]).read_text())
+    for key in deployment.NAMES:   # a missing one fails before a worker starts
+        deployment.named(config, key)
     traffic = json.loads((HERE / "traffic" / f"{cell['traffic']}.json").read_text())
     limits = json.loads((ROOT / entry["file"]).with_suffix(".limits.json").read_text())
     return manifest, cell, config, traffic, limits
 
 
 def load_reader(name: str):
-    path = HERE / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+    return deployment.module_at(HERE / "metrics" / f"{name}.py")
 
 
 def build_native() -> float:
@@ -138,11 +136,9 @@ def follow_reference(ev: dict, codes, y):
     first = ev["lives"][0].get("first")
     if not first:
         raise RunFailure("the first life recorded no first rounds")
-    params = reference.Params(c["max_depth"], c["max_bin"], c["eta"],
-                              c["lambda"], c["min_child_weight"])
-    follow = tuple(np.asarray(a) for a in first["forest"])
-    return reference.boost_rounds(codes, y, params, ev["traffic"]["check_rounds"],
-                                  follow=follow)
+    forest = tuple(np.asarray(a) for a in first["forest"])
+    return deployment.reference_of(c).follow(
+        c, codes, y, forest, ev["traffic"]["check_rounds"])
 
 
 def setup_split(ev: dict) -> dict:
@@ -188,8 +184,7 @@ def main(argv=None, rehearsal: dict | None = None, manifest: Path | None = None)
     proc, log, limit = start_worker(out, spec, traffic, env)
     try:
         # the reference's copy of the data, made while the worker sets up
-        codes, y = bdata.make_data(config["rows"], config["features"],
-                                   config["max_bin"], args.seed)
+        codes, y = bdata.draw(config, args.seed)
     finally:
         wait_worker(out, proc, log, limit)
     ev = gather(out, cell, config, traffic, args.seconds,
@@ -264,6 +259,6 @@ def main(argv=None, rehearsal: dict | None = None, manifest: Path | None = None)
 if __name__ == "__main__":
     try:
         sys.exit(main())
-    except (RunFailure, subprocess.TimeoutExpired) as e:
+    except (RunFailure, deployment.ConfigError, subprocess.TimeoutExpired) as e:
         say(f"benchmark/run.py: FAILED after {time.time() - T_CMD:.0f}s: {e}")
         sys.exit(1)

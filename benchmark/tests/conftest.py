@@ -13,11 +13,11 @@ if str(BENCH) not in sys.path:
     sys.path.insert(0, str(BENCH))
 
 
-def load_file(path: Path):
-    """A module by its file, for directories that are no packages."""
-    import importlib.util
+from harness.deployment import module_at as load_file  # noqa: E402,F401
 
-    spec = importlib.util.spec_from_file_location("bench_" + path.stem, path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+
+def bench_bytes() -> dict:
+    """Every file under ``benchmark/`` with its bytes: a test that adds a
+    cell, a configuration or a deployment has edited none of them."""
+    return {p: p.read_bytes() for p in BENCH.rglob("*") if p.is_file()
+            and "__pycache__" not in p.parts}

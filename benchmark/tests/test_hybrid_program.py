@@ -20,13 +20,17 @@ import functools, json, re, sys
 import jax, jax.numpy as jnp, numpy as np
 from rabit_tpu.models import gbdt
 
+sys.path.insert(0, "benchmark")
+from harness import deployment
+
 jax.default_backend = lambda: "tpu"     # the branch the chip takes
 c = json.loads(sys.argv[1])
-cfg = gbdt.GBDTConfig(          # worker.py's keywords
+# as worker.py builds it: the sizes and the ``program`` group's switches
+cfg = deployment.gbdt_config(c, gbdt.GBDTConfig)
+assert cfg == gbdt.GBDTConfig(
     n_features=c["features"], n_trees=c["num_trees"], depth=c["max_depth"],
     n_bins=c["max_bin"], learning_rate=c["eta"], reg_lambda=c["lambda"],
-    min_child_weight=c["min_child_weight"], mxu_i8=c["program"]["mxu_i8"],
-    fused_final=c["program"]["fused_final"], r_split=c["program"]["r_split"])
+    min_child_weight=c["min_child_weight"]), cfg
 n = c["rows"]
 sds = jax.ShapeDtypeStruct
 state = jax.eval_shape(lambda: gbdt.init_state(cfg, n))

@@ -70,6 +70,24 @@ def test_configs_are_files_of_their_own_and_used():
             assert key in body and f"{key}_published" in body
 
 
+@pytest.mark.parametrize("entry", M["configs"], ids=lambda e: e["name"])
+def test_a_configuration_names_its_data_its_reference_and_its_switches(entry):
+    """The three keys ``harness/deployment.py`` reads, each a file under the
+    benchmark's own directories with the functions the harness calls."""
+    from harness import deployment
+
+    body = json.loads((ROOT / entry["file"]).read_text())
+    files = {"data": body["data"]["file"], "reference": body["reference"]}
+    for path in files.values():
+        assert any(path.startswith(d + "/") for d in M["paths"]), path
+        assert (ROOT / path).is_file(), path
+    assert callable(deployment.module_at(files["data"]).make)
+    reference = deployment.module_at(files["reference"])
+    for name in ("follow", "free", "first_numbers"):
+        assert callable(getattr(reference, name)), name
+    assert isinstance(body["program"]["block_rows"], int)
+
+
 def test_cells_find_their_traffic_and_four_chip_cells_are_few():
     for c in M["workloads"]:
         assert c["chips"] in (1, 4)

@@ -11,7 +11,7 @@ import shutil
 import pytest
 
 import run
-from conftest import BENCH, ROOT
+from conftest import BENCH, ROOT, bench_bytes
 
 M = json.loads((ROOT / "BENCHMARK.json").read_text())
 CPU_TRACE = {"device_plane": "^/host:CPU$", "op_lines": ["^tf_XLA"]}
@@ -111,8 +111,7 @@ def test_adding_a_cell_a_configuration_and_a_metric_takes_only_files(capsys, tmp
     """README's worked example: cell 4 (engine-hop) on a configuration of its
     own with a per-layer metric of its own — new files, new entries, and no
     edit to a file that exists."""
-    before = {p: p.read_bytes() for p in BENCH.rglob("*") if p.is_file()
-              and "__pycache__" not in p.parts}
+    before = bench_bytes()
     added = [BENCH / "configs" / "zz-dummy.json",
              BENCH / "configs" / "zz-dummy.limits.json",
              BENCH / "metrics" / "zz.hops_a_round.py"]
@@ -154,9 +153,7 @@ def test_adding_a_cell_a_configuration_and_a_metric_takes_only_files(capsys, tmp
     finally:
         for p in added:
             p.unlink(missing_ok=True)
-    after = {p: p.read_bytes() for p in BENCH.rglob("*") if p.is_file()
-             and "__pycache__" not in p.parts}
-    assert after == before
+    assert bench_bytes() == before
 
 
 def test_the_kept_cell_epsilon_engine_hop_takes_only_entries(capsys, tmp_path):

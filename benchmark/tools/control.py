@@ -1,7 +1,8 @@
 """The control and the planted faults, read as a run would read them.
 
 ``python3 benchmark/tools/control.py --config <name> --seeds 1 2 3`` makes
-the configuration's data from each seed, puts in the program's place
+the configuration's data from each seed with the configuration's own
+generator and, with the reference it names, puts in the program's place
 
 * ``control``        the plain reference with gradients and hessians rounded
                      to the configuration's ``control_precision`` (bfloat16:
@@ -32,30 +33,30 @@ import numpy as np
 HERE = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(HERE))
 
-from harness import compare, data as bdata, reference  # noqa: E402
+from harness import compare, data as bdata, deployment  # noqa: E402
 
 CASES = ("control", "half_batch", "no_exchange", "state_unchanged")
 
 
-def in_programs_place(case: str, codes, y, params, rounds: int, precision: str):
+def in_programs_place(case: str, reference, config: dict, codes, y, rounds: int):
     """What a worker's ``first`` would hold had this case been the program."""
     if case == "control":
         import ml_dtypes
 
-        free = reference.boost_rounds(codes, y, params, rounds,
-                                      gh_dtype=getattr(ml_dtypes, precision))
+        forest = reference.free(
+            config, codes, y, rounds,
+            gh_dtype=getattr(ml_dtypes, config["control_precision"]))
     elif case == "half_batch":
-        free = reference.boost_rounds(codes[::2], y[::2], params, rounds)
+        forest = reference.free(config, codes[::2], y[::2], rounds)
     elif case == "no_exchange":
         q = codes.shape[0] // 4
-        free = reference.boost_rounds(codes[:q], y[:q], params, rounds)
+        forest = reference.free(config, codes[:q], y[:q], rounds)
     elif case == "state_unchanged":
-        free = reference.boost_rounds(codes, y, params, rounds)
-        for a in (free.feature, free.threshold, free.leaf):
+        forest = reference.free(config, codes, y, rounds)
+        for a in forest:
             a[1] = 0          # round 2 left the forest's slot as it was
     else:
         raise ValueError(case)
-    forest = (free.feature, free.threshold, free.leaf.astype(np.float32))
     margins = reference.margins_of(codes, *forest)   # a zeroed tree adds nought
     return {"forest": [a.tolist() for a in forest],
             "logloss": [reference.logloss(m.astype(np.float32), y) for m in margins],
@@ -64,16 +65,14 @@ def in_programs_place(case: str, codes, y, params, rounds: int, precision: str):
 
 
 def read_case(case: str, config: dict, limits: dict, seed: int, rows: int | None = None):
-    rows = rows or config["rows"]
-    codes, y = bdata.make_data(rows, config["features"], config["max_bin"], seed)
-    params = reference.Params(config["max_depth"], config["max_bin"], config["eta"],
-                              config["lambda"], config["min_child_weight"])
+    if rows:
+        config = {**config, "rows": rows}
+    codes, y = bdata.draw(config, seed)
+    reference = deployment.reference_of(config)
     rounds = 3
-    first = in_programs_place(case, codes, y, params, rounds,
-                              config["control_precision"])
-    followed = reference.boost_rounds(
-        codes, y, params, rounds,
-        follow=tuple(np.asarray(a) for a in first["forest"]))
+    first = in_programs_place(case, reference, config, codes, y, rounds)
+    followed = reference.follow(
+        config, codes, y, [np.asarray(a) for a in first["forest"]], rounds)
     ev = {"lives": [{"first": first, "version": rounds}], "rounds": [],
           "traffic": {"check_rounds": rounds}}
     compared = compare.numbers(ev, followed, limits)

@@ -9,13 +9,16 @@ a user's trainer is (``guide/hybrid_gbdt.py`` is the model): ``init``,
 has returned.
 
 Everything that differs between cells arrives as data in ``spec=<file>``:
-the configuration's sizes, the traffic's loop parameters, the seed, the
-window's length.  The first ``check_rounds`` rounds run in set-up through
-the window's own call on the window's own state, and what they produced is
-what ``run.py`` compares with the plain reference.  The window's clock,
-the per-round stamps, the counters and (with ``trace``) the profiler's
-trace reduced to a table go to ``<out>/life<k>.json``; this file decides
-nothing about a metric or about ``correct``.
+the configuration's sizes with what it names (its data's generator, its
+reference, its program's switches: ``harness/deployment.py``), the traffic's
+loop parameters, the seed, the window's length.  The first ``check_rounds``
+rounds run in set-up through the window's own call on the window's own
+state, and what they produced is what ``run.py`` compares with the plain
+reference; the numbers noted after each are the reference's own
+``first_numbers``.  The window's clock, the per-round stamps, the counters
+and (with ``trace``) the profiler's trace reduced to a table go to
+``<out>/life<k>.json``; this file decides nothing about a metric or about
+``correct``.
 """
 
 from __future__ import annotations
@@ -38,7 +41,7 @@ ROOT = HERE.parent
 sys.path.insert(0, str(ROOT))
 sys.path.insert(0, str(HERE))
 
-from harness import data as bdata  # noqa: E402
+from harness import data as bdata, deployment  # noqa: E402
 
 #: a restarted life reads the first life's window from here
 WINDOW_FILE = "window.json"
@@ -125,20 +128,14 @@ def main() -> int:
         return 3
     compilations = Compilations()
 
-    rows, features = cfg_in["rows"], cfg_in["features"]
+    rows = cfg_in["rows"]
     block = cfg_in["program"]["block_rows"]
-    codes, y = bdata.make_data(rows, features, cfg_in["max_bin"], spec["seed"])
+    codes, y = bdata.draw(cfg_in, spec["seed"])
     xb = codes.astype(np.int32)
     del codes
     stamps["data"] = time.time()
-    cfg = gbdt.GBDTConfig(
-        n_features=features, n_trees=cfg_in["num_trees"],
-        depth=cfg_in["max_depth"], n_bins=cfg_in["max_bin"],
-        learning_rate=cfg_in["eta"], reg_lambda=cfg_in["lambda"],
-        min_child_weight=cfg_in["min_child_weight"],
-        mxu_i8=cfg_in["program"]["mxu_i8"],
-        fused_final=cfg_in["program"]["fused_final"],
-        r_split=cfg_in["program"]["r_split"])
+    cfg = deployment.gbdt_config(cfg_in, gbdt.GBDTConfig)
+    reference = deployment.reference_of(cfg_in)
     interpret = bool(rehearse.get("interpret"))
     fault = rehearse.get("fault")
     if fault:
@@ -188,8 +185,9 @@ def main() -> int:
                             for i in range(n_dev)]),
             NamedSharding(mesh, P("dp", None, None)))
         data = (xb3, place(y))
-        sspec = gbdt.TrainState(forest=gbdt.Forest(P(), P(), P()),
-                                margin=P("dp"), round=P())
+        sspec = gbdt.TrainState(
+            forest=gbdt.Forest(*(P() for _ in gbdt.Forest._fields)),
+            margin=P("dp"), round=P())
         step = jax.jit(jax.shard_map(
             functools.partial(gbdt.train_round_dp_fused, cfg=cfg,
                               interpret=interpret),
@@ -273,16 +271,13 @@ def main() -> int:
     window_path = out / WINDOW_FILE
     if not window_path.exists():
         # Set-up's last part: the first rounds, on the object the window gets.
-        y64 = y.astype(np.float64)
-        first = {"logloss": [], "margin_norm": []}
+        first = {}
         for _ in range(traffic["check_rounds"]):
             one_round()
-            m = last["margin"].astype(np.float64)
-            first["logloss"].append(float(np.mean(np.logaddexp(0.0, m) - y64 * m)))
-            first["margin_norm"].append(float(np.sqrt(np.sum(m * m))))
+            for name, v in reference.first_numbers(last["margin"], y).items():
+                first.setdefault(name, []).append(v)
         k = traffic["check_rounds"]
         first["forest"] = [np.asarray(a[:k]).tolist() for a in last["forest"]]
-        del y64, m
         stamps["warm"] = time.time()
         window = {"start": time.time(), "seconds": spec["seconds"]}
         write_json(window_path, window)
