@@ -350,10 +350,12 @@ def train_round_fused(
 
     When the round is lowered, each level leaves one ``gbdt.hist_plan`` span
     with what ``ops.boost.hist_plan`` reckoned for its kernel
-    (``nodes_built``, ``nodes_derived``), the gauge
+    (``nodes_built``, ``nodes_derived``, ``lanes_a_feature``), the gauge
     ``gbdt_hist_rows_streamed_per_round`` takes rows x (tile sweeps of every
-    level + routing passes), and ``gbdt_hist_nodes_derived_per_round`` the
-    nodes a round reads off a subtraction (16 at depth 6, 112 at depth 8).
+    level + routing passes), ``gbdt_hist_nodes_derived_per_round`` the
+    nodes a round reads off a subtraction (16 at depth 6, 112 at depth 8),
+    and ``gbdt_hist_feats_a_register`` the features that share a 128-lane
+    register of the indicator (2 at up to 64 bins, else 1).
 
     ``xb3`` is the pre-blocked quantized matrix from ``ops.boost.block_rows``
     (built once per fit).  ``combine`` is the histogram allreduce hook
@@ -411,6 +413,7 @@ def train_round_fused(
                 nodes_derived=plan.nodes_derived,
                 m_rows=plan.m_rows, m_tiles=plan.m_tiles,
                 feat_tiles=plan.feat_tiles, tile_feats=plan.tile_feats,
+                lanes_a_feature=plan.lanes_a_feature,
                 acc_block_bytes=plan.acc_block_bytes,
                 vmem_bytes=plan.vmem_bytes):
             if plan.nodes_derived:
@@ -430,12 +433,15 @@ def train_round_fused(
     # a sweep a feature tile a level, the root's included; the leaves'
     # routing pass, and one a level below the root where routing is a pass
     # of its own (more tiles than one)
-    tiles = boost.hist_plan(xb3.shape[2], cfg.n_bins, 0, block).feat_tiles
+    root = boost.hist_plan(xb3.shape[2], cfg.n_bins, 0, block)
+    tiles = root.feat_tiles
     passes = cfg.depth * tiles + (1 if tiles == 1 else cfg.depth)
     obs.get_registry().gauge("gbdt_hist_rows_streamed_per_round").set(
         passes * xb3.shape[0] * block)
     obs.get_registry().gauge("gbdt_hist_nodes_derived_per_round").set(
         nodes_derived)
+    obs.get_registry().gauge("gbdt_hist_feats_a_register").set(
+        max(1, 128 // root.lanes_a_feature))
     # Leaf (g, h) masses come straight off the final combined histogram
     # (split_child_masses) — already globally reduced, so no leaf collective
     # and no histogram work in the last row pass (depth collectives per

@@ -19,7 +19,13 @@ into a single streaming pass per row block:
 The histogram itself is the one-hot MXU contraction of ``ops.hist``: the
 row block's gradient matrix L (one g column + one h column per node) is
 contracted against per-feature bin indicators built in VMEM; f32 gradients
-are split hi/lo into two bfloat16 matmuls (error ~2^-16-relative).
+are split hi/lo into two bfloat16 matmuls (error ~2^-16-relative).  The
+lanes a feature takes in the indicator follow the bins (``_bins_eff``):
+whole 128-lane registers above 64 bins; at up to 64 bins 64 lanes, two
+features a register, and since what bounds the build is the lane broadcast
+of a code column, the kernel packs the block's codes four a word first so
+that one broadcast serves two registers (``_accum``).  The rule reads
+``n_bins`` alone; no option chooses.
 
 ``hist_plan`` reckons what a level asks of the chip from the shape alone.
 The stacked gradient matrix has 4 rows a node built (g and h, hi and lo
@@ -29,7 +35,8 @@ level whose nodes would stack a tile or more — 4 * 2**level >= ``MXU_ROWS``:
 level 5, at any width — builds half of them, one child a parent, with the
 matmul, the accumulator block and the VMEM of the level above; no option
 chooses.  Up to ``TILE_FEATS`` features (one 128-lane tile of blocked codes)
-the accumulator ``(2 * nodes built, F * B_eff)`` float32 is ONE block held
+the accumulator ``(2 * nodes built, F * B_eff)`` float32 (F to whole
+words of four at 64 lanes a feature) is ONE block held
 across the row grid, and routing rides the histogram's sweep.  A wider matrix is
 walked in feature tiles: a grid over (feature tile, row block), the row
 axis innermost, one tile's accumulator block resident across its row
@@ -70,22 +77,35 @@ def _check_r_split(R: int, r_split: int) -> None:
 
 
 def _bins_eff(n_bins: int) -> int:
-    """Mask width per feature: bins padded to full 128-lane registers (the
-    pad columns never match a bin id, so they stay zero)."""
-    return _round_up(n_bins, 128)
+    """Lanes a feature takes in the indicator matrix and the accumulator:
+    64 at up to 64 bins, so that two features share one 128-lane register,
+    else the bins padded to whole registers (128 at 65 to 128 bins, 256 at
+    256).  The pad columns never match a bin id, so they stay zero."""
+    return 64 if n_bins <= 64 else _round_up(n_bins, 128)
+
+
+def _block_feats(n_feat: int, n_bins: int) -> int:
+    """Feature slots of an accumulator block over ``n_feat`` features: at 64
+    lanes a feature the kernels pack the codes four a word (``_accum``), so
+    whole words of slots."""
+    return _round_up(n_feat, 4) if _bins_eff(n_bins) < 128 else n_feat
 
 
 def _pick_fc(n_feat: int, n_bins: int) -> int:
-    """Features per matmul group (N = fc * bins_eff ~ 1792 lanes)."""
+    """Features per matmul group (N = fc * bins_eff ~ 1792 lanes: 28
+    features at up to 64 bins, 14 at up to 128, 7 at 256)."""
     return min(n_feat, max(1, 1792 // _bins_eff(n_bins)))
 
 
 def _pick_tile_fc(n_bins: int) -> int:
     """Features per matmul group inside a ``TILE_FEATS``-feature tile (N =
-    2048 lanes: 16 at up to 128 bins, 8 at 256), a power of two so that the
-    groups of a tile are all whole.  At F = 2000 x 64 bins groups of 16
-    were 6 % faster than of 8 at levels 0-4 and 4 % at level 7, groups of
-    4 12 % slower (my chip run, PR 31)."""
+    2048 lanes: 32 at up to 64 bins, 16 at up to 128, 8 at 256), a power of
+    two so that the groups of a tile are all whole.  At F = 2000 x 64 bins
+    x 400,384 rows, 64 lanes a feature, single kernels: groups of 32 (2048
+    lanes) took 74.2 | 78.5 | 174.6 ms at levels 0 | 4 | 7, groups of 16
+    (1024 lanes) 83.0 | 89.7 | 189.0 (my chip run, PR 35); at 128 lanes a
+    feature groups of 16 had been 6 % faster than of 8 at levels 0-4 and
+    4 % at level 7, groups of 4 12 % slower (my chip run, PR 31)."""
     return max(1, 2048 // _bins_eff(n_bins))
 
 
@@ -120,6 +140,7 @@ class HistPlan(NamedTuple):
     vmem_bytes: int       # what one tile's kernel takes: hist_plan's text
     tile_feats: int       # features a tile: all of F where one tile holds them
     feat_tiles: int       # tiles a level; each sweeps every row block
+    lanes_a_feature: int  # 64 at up to 64 bins (two features a register), else 128, 256, ...
 
     @property
     def nodes_built(self) -> int:
@@ -152,8 +173,8 @@ def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int) -> HistPlan
     ragged.  A tile's accumulator block is counted twice (its index moves
     with the tile, so one is written back while the next fills), the row
     blocks twice — a tile's codes, node, g and h; routing is a pass of its
-    own — and ``VMEM_STACK``.  (F = 2000 at 64 bins, level 7: 8 MiB a
-    block, 28 MiB.)
+    own — and ``VMEM_STACK``.  (F = 2000 at 64 bins, 64 lanes a feature,
+    level 7: 4 MiB a block, 20 MiB; at 65 to 128 bins 8 MiB and 28 MiB.)
 
     From the level whose every node, built, stacks ``MXU_ROWS`` rows of
     gradient matrix (4 * 2**level: level 5 at any width) half the nodes are
@@ -163,7 +184,7 @@ def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int) -> HistPlan
     m_pad = _round_up(2 * (2 ** level - derived), 8)
     tile = min(n_feat, TILE_FEATS)
     tiles = -(-n_feat // tile)
-    acc = m_pad * tile * _bins_eff(n_bins) * 4
+    acc = m_pad * _block_feats(tile, n_bins) * _bins_eff(n_bins) * 4
     if tiles == 1:
         blocks = acc + 2 * 4 * block_rows * (_round_up(n_feat, 128) + 4 * 128)
     else:
@@ -176,7 +197,8 @@ def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int) -> HistPlan
             f"of one {tile}-feature tile is {acc} bytes and the kernel needs "
             f"{need} of {VMEM_MOST} bytes of VMEM (a depth of {level + 1} is "
             "one level too many)")
-    return HistPlan(level, derived, m_pad, acc, need, tile, tiles)
+    return HistPlan(level, derived, m_pad, acc, need, tile, tiles,
+                    _bins_eff(n_bins))
 
 
 def _encode_bf16(L):
@@ -235,7 +257,10 @@ def _accum(xb_blk, L, out_ref, *, n_bins: int, n_feat: int, fc: int, i8: bool,
            r_split: int = 1, feats_left=None):
     """out_ref[m, f*Beff+b] += sum_r L[r, m] * [xb_blk[r, f] == b], via the
     MXU: the encoded gradient planes are contracted against per-feature-
-    group bin-indicator matrices built in VMEM.
+    group bin-indicator matrices built in VMEM.  At 64 lanes a feature
+    (``_bins_eff``) ``n_feat`` counts the block's feature SLOTS, whole words
+    of four, and slot 4 * q + b of ``out_ref`` is feature b * n_feat / 4 + q
+    (``_cut_lanes`` puts them back in order).
 
     ``r_split > 1`` splits the row block into that many independent
     sub-contractions per feature group (raw accumulators summed, one
@@ -264,7 +289,38 @@ def _accum(xb_blk, L, out_ref, *, n_bins: int, n_feat: int, fc: int, i8: bool,
     # comparison" on vector<...xi8> cmpi AND vector<...xbf16> cmpf
     # (RESULTS/narrow_compare_rejection.txt; the local jax.export gate
     # accepts both, so only on-chip compiles catch this).
-    b_iota = lax.broadcasted_iota(jnp.int32, (rs, be), 1)
+    b_iota = lax.broadcasted_iota(jnp.int32, (rs, max(be, 128)), 1)
+    unit = 1            # features a lane broadcast serves
+    if be < 128:
+        # The lane broadcast of a code column bounds a level of up to one
+        # MXU tile (3 cycles an (8 rows, 1 column) whatever the lanes it
+        # feeds: PERF.md section 5), so at 64 lanes a feature ONE broadcast
+        # serves four features, two registers: the block's codes are packed
+        # four a 32-bit word here, in VMEM — word q holds, a byte each,
+        # feature slots q, s + q, 2s + q, 3s + q of the block (s = n_feat /
+        # 4): the block rolled along its lanes three times, shifted and
+        # or-ed, no shuffle a code — and a register is one and and one
+        # compare of the word's broadcast: lanes 0-63 pick bytes 0 | 2 and
+        # match them with the lane's bin, lanes 64-127 bytes 1 | 3.  Lanes
+        # past the matrix in a ragged last tile hold anything, and a shifted
+        # word of them spills into higher bytes only: slots further past it
+        # still.  (Rolls, not lane slices: the slice at 2s compiles, and on
+        # the chip gives wrong bytes, silently; PR 35.)
+        unit = 4
+        s, width = n_feat // unit, xb_blk.shape[1]
+        words = xb_blk
+        for b in range(1, unit):
+            words = words | (pltpu.roll(xb_blk, width - b * s, 1) << (8 * b))
+        shift = jnp.where(b_iota >= be, 8, 0)
+        masks = [jnp.left_shift(255, shift + p) for p in (0, 16)]
+        keys = [jnp.left_shift(b_iota & (be - 1), shift + p) for p in (0, 16)]
+
+    def indicator(lo, gi, k):
+        if be >= 128:
+            return [xb_blk[lo : lo + rs, f : f + 1] == b_iota
+                    for f in range(gi, gi + k)]
+        return [(words[lo : lo + rs, q : q + 1] & mask) == key
+                for q in range(gi, gi + k) for mask, key in zip(masks, keys)]
 
     def group(gi, k):
         # Sum the RAW accumulators across sub-blocks and decode once:
@@ -275,17 +331,15 @@ def _accum(xb_blk, L, out_ref, *, n_bins: int, n_feat: int, fc: int, i8: bool,
         for s in range(r_split):
             lo = s * rs
             onehot = jnp.concatenate(
-                [(xb_blk[lo : lo + rs, f : f + 1] == b_iota)
-                 for f in range(gi, gi + k)],
-                axis=1,
-            ).astype(onehot_dtype)
+                indicator(lo, gi, k), axis=1).astype(onehot_dtype)
             part = lax.dot_general(l2[lo : lo + rs], onehot, _DN,
                                    preferred_element_type=acc_dtype)
             acc2 = part if acc2 is None else acc2 + part
-        out_ref[:, gi * be : (gi + k) * be] += decode(acc2)
+        out_ref[:, gi * unit * be : (gi + k) * unit * be] += decode(acc2)
 
-    for gi in range(0, n_feat, fc):
-        k = min(fc, n_feat - gi)
+    # a group is ``fc`` features: so many code columns, or words of four
+    for gi in range(0, n_feat // unit, fc // unit):
+        k = min(fc // unit, n_feat // unit - gi)
         if feats_left is None:
             group(gi, k)
         else:
@@ -373,7 +427,7 @@ def _level_kernel(xb_ref, node_ref, g_ref, h_ref, feat_ref, thr_ref, *refs,
         out_ref[:] = jnp.zeros_like(out_ref)
 
     node = _route(xb_ref[0], node_ref[0], feat_ref[0:1], thr_ref[0:1],
-                  p_pad=p_pad, n_feat=n_feat)
+                  p_pad=p_pad, n_feat=xb_ref.shape[2])
     node_out_ref[0] = node
     L = _gradient_matrix(
         node, g_ref[0], h_ref[0], n_nodes=n_nodes, m_pad=m_pad,
@@ -563,6 +617,32 @@ def _vmem_params(plan: HistPlan):
     return pltpu.CompilerParams(vmem_limit_bytes=plan.vmem_bytes)
 
 
+def _one_block(xb3, n_bins: int):
+    """What a kernel with ONE accumulator block over all of ``xb3``'s
+    features takes: the codes — at up to 64 bins padded with zeros to whole
+    128-lane tiles of columns (what a block of them fills in VMEM anyway),
+    so that ``_accum`` rolls whole registers; above, as they are — the
+    block's feature slots, its lanes and the features a matmul group."""
+    F = xb3.shape[2]
+    slots = _block_feats(F, n_bins)
+    if _bins_eff(n_bins) < 128 and F % 128:
+        xb3 = jnp.pad(xb3, ((0, 0), (0, 0), (0, -F % 128)))
+    return xb3, slots, slots * _bins_eff(n_bins), _pick_fc(slots, n_bins)
+
+
+def _cut_lanes(out, n_feat: int, n_bins: int, tile: int):
+    """A kernel's ``(m, lanes)`` accumulator as ``(m, F, n_bins)``: the pad
+    lanes of every feature, and the feature slots past F that fill the last
+    word or the ragged last tile, cut off.  At 64 lanes a feature a tile of
+    ``tile`` feature slots comes word by word (``_accum``): slot 4 * q + b
+    holds feature b * tile / 4 + q, and is put back in the features' order
+    here, in the pass that cuts."""
+    be = _bins_eff(n_bins)
+    if be < 128:
+        out = out.reshape(out.shape[0], -1, tile // 4, 4, be).swapaxes(2, 3)
+    return out.reshape(out.shape[0], -1, be)[:, :n_feat, :n_bins]
+
+
 def _hist_tiles(plan: HistPlan, xb3, node3, g3, h3, *, n_bins, interpret,
                 mxu_i8, r_split, name, built=()):
     """A level wider than one tile: the ``(m_pad, F, B)`` sums of rows that
@@ -571,7 +651,7 @@ def _hist_tiles(plan: HistPlan, xb3, node3, g3, h3, *, n_bins, interpret,
     The kernel's output is whole tiles wide; the ragged last tile's tail is
     cut off here."""
     nb, R, F = xb3.shape
-    be = _bins_eff(n_bins)
+    be = plan.lanes_a_feature
     tile, tiles, m_pad = plan.tile_feats, plan.feat_tiles, plan.m_pad
     row = pl.BlockSpec((1, R, 1), lambda t, i: (i, 0, 0))
     rows = [a for a in (node3, g3, h3) if a is not None]
@@ -591,7 +671,7 @@ def _hist_tiles(plan: HistPlan, xb3, node3, g3, h3, *, n_bins, interpret,
         name=name,
         compiler_params=_vmem_params(plan),
     )(xb3, *rows)
-    return out.reshape(m_pad, tiles * tile, be)[:, :F, :n_bins]
+    return _cut_lanes(out, F, n_bins, tile)
 
 
 @functools.partial(
@@ -608,19 +688,18 @@ def hist_level0(xb3, g3, h3, *, n_bins: int, interpret: bool = False,
                           interpret=interpret, mxu_i8=mxu_i8, r_split=r_split,
                           name="hist_level0")
         return jnp.stack([out[0:1], out[1:2]], axis=-1)
-    be = _bins_eff(n_bins)
-    fc = _pick_fc(F, n_bins)
+    xb3, slots, lanes, fc = _one_block(xb3, n_bins)
     out = pl.pallas_call(
-        functools.partial(_level0_kernel, n_bins=n_bins, n_feat=F, fc=fc,
+        functools.partial(_level0_kernel, n_bins=n_bins, n_feat=slots, fc=fc,
                           i8=mxu_i8, r_split=r_split),
         grid=(nb,),
-        in_specs=[_blk(R, F), _blk(R, 1), _blk(R, 1)],
-        out_specs=pl.BlockSpec((8, F * be), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((8, F * be), jnp.float32),
+        in_specs=[_blk(R, xb3.shape[2]), _blk(R, 1), _blk(R, 1)],
+        out_specs=pl.BlockSpec((8, lanes), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((8, lanes), jnp.float32),
         interpret=interpret,
         name="hist_level0",
     )(xb3, g3, h3)
-    out = out.reshape(8, F, be)[..., :n_bins]
+    out = _cut_lanes(out, F, n_bins, slots)
     return jnp.stack([out[0:1], out[1:2]], axis=-1)
 
 
@@ -657,7 +736,6 @@ def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, *, depth: int,
     nb, R, F = xb3.shape
     _check_r_split(R, r_split)
     plan = hist_plan(F, n_bins, depth, R)
-    be = _bins_eff(n_bins)
     n_nodes, m_pad = plan.nodes_built, plan.m_pad
     n_prev = 2 ** (depth - 1)
     if bool(plan.nodes_derived) != (built_right is not None):
@@ -675,33 +753,33 @@ def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, *, depth: int,
         hist = jnp.stack([out[:n_nodes], out[n_nodes : 2 * n_nodes]], axis=-1)
         return hist, node_out
     p_pad = _round_up(n_prev, 128)
-    fc = _pick_fc(F, n_bins)
+    xb3, slots, lanes, fc = _one_block(xb3, n_bins)
     featp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(feat)
     thrp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(thr)
     out, node_out = pl.pallas_call(
         functools.partial(
-            _level_kernel, n_nodes=n_nodes, n_bins=n_bins, n_feat=F,
+            _level_kernel, n_nodes=n_nodes, n_bins=n_bins, n_feat=slots,
             m_pad=m_pad, p_pad=p_pad, fc=fc, i8=mxu_i8, r_split=r_split,
         ),
         grid=(nb,),
         in_specs=[
-            _blk(R, F), _blk(R, 1), _blk(R, 1), _blk(R, 1),
+            _blk(R, xb3.shape[2]), _blk(R, 1), _blk(R, 1), _blk(R, 1),
             pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
             pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
         ] + [pl.BlockSpec(b.shape, lambda i: (0, 0)) for b in built],
         out_specs=[
-            pl.BlockSpec((m_pad, F * be), lambda i: (0, 0)),
+            pl.BlockSpec((m_pad, lanes), lambda i: (0, 0)),
             _blk(R, 1),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m_pad, F * be), jnp.float32),
+            jax.ShapeDtypeStruct((m_pad, lanes), jnp.float32),
             jax.ShapeDtypeStruct((nb, R, 1), jnp.int32),
         ],
         interpret=interpret,
         name=f"hist_level_d{depth}",
         compiler_params=_vmem_params(plan),
     )(xb3, node3, g3, h3, featp, thrp, *built)
-    out = out.reshape(m_pad, F, be)[..., :n_bins]
+    out = _cut_lanes(out, F, n_bins, slots)
     hist = jnp.stack([out[:n_nodes], out[n_nodes : 2 * n_nodes]], axis=-1)
     return hist, node_out
 

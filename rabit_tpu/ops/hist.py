@@ -150,34 +150,33 @@ def node_histograms_pallas(xb, g, h, node, n_nodes: int, n_bins: int,
         h = jnp.pad(h, (0, pad))
         node = jnp.pad(node, (0, pad))
     m_pad = _round_up(2 * n_nodes, 8)
-    be = boost._bins_eff(n_bins)
-    fc = boost._pick_fc(F, n_bins)
     nb = n_pad // R
+    xb3, slots, lanes, fc = boost._one_block(xb.reshape(nb, R, F), n_bins)
 
     out = pl.pallas_call(
         functools.partial(
             _hist_kernel, n_nodes=n_nodes, n_bins=n_bins, m_pad=m_pad,
-            n_feat=F, fc=fc, i8=mxu_i8,
+            n_feat=slots, fc=fc, i8=mxu_i8,
         ),
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((1, R, F), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, R, xb3.shape[2]), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, R, 1), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, R, 1), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, R, 1), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((m_pad, F * be), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((m_pad, F * be), jnp.float32),
+        out_specs=pl.BlockSpec((m_pad, lanes), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((m_pad, lanes), jnp.float32),
         interpret=interpret,
         name=f"node_histograms_n{n_nodes}",
     )(
-        xb.reshape(nb, R, F),
+        xb3,
         node.reshape(nb, R, 1),
         g.reshape(nb, R, 1),
         h.reshape(nb, R, 1),
     )
 
-    out = out.reshape(m_pad, F, be)[..., :n_bins]
+    out = boost._cut_lanes(out, F, n_bins, slots)
     return jnp.stack([out[:n_nodes], out[n_nodes : 2 * n_nodes]], axis=-1)
 
 

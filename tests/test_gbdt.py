@@ -481,35 +481,47 @@ def test_hist_plan():
 def test_hist_plan_tiles_a_wide_matrix():
     """Wider than one tile of codes the plan walks the features in tiles of
     128, the last one ragged, and reckons ONE tile's accumulator block,
-    counted twice: Epsilon (F 2000, 64 bins padded to 128 lanes, depth 8)
-    is sixteen tiles a level, and with one child a parent built from level
-    5 on an 8 MiB block and 28 MiB asked at level 7 (16 and 44 with every
-    node built) — a width whose one-block accumulator nothing holds.  The
-    halved block lets level 8 through (a 16 MiB block, 44 MiB: depth 9);
-    level 9 is refused by name, with the tile's block in the text."""
+    counted twice: Epsilon (F 2000, 64 bins at 64 lanes a feature, two
+    features a register, depth 8) is sixteen tiles a level, and with one
+    child a parent built from level 5 on a 4 MiB block and 20 MiB asked at
+    level 7 (8 and 28 at 128 lanes a feature, before PR 35; 16 and 44 with
+    every node built besides) — a width whose one-block accumulator nothing
+    holds.  Level 7 is the first to pass Mosaic's default (level 6 takes it
+    whole, 16 MiB).  The halved lanes let levels 8 and 9 through (8 and 16
+    MiB blocks, 28 and 44 MiB: depths 9 and 10); level 10 is refused by
+    name, with the tile's block in the text.  At 65 bins and more a feature
+    takes whole registers and the groups and blocks are what they were."""
     from rabit_tpu.ops import boost
 
-    assert boost._pick_tile_fc(64) == boost._pick_tile_fc(128) == 16
+    assert boost._pick_tile_fc(64) == 2 * boost._pick_tile_fc(128) == 32
     assert boost._pick_tile_fc(256) == 8
     assert boost.hist_plan(129, 64, 3, 1024).feat_tiles == 2
-    for d in range(9):
+    for d in range(10):
         p = boost.hist_plan(2000, 64, d, 1024)
-        assert (p.tile_feats, p.feat_tiles) == (128, 16)
+        assert (p.tile_feats, p.feat_tiles, p.lanes_a_feature) == (128, 16, 64)
         built = 2 ** d if d < 5 else 2 ** (d - 1)
         assert (p.nodes_built, p.nodes_derived) == (built, 2 ** d - built)
         assert p.m_pad == max(8, 2 * built)
-        assert p.acc_block_bytes == p.m_pad * 128 * 128 * 4
+        assert p.acc_block_bytes == p.m_pad * 128 * 64 * 4
         assert p.vmem_bytes == (2 * p.acc_block_bytes + 2 * 4 * 1024 * 4 * 128
                                 + boost.VMEM_STACK)
+        wide = boost.hist_plan(2000, 128, d, 1024) if d < 9 else None
+        if wide:
+            assert wide.acc_block_bytes == p.m_pad * 128 * 128 * 4
+            assert wide[:3] + wide[5:7] == p[:3] + p[5:7]
     assert p.acc_block_bytes == 16 << 20 and p.vmem_bytes == 44 << 20
     p = boost.hist_plan(2000, 64, 7, 1024)
-    assert p.acc_block_bytes == 8 << 20 and p.vmem_bytes == 28 << 20
+    assert p.acc_block_bytes == 4 << 20 and p.vmem_bytes == 20 << 20
+    assert boost.hist_plan(2000, 128, 7, 1024)[3:5] == (8 << 20, 28 << 20)
     assert boost.VMEM_DEFAULT < p.vmem_bytes <= boost.VMEM_MOST
-    assert boost.hist_plan(2000, 64, 6, 1024).vmem_bytes > boost.VMEM_DEFAULT
-    assert boost.hist_plan(2000, 64, 5, 1024).vmem_bytes <= boost.VMEM_DEFAULT
-    with pytest.raises(ValueError, match=r"level 9 of F=2000 .*128-feature "
+    assert boost.hist_plan(2000, 64, 7, 1024).vmem_bytes > boost.VMEM_DEFAULT
+    assert boost.hist_plan(2000, 64, 6, 1024).vmem_bytes == boost.VMEM_DEFAULT
+    with pytest.raises(ValueError, match=r"level 10 of F=2000 .*128-feature "
                                          r"tile is 33554432 bytes"):
-        boost.hist_plan(2000, 64, 9, 1024)
+        boost.hist_plan(2000, 64, 10, 1024)
+    with pytest.raises(ValueError, match=r"level 9 of F=2000 features x 128 "
+                                         r"bins .*tile is 33554432 bytes"):
+        boost.hist_plan(2000, 128, 9, 1024)
 
 
 @pytest.mark.parametrize("d", [0, 3])
@@ -657,6 +669,113 @@ def test_hist_level_at_the_criteo_width_matches_scatter(d):
         boost.hist_level(*blocked, feat, thr, depth=d, n_bins=B, interpret=True)
 
 
+def _numpy_histogram(xb, g, h, node, n_nodes, bins):
+    """[n_nodes, F, bins, 2] float64 sums of g and h a (node, feature, code)."""
+    n, f = xb.shape
+    ref = np.zeros((n_nodes, f, bins, 2))
+    cols = np.broadcast_to(np.arange(f), (n, f))
+    np.add.at(ref, (node[:, None], cols, xb, 0), g[:, None])
+    np.add.at(ref, (node[:, None], cols, xb, 1), h[:, None])
+    return ref
+
+
+@pytest.mark.parametrize("tiling", ["one-tile", "tiled"])
+@pytest.mark.parametrize("d", [0, 2, 5], ids=["root", "built", "derived"])
+@pytest.mark.parametrize("F,bins", [(5, 64), (6, 33), (7, 17), (130, 64),
+                                    (131, 64)])
+def test_two_features_a_register_match_a_numpy_histogram(F, bins, d, tiling,
+                                                         monkeypatch):
+    """At up to 64 bins a feature takes 64 lanes, two share a register
+    (ops.boost._bins_eff) and the kernels pack the codes four a word
+    (ops.boost._accum): the root, a level with every node built and a
+    derived one (one child a parent) against numpy float64 sums, as ONE
+    accumulator block and walked in feature tiles.  The counts are odd and
+    no whole words (a last word of one, two or three features), fewer bins
+    than lanes (33, 17), and the ragged last tile holds one, two or three
+    features: of 130 and 131 behind a whole tile of 128, of 5, 6 and 7
+    behind a tile of 4 (``TILE_FEATS`` cut for the test)."""
+    from rabit_tpu.ops import boost
+
+    one_tile = tiling == "one-tile"
+    if one_tile == (F > boost.TILE_FEATS):
+        monkeypatch.setattr(boost, "TILE_FEATS", 512 if one_tile else 4)
+    rng = np.random.RandomState(F + bins + d)
+    n, block = 512, 256
+    plan = boost.hist_plan(F, bins, d, block)
+    assert plan.lanes_a_feature == 64
+    assert (plan.feat_tiles == 1) == one_tile
+    assert plan.acc_block_bytes == plan.m_pad * 4 * 64 * (
+        -(-F // 4) * 4 if one_tile else plan.tile_feats)
+    xb = rng.randint(0, bins, size=(n, F))
+    g, h = rng.randn(n).astype(np.float32), rng.rand(n).astype(np.float32)
+    xb3, g3, h3 = (boost.block_rows(jnp.asarray(a), block)[0]
+                   for a in (xb.astype(np.int32), g, h))
+    if d == 0:
+        hist = boost.hist_level0.__wrapped__(xb3, g3, h3, n_bins=bins,
+                                             interpret=True)
+        ref = _numpy_histogram(xb, g, h, np.zeros(n, int), 1, bins)
+    else:
+        n_prev = 2 ** (d - 1)
+        node = rng.randint(0, n_prev, size=n)
+        # splits on the last feature (the odd one's half register) too
+        feat = np.r_[F - 1, rng.randint(0, F, size=n_prev - 1)]
+        thr = rng.randint(0, bins, size=n_prev)
+        built_right = rng.randint(0, 2, size=n_prev) if plan.nodes_derived else None
+        hist, node_out = boost.hist_level.__wrapped__(
+            xb3, boost.block_rows(jnp.asarray(node, jnp.int32), block)[0],
+            g3, h3, jnp.asarray(feat, jnp.int32), jnp.asarray(thr, jnp.int32),
+            None if built_right is None else jnp.asarray(built_right, jnp.int32),
+            depth=d, n_bins=bins, interpret=True)
+        routed = 2 * node + (xb[np.arange(n), feat[node]] > thr[node])
+        np.testing.assert_array_equal(
+            np.asarray(boost.unblock_rows(node_out, n)), routed)
+        ref = _numpy_histogram(xb, g, h, routed, 2 * n_prev, bins)
+        if plan.nodes_derived:
+            ref = ref[2 * np.arange(n_prev) + built_right]
+    assert hist.shape == ref.shape == (plan.nodes_built, F, bins, 2)
+    np.testing.assert_allclose(np.asarray(hist), ref, rtol=1e-4, atol=1e-4)
+    assert np.abs(ref).sum((0, 2, 3)).min() > 0     # every feature has mass
+
+
+@pytest.mark.parametrize("bins,lanes", [(64, 64), (65, 128), (128, 128),
+                                        (256, 256)])
+def test_lanes_a_feature_follow_the_bins(bins, lanes):
+    """``_bins_eff``: 64 lanes a feature at up to 64 bins, else the bins
+    padded to whole 128-lane registers; the matmul groups and the plan's
+    blocks follow it."""
+    from rabit_tpu.ops import boost
+
+    assert boost._bins_eff(bins) == lanes
+    assert boost._bins_eff(bins - 1 if bins != 65 else 127) == lanes
+    assert boost._pick_fc(2000, bins) == 1792 // lanes
+    p = boost.hist_plan(100, bins, 3, 1024)
+    assert p.lanes_a_feature == lanes
+    assert p.acc_block_bytes == p.m_pad * 100 * lanes * 4
+
+
+@pytest.mark.parametrize("F,last", [(28, 5), (67, 9)], ids=["higgs", "criteo"])
+def test_hist_plan_at_256_bins_is_field_for_field_what_it_was(F, last):
+    """Nothing of the plan moves above 64 bins: every level's plan at the
+    HIGGS and the Criteo width is, field for field, what the parent of PR 35
+    reckoned (the formulas as they stood there), with ``lanes_a_feature``
+    256 beside them."""
+    from rabit_tpu.ops import boost
+
+    for d in range(last + 1):
+        built = 2 ** d if d < 5 else 2 ** (d - 1)
+        m_pad = max(8, 2 * built)
+        acc = m_pad * F * 256 * 4
+        vmem = acc + 2 * 4 * 1024 * (128 + 4 * 128) + boost.VMEM_STACK
+        assert boost.hist_plan(F, 256, d, 1024) == boost.HistPlan(
+            level=d, nodes_derived=2 ** d - built, m_pad=m_pad,
+            acc_block_bytes=acc, vmem_bytes=vmem, tile_feats=F, feat_tiles=1,
+            lanes_a_feature=256)
+    assert boost.hist_plan(28, 256, 5, 1024)[:7] == (
+        5, 16, 32, 917504, 14548992, 28, 1)
+    assert boost.hist_plan(67, 256, 7, 1024)[:7] == (
+        7, 64, 128, 8781824, 22413312, 67, 1)
+
+
 @pytest.mark.parametrize("F,bins,depth,tiles",
                          [(67, 256, 8, 1), (2000, 64, 8, 16), (28, 256, 6, 1)],
                          ids=["criteo", "epsilon", "higgs"])
@@ -666,7 +785,9 @@ def test_hist_plan_span_and_rows_streamed_gauge(F, bins, depth, tiles):
     passes over the row grid: depth histogram passes and the leaves'.  At
     the Epsilon shape (lowering only) the span says sixteen tiles of 128
     features and ONE tile's block, and the gauge counts a sweep a tile a
-    level and a routing pass a level.  ``nodes_derived`` is 0 up to level
+    level and a routing pass a level.  ``lanes_a_feature`` is the bins' (64
+    at Epsilon's 64 bins, 256 at 256) and the gauge
+    ``gbdt_hist_feats_a_register`` 2 and 1.  ``nodes_derived`` is 0 up to level
     4 and half the level's nodes from level 5 on, and the gauge
     ``gbdt_hist_nodes_derived_per_round`` their sum: 16 at the HIGGS shape,
     16 + 32 + 64 at depth 8."""
@@ -686,6 +807,9 @@ def test_hist_plan_span_and_rows_streamed_gauge(F, bins, depth, tiles):
     assert gauge.value == (depth + 1 if tiles == 1 else depth * tiles + depth) * n
     derived = obs.get_registry().gauge("gbdt_hist_nodes_derived_per_round")
     assert derived.value == {6: 16, 8: 112}[depth]
+    # two features a 128-lane register at 64 bins, one feature two at 256
+    feats = obs.get_registry().gauge("gbdt_hist_feats_a_register")
+    assert feats.value == {64: 2, 256: 1}[bins]
     spans = [e.fields for e in obs.get_recorder().snapshot()
              if e.ts >= t0 and e.kind == "span"
              and e.fields.get("name") == "gbdt.hist_plan"]
@@ -697,13 +821,14 @@ def test_hist_plan_span_and_rows_streamed_gauge(F, bins, depth, tiles):
             plan.nodes_built, plan.m_rows, plan.m_tiles,
             plan.acc_block_bytes, plan.vmem_bytes)
         assert (s["feat_tiles"], s["tile_feats"]) == (tiles, min(F, 128))
+        assert s["lanes_a_feature"] == bins == plan.lanes_a_feature
         assert s["nodes_derived"] == (2 ** (s["level"] - 1)
                                       if s["level"] >= 5 else 0)
         assert s["nodes_built"] + s["nodes_derived"] == 2 ** s["level"]
     assert spans[-1]["nodes_built"] == 2 ** (depth - 2)
     assert spans[-1]["m_tiles"] == {6: 1, 8: 2}[depth]
     if tiles > 1:
-        assert spans[-1]["acc_block_bytes"] == 128 * 128 * 128 * 4
+        assert spans[-1]["acc_block_bytes"] == 128 * 128 * 64 * 4
 
 
 def test_train_round_fused_i8_matches_reference():

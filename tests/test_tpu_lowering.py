@@ -244,18 +244,20 @@ NB_EPSILON, F_EPSILON, B_EPSILON = 391, 2000, 64
 
 @pytest.mark.parametrize("d", (0, 4, 5, 6, 7))
 def test_epsilon_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d):
-    """The tiled level kernels at the cell's block count: the root, the last
-    level that builds every node (4: two 2 MiB blocks of one tile, the
-    default's 16 MiB to the byte) and the three that build one child a
-    parent: level 5 is level 4's block and asks nothing, level 6 (two 4 MiB
-    blocks) is the first to ask, level 7 asks 28 MiB for its 8 MiB block (44
-    for 16 with every node built).  Each is two custom calls below the root:
-    the routing pass and the sweep of the feature tiles."""
+    """The tiled level kernels at the cell's block count, 64 lanes a feature
+    and two features a register (64 bins): the root, the last level that
+    builds every node (4: two 1 MiB blocks of one tile) and the three that
+    build one child a parent: level 5 is level 4's block, level 6 (two 2
+    MiB blocks) is the default's 16 MiB to the byte and asks nothing, level
+    7 is the first to ask, 20 MiB for its 4 MiB block (28 for 8 at 128
+    lanes a feature, before PR 35).  Each is two custom calls below the
+    root: the routing pass and the sweep of the feature tiles."""
     plan = boost.hist_plan(F_EPSILON, B_EPSILON, d, R)
     assert (plan.feat_tiles, plan.tile_feats) == (16, 128)
     built = 1 << (d - 1 if d >= 5 else d)
     assert (plan.nodes_built, plan.nodes_derived) == (built, (1 << d) - built)
-    assert plan.acc_block_bytes == max(8, 2 * built) * 128 * 128 * 4
+    assert plan.lanes_a_feature == 64
+    assert plan.acc_block_bytes == max(8, 2 * built) * 128 * 64 * 4
     xb3, g3, node3 = _blocked(one_chip, NB_EPSILON, F_EPSILON)
     if d == 0:
         c = _compile(functools.partial(boost.hist_level0, n_bins=B_EPSILON),
@@ -268,9 +270,29 @@ def test_epsilon_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d):
     text = c.as_text()
     assert text.count("tpu_custom_call") >= (2 if d else 1)
     assert (str(plan.vmem_bytes) in text) == (plan.vmem_bytes > boost.VMEM_DEFAULT)
-    assert (plan.vmem_bytes > boost.VMEM_DEFAULT) == (d >= 6)
+    assert (plan.vmem_bytes > boost.VMEM_DEFAULT) == (d >= 7)
     if d == 7:
-        assert plan.vmem_bytes == 28 << 20
+        assert plan.vmem_bytes == 20 << 20
+
+
+@pytest.mark.parametrize("f,bins,d", ((28, 64, 0), (27, 64, 5), (100, 33, 3)))
+def test_one_tile_kernel_at_up_to_64_bins_compiles_for_v5e(
+        one_chip, no_compile_cache, f, bins, d):
+    """The one-block kernels at 64 lanes a feature, which no cell runs
+    (HIGGS's width at LightGBM's ``max_bin`` 63, an odd count, fewer bins
+    than lanes): the codes go in padded to one 128-lane tile and the kernel
+    packs them four a word with whole-register rolls."""
+    plan = boost.hist_plan(f, bins, d, R)
+    assert (plan.feat_tiles, plan.lanes_a_feature) == (1, 64)
+    xb3, g3, node3 = _blocked(one_chip, 8, f)
+    if d == 0:
+        c = _compile(functools.partial(boost.hist_level0, n_bins=bins),
+                     xb3, g3, g3)
+    else:
+        tab = _sds((1 << (d - 1),), jnp.int32, one_chip)
+        c = _compile(functools.partial(boost.hist_level, depth=d, n_bins=bins),
+                     xb3, node3, g3, g3, *(tab,) * (3 if plan.nodes_derived else 2))
+    assert "tpu_custom_call" in c.as_text()
 
 
 def test_route_level_compiles_for_v5e(one_chip, no_compile_cache):
