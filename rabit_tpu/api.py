@@ -447,26 +447,30 @@ def allreduce(
     ``rabit_compress_min_bytes``); ``"identity"`` forces the exact path.
     On the compressed path ``prepare_fun`` runs eagerly — its output feeds
     the encoder."""
+    # entry to the engine's call under one name, the result's reshape under
+    # another: with the engine's own span between them, a hop's host side
+    # in three parts (doc/observability.md, "A hop's five phases")
     if not isinstance(data, np.ndarray):
         raise TypeError("allreduce only takes numpy ndarrays")
-    if data.dtype not in DTYPE_ENUM:
-        raise TypeError(f"dtype {data.dtype} not supported")
-    if op not in (MAX, MIN, SUM, BITOR):
-        raise ValueError(f"unknown reduction op {op}")
-    buf = data.flatten()  # always a fresh 1-D C-order copy
-    shape = data.shape
-    if prepare_fun is not None:
-        orig_prepare = prepare_fun
+    with obs.span("rabit.allreduce.copy_in", nbytes=data.nbytes):
+        if data.dtype not in DTYPE_ENUM:
+            raise TypeError(f"dtype {data.dtype} not supported")
+        if op not in (MAX, MIN, SUM, BITOR):
+            raise ValueError(f"unknown reduction op {op}")
+        buf = data.flatten()  # always a fresh 1-D C-order copy
+        shape = data.shape
+        if prepare_fun is not None:
+            orig_prepare = prepare_fun
 
-        def prepare_fun(buf_view: np.ndarray) -> None:  # type: ignore[misc]
-            orig_prepare(data)
-            buf_view[...] = np.ascontiguousarray(data).reshape(-1)
+            def prepare_fun(buf_view: np.ndarray) -> None:  # type: ignore[misc]
+                orig_prepare(data)
+                buf_view[...] = np.ascontiguousarray(data).reshape(-1)
 
-    c = compress.resolve(codec, buf.dtype, op, buf.nbytes)
+        c = compress.resolve(codec, buf.dtype, op, buf.nbytes)
+        key = _caller_key()
     # NOTE: the timed window includes a lazy prepare_fun's execution (it
     # runs inside the engine, interleaved with recovery decisions), so
     # expensive preparation shows up as allreduce latency in the stats.
-    key = _caller_key()
     if c is None:
         with obs.collective("allreduce", buf.nbytes, cache_key=key):
             out = _get_engine().allreduce(
@@ -480,7 +484,8 @@ def allreduce(
             out = engine.allreduce_compressed(
                 buf, op, c, prepare_fun=prepare_fun, cache_key=key
             )
-    return np.asarray(out).reshape(shape)
+    with obs.span("rabit.allreduce.copy_out", nbytes=buf.nbytes):
+        return np.asarray(out).reshape(shape)
 
 
 def allgather(data: np.ndarray) -> np.ndarray:
@@ -666,12 +671,6 @@ def checkpoint(global_model: Any, local_model: Any = None) -> None:
                 _ckpt_store.save(_ckpt_base + engine.version_number(), gblob,
                                  lblob, epoch=_world_epoch["epoch"])
         _publish_commit(engine, gblob)
-        # The frames go back to the allocator here, under a name, and not
-        # at the return, under none.  Before PR 30 they were whole pickles
-        # and a 42 MB one was a munmap of milliseconds; now they are heads
-        # and views, and this should read about nothing.
-        with obs.span("rabit.checkpoint.release", nbytes=nbytes):
-            del gframe, lframe, frames, gblob, lblob
 
 
 def _publish_commit(engine: Engine, pieces: tuple) -> None:

@@ -543,10 +543,16 @@ def train_round_hybrid(
         # replicated shardings this program needs.)
         def host(x, _t):
             # the host side of one hop, device->host copy to the result's
-            # copy back: what the device waits for beyond the engine's call
-            with obs.span("gbdt.cross", level=tag):
-                return np.asarray(engine_allreduce(np.asarray(x)),
-                                  dtype=x.dtype)
+            # copy back: what the device waits for beyond the engine's
+            # call, the two copies under names of their own
+            # (doc/observability.md, "A hop's five phases")
+            n = x.nbytes    # the payload one way
+            with obs.span("gbdt.cross", level=tag, nbytes=n):
+                with obs.span("gbdt.cross.in", nbytes=n):
+                    a = np.asarray(x)
+                out = engine_allreduce(a)
+                with obs.span("gbdt.cross.out", nbytes=n):
+                    return np.asarray(out, dtype=x.dtype)
 
         return jax.pure_callback(
             host,

@@ -98,14 +98,16 @@ def test_collective_keeps_its_events_and_gains_a_span(ring):
         rt.allreduce(np.arange(6, dtype=np.float32), rt.SUM)
     finally:
         rt.finalize()
-    kinds = [e.kind for e in ring.snapshot()
+    kinds = [e.fields["name"] if e.kind == "span" else e.kind
+             for e in ring.snapshot()
              if e.kind in ("op_begin", "span", "op_end")]
-    assert kinds == ["op_begin", "span", "op_end"]
+    assert kinds == ["rabit.allreduce.copy_in", "op_begin", "rabit.allreduce",
+                     "op_end", "rabit.allreduce.copy_out"]
     begin, end = (next(e.fields for e in ring.snapshot() if e.kind == k)
                   for k in ("op_begin", "op_end"))
     assert set(begin) == {"op", "nbytes", "cache_key", "version", "seqno"}
     assert set(end) == set(begin) | {"seconds"}
-    (f,) = spans_of(ring, "rabit.allreduce")
+    (f,) = [f for f in spans_of(ring) if f["name"] == "rabit.allreduce"]
     assert (f["version"], f["seqno"], f["nbytes"]) == (
         begin["version"], begin["seqno"], 24)
     assert obs.get_registry().ops["allreduce"].calls >= 1
@@ -168,7 +170,7 @@ def test_checkpoint_fields_and_parents(ring, tmp_path):
     top = by["rabit.checkpoint"][0]
     assert top["parent"] is None and top["nbytes_local"] > 8000
     assert top["nbytes_global"] > 0
-    for name in ("pickle", "commit", "spill", "release"):
+    for name in ("pickle", "commit", "spill"):
         assert by[f"rabit.checkpoint.{name}"][0]["parent"] == "rabit.checkpoint"
     for name in ("encode", "write", "dirsync"):
         assert [f["parent"] for f in by[f"rabit.spill.{name}"]] == [
@@ -259,13 +261,18 @@ def test_one_site_opens_a_trace_annotation():
 
 def test_off_cost_is_microseconds():
     """jax imported, no profiler session: a span is a context manager, one
-    event and one histogram observation — held under 10 us (ISSUE 25: at
-    most 14 spans a committed round of 179 ms; 6.4 us on a quiet host).
-    Where the host is so loaded that even the undisturbed best of many
-    short batches passes 10 us, the bound is five flight-recorder events of
-    a span's shape, timed the same way at the same moment (a span is three
-    of them)."""
+    event and one histogram observation — some three flight-recorder events
+    of a span's shape (3.3 on a quiet host, where a span is 6.4-7.5 us and
+    an event 2.2), and held under five.  The two are timed back to back in
+    the same short batches and compared batch by batch, the median of the
+    ratios taken: a host loaded by five other workers slows both alike, so
+    no microsecond is named.  What that buys (ISSUE 36): an in-memory
+    commit holds three spans and a spilled one eleven; a hop holds six
+    (``gbdt.cross``, ``rabit.allreduce`` and the two copies of each), so
+    the seven hops and the commit of ``higgs-quarter.engine-hop``'s round
+    are 45 spans, 0.3 ms of its 187.76 ms."""
     import gc
+    import statistics
 
     import jax  # noqa: F401
 
@@ -279,22 +286,22 @@ def test_off_cost_is_microseconds():
             obs.record_event("span", name="t.ref", t0=1.5, seconds=0.5,
                              parent=None, version=0, nbytes=1)
 
-    def best(fn, n=500, batches=40):
-        fn(200)
-        out = float("inf")
-        for _ in range(batches):
-            t = time.perf_counter()
-            fn(n)
-            out = min(out, (time.perf_counter() - t) / n)
-        return out
+    def took(fn, n=500):
+        t = time.perf_counter()
+        fn(n)
+        return (time.perf_counter() - t) / n
 
     gc.disable()
     try:
-        a_span, an_event = best(spans), best(events)
+        spans(200), events(200)
+        batches = [(took(spans), took(events)) for _ in range(40)]
     finally:
         gc.enable()
-    assert a_span < max(10e-6, 5 * an_event), (
-        f"{a_span * 1e6:.2f} us a span, {an_event * 1e6:.2f} us an event")
+    ratio = statistics.median(a / b for a, b in batches)
+    assert ratio < 5, (
+        f"a span is {ratio:.2f} events; the quietest batch: "
+        f"{min(a for a, _ in batches) * 1e6:.2f} us a span, "
+        f"{min(b for _, b in batches) * 1e6:.2f} us an event")
 
 
 # -- the operator's view: trace_tool export, the launcher ------------------------

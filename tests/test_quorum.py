@@ -475,21 +475,31 @@ def test_chaos_straggler_without_quorum_still_converges():
     assert r.outcome == "completed" and r.n_quorum_met == 0
 
 
+#: the tier-1 case of the campaign below: world 4, a straggler healed at
+#: version 3, quorum 0.5 and the sampled faults
+CAMPAIGN = dict(world=4, straggler=(2, 0.25, 3), quorum="0.5", niter=5,
+                mix_faults=True)
+
+
 def test_fuzz_straggler_quorum_kill_campaign():
-    """The seeded tier-1 campaign mixing straggler + quorum + kill
-    faults: heal-then-must-converge, cross-rank bitwise identity, and
-    the correction accounting (exact single-epoch, sandwich across
-    waves) are asserted inside run_elastic_schedule."""
-    for seed in range(9300, 9305):
-        r = run_elastic_schedule(seed, world=4, straggler=(2, 0.25, 3),
-                                 quorum="0.5", niter=5, mix_faults=True,
-                                 deadline_sec=45.0)
-        assert r.outcome == "completed", f"seed {seed}: {r}"
+    """One seed of the campaign mixing straggler + quorum + kill faults:
+    heal-then-must-converge, cross-rank bitwise identity, and the
+    correction accounting (exact single-epoch, sandwich across waves) are
+    asserted inside run_elastic_schedule.  Seed 9303 draws the whole mix
+    (rank 3 killed at version 2, while the straggler still lags; a spare
+    that arrives at once and is promoted) and is done in 1.5 s; the sweep
+    over seeds is the ``slow`` twin's.  Five seeds in a row, each under a
+    wall-clock deadline, failed at the driver beside five other xdist
+    workers and never alone (ROADMAP.md D11): tier-1 keeps the mechanism,
+    not the sweep."""
+    r = run_elastic_schedule(9303, deadline_sec=90.0, **CAMPAIGN)
+    assert r.outcome == "completed", f"seed 9303: {r}"
 
 
 @pytest.mark.slow
 def test_fuzz_straggler_quorum_kill_campaign_slow():
-    """The acceptance sweep: 20 seeds across worlds/specs/delays."""
+    """The acceptance sweep: 20 seeds across worlds/specs/delays, and the
+    five seeds tier-1 ran before it kept one."""
     for i, seed in enumerate(range(9400, 9420)):
         world = 3 + (i % 2)
         spec = ("0.5", "0.6", "2")[i % 3]
@@ -498,6 +508,9 @@ def test_fuzz_straggler_quorum_kill_campaign_slow():
                                             3),
                                  quorum=spec, niter=5, mix_faults=True,
                                  deadline_sec=60.0)
+        assert r.outcome == "completed", f"seed {seed}: {r}"
+    for seed in range(9300, 9305):
+        r = run_elastic_schedule(seed, deadline_sec=60.0, **CAMPAIGN)
         assert r.outcome == "completed", f"seed {seed}: {r}"
 
 
