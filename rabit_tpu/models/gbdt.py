@@ -350,12 +350,16 @@ def train_round_fused(
 
     When the round is lowered, each level leaves one ``gbdt.hist_plan`` span
     with what ``ops.boost.hist_plan`` reckoned for its kernel
-    (``nodes_built``, ``nodes_derived``, ``lanes_a_feature``), the gauge
+    (``nodes_built``, ``nodes_derived``, ``lanes_a_feature``,
+    ``regs_a_broadcast``), the gauge
     ``gbdt_hist_rows_streamed_per_round`` takes rows x (tile sweeps of every
     level + routing passes), ``gbdt_hist_nodes_derived_per_round`` the
     nodes a round reads off a subtraction (16 at depth 6, 112 at depth 8),
-    and ``gbdt_hist_feats_a_register`` the features that share a 128-lane
-    register of the indicator (2 at up to 64 bins, else 1).
+    ``gbdt_hist_feats_a_register`` the features that share a 128-lane
+    register of the indicator (2 at up to 64 bins, else 1), and
+    ``gbdt_hist_regs_a_broadcast`` the registers one lane broadcast of a
+    word of four packed codes serves (2 at up to 64 bins, 4 at up to 128, 8
+    at 256).
 
     ``xb3`` is the pre-blocked quantized matrix from ``ops.boost.block_rows``
     (built once per fit).  ``combine`` is the histogram allreduce hook
@@ -414,6 +418,7 @@ def train_round_fused(
                 m_rows=plan.m_rows, m_tiles=plan.m_tiles,
                 feat_tiles=plan.feat_tiles, tile_feats=plan.tile_feats,
                 lanes_a_feature=plan.lanes_a_feature,
+                regs_a_broadcast=plan.regs_a_broadcast,
                 acc_block_bytes=plan.acc_block_bytes,
                 vmem_bytes=plan.vmem_bytes):
             if plan.nodes_derived:
@@ -442,6 +447,8 @@ def train_round_fused(
         nodes_derived)
     obs.get_registry().gauge("gbdt_hist_feats_a_register").set(
         max(1, 128 // root.lanes_a_feature))
+    obs.get_registry().gauge("gbdt_hist_regs_a_broadcast").set(
+        root.regs_a_broadcast)
     # Leaf (g, h) masses come straight off the final combined histogram
     # (split_child_masses) — already globally reduced, so no leaf collective
     # and no histogram work in the last row pass (depth collectives per

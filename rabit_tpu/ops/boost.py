@@ -21,11 +21,17 @@ row block's gradient matrix L (one g column + one h column per node) is
 contracted against per-feature bin indicators built in VMEM; f32 gradients
 are split hi/lo into two bfloat16 matmuls (error ~2^-16-relative).  The
 lanes a feature takes in the indicator follow the bins (``_bins_eff``):
-whole 128-lane registers above 64 bins; at up to 64 bins 64 lanes, two
-features a register, and since what bounds the build is the lane broadcast
-of a code column, the kernel packs the block's codes four a word first so
-that one broadcast serves two registers (``_accum``).  The rule reads
-``n_bins`` alone; no option chooses.
+64 at up to 64 bins, two features a register, else whole 128-lane
+registers.  What bounds the build of a level below a full MXU tile of
+stacked rows is the lane broadcast of a code column, so there — where the
+codes fit a byte (up to 256 bins); at 64 lanes a feature at every level —
+the kernel packs the block's codes four a word first and ONE broadcast
+serves four features: two registers at 64 lanes a feature, four at 128,
+eight at 256 (``_accum``, ``_packed``, ``HistPlan.regs_a_broadcast``).  From
+a full tile on the MXU's streaming hides the build, and at 128 and 256
+lanes a feature a code column is broadcast a feature, as it always was.
+The rule reads ``n_bins`` and the level's stacked rows alone; no option
+chooses.
 
 ``hist_plan`` reckons what a level asks of the chip from the shape alone.
 The stacked gradient matrix has 4 rows a node built (g and h, hi and lo
@@ -36,7 +42,7 @@ level 5, at any width — builds half of them, one child a parent, with the
 matmul, the accumulator block and the VMEM of the level above; no option
 chooses.  Up to ``TILE_FEATS`` features (one 128-lane tile of blocked codes)
 the accumulator ``(2 * nodes built, F * B_eff)`` float32 (F to whole
-words of four at 64 lanes a feature) is ONE block held
+words of four feature slots where the level packs the codes) is ONE block held
 across the row grid, and routing rides the histogram's sweep.  A wider matrix is
 walked in feature tiles: a grid over (feature tile, row block), the row
 axis innermost, one tile's accumulator block resident across its row
@@ -84,17 +90,50 @@ def _bins_eff(n_bins: int) -> int:
     return 64 if n_bins <= 64 else _round_up(n_bins, 128)
 
 
-def _block_feats(n_feat: int, n_bins: int) -> int:
-    """Feature slots of an accumulator block over ``n_feat`` features: at 64
-    lanes a feature the kernels pack the codes four a word (``_accum``), so
-    whole words of slots."""
-    return _round_up(n_feat, 4) if _bins_eff(n_bins) < 128 else n_feat
+def _packed(n_bins: int, m_rows: int) -> bool:
+    """Whether a kernel over ``m_rows`` stacked rows of gradient matrix
+    builds its indicator from words of four packed codes (``_accum``), one
+    lane broadcast for four features.  The codes have to fit a byte (up to
+    256 bins).  At 64 lanes a feature it always does (PR 35: the words won
+    at every level).  At 128 and 256 lanes a broadcast already serves one
+    and two registers, and packing pays where the build bounds the kernel:
+    below a full MXU tile of stacked rows.  From a full tile on the MXU's
+    streaming hides the build and the packed kernel is slower (its matmul
+    results spill: ``hist_plan``): F = 67 x 256 bins x 2,621,440 rows,
+    kernels alone, a code column a feature | packed: 46.8 | 35.2 ms at 16
+    stacked rows, 46.9 | 36.7 at 32, 48.3 | 46.2 at 64, 70.5 | 78.4 at 128,
+    150.2 | 158.3 at 256; F = 28: 24.1 | 19.4 at 32, 25.3 | 25.4 at 64 (my
+    chip runs, PR 37)."""
+    return n_bins <= 256 and (_bins_eff(n_bins) < 128 or m_rows < MXU_ROWS)
 
 
-def _pick_fc(n_feat: int, n_bins: int) -> int:
-    """Features per matmul group (N = fc * bins_eff ~ 1792 lanes: 28
-    features at up to 64 bins, 14 at up to 128, 7 at 256)."""
-    return min(n_feat, max(1, 1792 // _bins_eff(n_bins)))
+def _block_feats(n_feat: int, packed: bool) -> int:
+    """Feature slots of an accumulator block over ``n_feat`` features: whole
+    words of four where the kernel packs the codes (28 stay 28, 67 become
+    68)."""
+    return _round_up(n_feat, 4) if packed else n_feat
+
+
+#: words of four packed codes a matmul group of a one-block kernel above 64
+#: bins (``_pick_fc``)
+GROUP_WORDS = 4
+
+
+def _pick_fc(n_feat: int, n_bins: int, packed: bool) -> int:
+    """Feature slots per matmul group of a one-block kernel.  A code column
+    a feature: N = fc * bins_eff ~ 1792 lanes (14 features at up to 128
+    bins, 7 at 256).  Packed, whole words of four: 28 slots (seven words,
+    1792 lanes) at up to 64 bins; above, ``GROUP_WORDS`` words (4096 lanes
+    at 256 bins).  Kernels alone at F = 67 x 256 bins x 2,621,440 rows,
+    groups of 1 | 2 | 3 | 4 | 6 | 9 | 17 words: 36.7 | 35.2 | 33.7 | 32.9 |
+    40.4 | 40.4 | 41.0 ms at 16 stacked rows, 37.7 | 36.7 | 35.0 | 35.0 |
+    40.1 | 40.1 | 43.0 at 32, and 46.2 at 64 whatever the group; at F = 28
+    groups of 1 | 2 | 4 | 7: - | 18.3 | 18.5 | 18.5 at 16 rows, 20.6 |
+    19.4 | 19.4 | 19.7 at 32 (my chip runs, PR 37)."""
+    be = _bins_eff(n_bins)
+    if not packed:
+        return min(n_feat, max(1, 1792 // be))
+    return min(n_feat, 4 * (7 if be < 128 else GROUP_WORDS))
 
 
 def _pick_tile_fc(n_bins: int) -> int:
@@ -141,6 +180,7 @@ class HistPlan(NamedTuple):
     tile_feats: int       # features a tile: all of F where one tile holds them
     feat_tiles: int       # tiles a level; each sweeps every row block
     lanes_a_feature: int  # 64 at up to 64 bins (two features a register), else 128, 256, ...
+    regs_a_broadcast: int  # registers of the indicator one lane broadcast serves
 
     @property
     def nodes_built(self) -> int:
@@ -155,6 +195,13 @@ class HistPlan(NamedTuple):
     def m_tiles(self) -> int:
         return -(-self.m_rows // MXU_ROWS)
 
+    @property
+    def packed(self) -> bool:
+        """Whether the level's kernel packs the codes four a word
+        (``_packed``): then one lane broadcast serves four features' registers
+        (2 at 64 lanes a feature, 4 at 128, 8 at 256), else one feature's."""
+        return self.regs_a_broadcast * 128 == 4 * self.lanes_a_feature
+
 
 def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int) -> HistPlan:
     """A level's feature tiles, one tile's accumulator block and the scoped
@@ -165,9 +212,18 @@ def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int) -> HistPlan
     what Mosaic calls the kernel's scoped allocation: the accumulator once
     (its block index never changes along the row grid), the row blocks twice
     — codes at 128 lanes, node in and out, g and h at one lane padded to 128
-    — and ``VMEM_STACK``.  (F = 67, level 7: 16.75 + 5 MiB of blocks,
+    — and ``VMEM_STACK``.  (F = 67, level 8: 16.75 + 5 MiB of blocks,
     Mosaic's own "21.75M", 24.75 with its stack.)  The MXU walks M a 128-row
     tile at a time by itself.
+
+    A level below a full MXU tile of stacked rows packs the codes four a
+    word (``_packed``: at more than 64 bins levels 0 to 5): its
+    accumulator's features are whole words of four slots (``_block_feats``:
+    68 for 67), and at 128 and 256 lanes a feature the kernel's stack grows
+    with the block, every matmul group's result live at once: Mosaic's
+    scoped count read 4.4 to 4.8 times the block over the 5 MiB of a level
+    with a small one (F = 67: 16.0 MiB at level 5 for a 2.1 MiB block; F =
+    28: 9.2 for 0.9; my compile, PR 37), so five blocks more are asked for.
 
     A wider matrix goes in tiles of ``TILE_FEATS`` features, the last one
     ragged.  A tile's accumulator block is counted twice (its index moves
@@ -184,12 +240,14 @@ def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int) -> HistPlan
     m_pad = _round_up(2 * (2 ** level - derived), 8)
     tile = min(n_feat, TILE_FEATS)
     tiles = -(-n_feat // tile)
-    acc = m_pad * _block_feats(tile, n_bins) * _bins_eff(n_bins) * 4
+    be = _bins_eff(n_bins)
+    packed = _packed(n_bins, 2 * m_pad)
+    acc = m_pad * _block_feats(tile, packed) * be * 4
     if tiles == 1:
         blocks = acc + 2 * 4 * block_rows * (_round_up(n_feat, 128) + 4 * 128)
     else:
         blocks = 2 * acc + 2 * 4 * block_rows * (tile + 3 * 128)
-    need = blocks + VMEM_STACK
+    need = blocks + VMEM_STACK + (5 * acc if packed and be >= 128 else 0)
     if need > VMEM_MOST:
         raise ValueError(
             f"hist_plan: level {level} of F={n_feat} features x {n_bins} bins "
@@ -197,8 +255,8 @@ def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int) -> HistPlan
             f"of one {tile}-feature tile is {acc} bytes and the kernel needs "
             f"{need} of {VMEM_MOST} bytes of VMEM (a depth of {level + 1} is "
             "one level too many)")
-    return HistPlan(level, derived, m_pad, acc, need, tile, tiles,
-                    _bins_eff(n_bins))
+    return HistPlan(level, derived, m_pad, acc, need, tile, tiles, be,
+                    (4 if packed else 1) * be // 128)
 
 
 def _encode_bf16(L):
@@ -254,13 +312,14 @@ def _encode_i8(L):
 
 
 def _accum(xb_blk, L, out_ref, *, n_bins: int, n_feat: int, fc: int, i8: bool,
-           r_split: int = 1, feats_left=None):
+           packed: bool, r_split: int = 1, feats_left=None):
     """out_ref[m, f*Beff+b] += sum_r L[r, m] * [xb_blk[r, f] == b], via the
     MXU: the encoded gradient planes are contracted against per-feature-
-    group bin-indicator matrices built in VMEM.  At 64 lanes a feature
-    (``_bins_eff``) ``n_feat`` counts the block's feature SLOTS, whole words
-    of four, and slot 4 * q + b of ``out_ref`` is feature b * n_feat / 4 + q
-    (``_cut_lanes`` puts them back in order).
+    group bin-indicator matrices built in VMEM.  Where the kernel packs the
+    codes (``packed``: ``_packed``) ``n_feat`` counts the block's feature
+    SLOTS, whole words of four, slot 4 * q + b of ``out_ref`` is feature
+    b * n_feat / 4 + q (``_cut_lanes`` puts them back in order), and
+    ``xb_blk`` is whole 128-lane tiles of codes, each in 0..255.
 
     ``r_split > 1`` splits the row block into that many independent
     sub-contractions per feature group (raw accumulators summed, one
@@ -289,38 +348,59 @@ def _accum(xb_blk, L, out_ref, *, n_bins: int, n_feat: int, fc: int, i8: bool,
     # comparison" on vector<...xi8> cmpi AND vector<...xbf16> cmpf
     # (RESULTS/narrow_compare_rejection.txt; the local jax.export gate
     # accepts both, so only on-chip compiles catch this).
-    b_iota = lax.broadcasted_iota(jnp.int32, (rs, max(be, 128)), 1)
     unit = 1            # features a lane broadcast serves
-    if be < 128:
+    if packed:
         # The lane broadcast of a code column bounds a level of up to one
         # MXU tile (3 cycles an (8 rows, 1 column) whatever the lanes it
-        # feeds: PERF.md section 5), so at 64 lanes a feature ONE broadcast
-        # serves four features, two registers: the block's codes are packed
-        # four a 32-bit word here, in VMEM — word q holds, a byte each,
-        # feature slots q, s + q, 2s + q, 3s + q of the block (s = n_feat /
-        # 4): the block rolled along its lanes three times, shifted and
-        # or-ed, no shuffle a code — and a register is one and and one
-        # compare of the word's broadcast: lanes 0-63 pick bytes 0 | 2 and
-        # match them with the lane's bin, lanes 64-127 bytes 1 | 3.  Lanes
-        # past the matrix in a ragged last tile hold anything, and a shifted
-        # word of them spills into higher bytes only: slots further past it
-        # still.  (Rolls, not lane slices: the slice at 2s compiles, and on
-        # the chip gives wrong bytes, silently; PR 35.)
+        # feeds: PERF.md section 5), so ONE broadcast serves four features:
+        # the block's codes are packed four a 32-bit word here, in VMEM —
+        # word q holds, a byte each, feature slots q, s + q, 2s + q, 3s + q
+        # of the block (s = n_feat / 4): the block rolled along its lanes
+        # three times, shifted and or-ed, no shuffle a code — and a register
+        # is one and and one compare of the word's broadcast against keys
+        # shifted once a kernel (never the word).  At 64 lanes a feature a
+        # register holds two bytes: lanes 0-63 pick bytes 0 | 2 and match
+        # them with the lane's bin, lanes 64-127 bytes 1 | 3 (two registers
+        # a broadcast).  At 128 and 256 lanes a register is one byte's (four
+        # and eight registers a broadcast), and at 256 the byte's two
+        # registers, bins 0-127 and 128-255, share its and.  Byte 3 reaches
+        # the sign bit (mask 255 << 24, keys of bins >= 128): the shifts run
+        # on int32 lanes and wrap, the compare is an equality of bit
+        # patterns, and no Python int is shifted.  Lanes past the matrix
+        # (the last lanes of a block wider than the codes, the ragged last
+        # tile) hold anything, and a shifted word of them spills into higher
+        # bytes only: slots further past it still.  (Rolls, not lane slices:
+        # the slice at 2s compiles, and on the chip gives wrong bytes,
+        # silently; PR 35.)
         unit = 4
+        lanes = min(be, 128)
+        b_iota = lax.broadcasted_iota(jnp.int32, (rs, 128), 1)
         s, width = n_feat // unit, xb_blk.shape[1]
         words = xb_blk
         for b in range(1, unit):
             words = words | (pltpu.roll(xb_blk, width - b * s, 1) << (8 * b))
-        shift = jnp.where(b_iota >= be, 8, 0)
-        masks = [jnp.left_shift(255, shift + p) for p in (0, 16)]
-        keys = [jnp.left_shift(b_iota & (be - 1), shift + p) for p in (0, 16)]
+        shift = jnp.where(b_iota >= lanes, 8, 0)
+        steps = range(0, 32, 8 * 128 // lanes)      # a register's (first) byte
+
+        def key_of(p, hi):
+            bins = b_iota & (lanes - 1)
+            return jnp.left_shift(bins + hi if hi else bins, shift + p)
+
+        masks = [jnp.left_shift(255, shift + p) for p in steps]
+        keys = [[key_of(p, hi) for hi in range(0, be, 128)] for p in steps]
+    else:
+        b_iota = lax.broadcasted_iota(jnp.int32, (rs, be), 1)
 
     def indicator(lo, gi, k):
-        if be >= 128:
+        if not packed:
             return [xb_blk[lo : lo + rs, f : f + 1] == b_iota
                     for f in range(gi, gi + k)]
-        return [(words[lo : lo + rs, q : q + 1] & mask) == key
-                for q in range(gi, gi + k) for mask, key in zip(masks, keys)]
+        cols = []
+        for q in range(gi, gi + k):
+            for mask, regs in zip(masks, keys):
+                a = words[lo : lo + rs, q : q + 1] & mask
+                cols += [a == key for key in regs]
+        return cols
 
     def group(gi, k):
         # Sum the RAW accumulators across sub-blocks and decode once:
@@ -401,7 +481,7 @@ def _route(xb_blk, node, feat_row, thr_row, *, p_pad: int, n_feat: int):
 
 
 def _level0_kernel(xb_ref, g_ref, h_ref, out_ref, *, n_bins, n_feat, fc, i8,
-                   r_split=1):
+                   packed, r_split=1):
     @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[:] = jnp.zeros_like(out_ref)
@@ -410,14 +490,15 @@ def _level0_kernel(xb_ref, g_ref, h_ref, out_ref, *, n_bins, n_feat, fc, i8,
     node = jnp.zeros((r, 1), jnp.int32)
     L = _gradient_matrix(node, g_ref[0], h_ref[0], n_nodes=1, m_pad=8)
     _accum(xb_ref[0], L, out_ref, n_bins=n_bins, n_feat=n_feat, fc=fc, i8=i8,
-           r_split=r_split)
+           packed=packed, r_split=r_split)
 
 
 # -- level d >= 1: route + histogram ---------------------------------------
 
 
 def _level_kernel(xb_ref, node_ref, g_ref, h_ref, feat_ref, thr_ref, *refs,
-                  n_nodes, n_bins, n_feat, m_pad, p_pad, fc, i8, r_split=1):
+                  n_nodes, n_bins, n_feat, m_pad, p_pad, fc, i8, packed,
+                  r_split=1):
     """``refs``: at a derived level the built-child table, then the
     accumulator (``n_nodes`` built nodes) and the routed node ids."""
     *built_ref, out_ref, node_out_ref = refs
@@ -433,14 +514,14 @@ def _level_kernel(xb_ref, node_ref, g_ref, h_ref, feat_ref, thr_ref, *refs,
         node, g_ref[0], h_ref[0], n_nodes=n_nodes, m_pad=m_pad,
         built_row=built_ref[0][0:1, :m_pad] if built_ref else None)
     _accum(xb_ref[0], L, out_ref, n_bins=n_bins, n_feat=n_feat, fc=fc, i8=i8,
-           r_split=r_split)
+           packed=packed, r_split=r_split)
 
 
 # -- one feature tile of a level wider than TILE_FEATS: histogram only ------
 
 
 def _tile_kernel(xb_ref, *refs, n_feat, tile, n_nodes, n_bins, m_pad, fc, i8,
-                 r_split=1):
+                 packed, r_split=1):
     """Grid (feature tile, row block), the rows innermost: ``out_ref`` is
     this tile's accumulator block, zeroed at its first row block.  The rows
     come routed: ``refs`` is their node ids (not at the root, where every
@@ -458,7 +539,8 @@ def _tile_kernel(xb_ref, *refs, n_feat, tile, n_nodes, n_bins, m_pad, fc, i8,
         node, g_ref[0], h_ref[0], n_nodes=n_nodes, m_pad=m_pad,
         built_row=lead[1][0:1, :m_pad] if len(lead) > 1 else None)
     _accum(xb_ref[0], L, out_ref, n_bins=n_bins, n_feat=tile, fc=fc, i8=i8,
-           r_split=r_split, feats_left=n_feat - pl.program_id(0) * tile)
+           packed=packed, r_split=r_split,
+           feats_left=n_feat - pl.program_id(0) * tile)
 
 
 # -- routing-only pass (leaf assignment without histogramming) -------------
@@ -617,28 +699,27 @@ def _vmem_params(plan: HistPlan):
     return pltpu.CompilerParams(vmem_limit_bytes=plan.vmem_bytes)
 
 
-def _one_block(xb3, n_bins: int):
-    """What a kernel with ONE accumulator block over all of ``xb3``'s
-    features takes: the codes — at up to 64 bins padded with zeros to whole
-    128-lane tiles of columns (what a block of them fills in VMEM anyway),
-    so that ``_accum`` rolls whole registers; above, as they are — the
-    block's feature slots, its lanes and the features a matmul group."""
-    F = xb3.shape[2]
-    slots = _block_feats(F, n_bins)
-    if _bins_eff(n_bins) < 128 and F % 128:
-        xb3 = jnp.pad(xb3, ((0, 0), (0, 0), (0, -F % 128)))
-    return xb3, slots, slots * _bins_eff(n_bins), _pick_fc(slots, n_bins)
+def _one_block(n_feat: int, n_bins: int, packed: bool):
+    """What a kernel with ONE accumulator block over all ``n_feat`` features
+    takes: the code lanes of its block — where it packs the codes whole
+    128-lane tiles (what a block of them fills in VMEM anyway), so that
+    ``_accum`` rolls whole registers; the lanes past the matrix hold
+    anything, as the ragged last tile's do — the block's feature slots, its
+    lanes and the feature slots a matmul group."""
+    slots = _block_feats(n_feat, packed)
+    return (_round_up(n_feat, 128) if packed else n_feat, slots,
+            slots * _bins_eff(n_bins), _pick_fc(slots, n_bins, packed))
 
 
-def _cut_lanes(out, n_feat: int, n_bins: int, tile: int):
+def _cut_lanes(out, n_feat: int, n_bins: int, tile: int, packed: bool):
     """A kernel's ``(m, lanes)`` accumulator as ``(m, F, n_bins)``: the pad
     lanes of every feature, and the feature slots past F that fill the last
-    word or the ragged last tile, cut off.  At 64 lanes a feature a tile of
-    ``tile`` feature slots comes word by word (``_accum``): slot 4 * q + b
-    holds feature b * tile / 4 + q, and is put back in the features' order
-    here, in the pass that cuts."""
+    word or the ragged last tile, cut off.  From a kernel that packs the
+    codes a tile of ``tile`` feature slots comes word by word (``_accum``):
+    slot 4 * q + b holds feature b * tile / 4 + q, and is put back in the
+    features' order here, in the pass that cuts."""
     be = _bins_eff(n_bins)
-    if be < 128:
+    if packed:
         out = out.reshape(out.shape[0], -1, tile // 4, 4, be).swapaxes(2, 3)
     return out.reshape(out.shape[0], -1, be)[:, :n_feat, :n_bins]
 
@@ -662,7 +743,7 @@ def _hist_tiles(plan: HistPlan, xb3, node3, g3, h3, *, n_bins, interpret,
         functools.partial(
             _tile_kernel, n_feat=F, tile=tile, n_nodes=plan.nodes_built,
             n_bins=n_bins, m_pad=m_pad, fc=_pick_tile_fc(n_bins), i8=mxu_i8,
-            r_split=r_split),
+            packed=plan.packed, r_split=r_split),
         grid=(tiles, nb),
         in_specs=[pl.BlockSpec((1, R, tile), lambda t, i: (i, 0, t))] + specs,
         out_specs=pl.BlockSpec((m_pad, tile * be), lambda t, i: (0, t)),
@@ -671,7 +752,7 @@ def _hist_tiles(plan: HistPlan, xb3, node3, g3, h3, *, n_bins, interpret,
         name=name,
         compiler_params=_vmem_params(plan),
     )(xb3, *rows)
-    return _cut_lanes(out, F, n_bins, tile)
+    return _cut_lanes(out, F, n_bins, tile, plan.packed)
 
 
 @functools.partial(
@@ -688,18 +769,18 @@ def hist_level0(xb3, g3, h3, *, n_bins: int, interpret: bool = False,
                           interpret=interpret, mxu_i8=mxu_i8, r_split=r_split,
                           name="hist_level0")
         return jnp.stack([out[0:1], out[1:2]], axis=-1)
-    xb3, slots, lanes, fc = _one_block(xb3, n_bins)
+    codes, slots, lanes, fc = _one_block(F, n_bins, plan.packed)
     out = pl.pallas_call(
         functools.partial(_level0_kernel, n_bins=n_bins, n_feat=slots, fc=fc,
-                          i8=mxu_i8, r_split=r_split),
+                          i8=mxu_i8, packed=plan.packed, r_split=r_split),
         grid=(nb,),
-        in_specs=[_blk(R, xb3.shape[2]), _blk(R, 1), _blk(R, 1)],
+        in_specs=[_blk(R, codes), _blk(R, 1), _blk(R, 1)],
         out_specs=pl.BlockSpec((8, lanes), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((8, lanes), jnp.float32),
         interpret=interpret,
         name="hist_level0",
     )(xb3, g3, h3)
-    out = _cut_lanes(out, F, n_bins, slots)
+    out = _cut_lanes(out, F, n_bins, slots, plan.packed)
     return jnp.stack([out[0:1], out[1:2]], axis=-1)
 
 
@@ -727,8 +808,9 @@ def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, *, depth: int,
     Wider than ``TILE_FEATS`` features routing and histogram are two
     kernels: ``route_level``'s tiled pass, then a histogram sweep a feature
     tile (``hist_plan``).  The kernel asks for ``hist_plan``'s scoped VMEM
-    where Mosaic's default might not hold it (F = 67 from level 6 on), and
-    is the same kernel elsewhere.  Compiled on its own, a 16.75 MiB
+    where Mosaic's default might not hold it (F = 67 at every level, F = 28
+    at levels 4 and 5, since the packed kernels' stack is counted), and is
+    the same kernel elsewhere.  Compiled on its own, a 16.75 MiB
     accumulator block at F = 67 (level 8 now, level 7 with every node built)
     is refused at the default, on the chip too; inside the whole round XLA
     keeps the accumulator in VMEM itself and the default holds, and asking
@@ -753,17 +835,18 @@ def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, *, depth: int,
         hist = jnp.stack([out[:n_nodes], out[n_nodes : 2 * n_nodes]], axis=-1)
         return hist, node_out
     p_pad = _round_up(n_prev, 128)
-    xb3, slots, lanes, fc = _one_block(xb3, n_bins)
+    codes, slots, lanes, fc = _one_block(F, n_bins, plan.packed)
     featp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(feat)
     thrp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(thr)
     out, node_out = pl.pallas_call(
         functools.partial(
             _level_kernel, n_nodes=n_nodes, n_bins=n_bins, n_feat=slots,
-            m_pad=m_pad, p_pad=p_pad, fc=fc, i8=mxu_i8, r_split=r_split,
+            m_pad=m_pad, p_pad=p_pad, fc=fc, i8=mxu_i8, packed=plan.packed,
+            r_split=r_split,
         ),
         grid=(nb,),
         in_specs=[
-            _blk(R, xb3.shape[2]), _blk(R, 1), _blk(R, 1), _blk(R, 1),
+            _blk(R, codes), _blk(R, 1), _blk(R, 1), _blk(R, 1),
             pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
             pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
         ] + [pl.BlockSpec(b.shape, lambda i: (0, 0)) for b in built],
@@ -779,7 +862,7 @@ def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, *, depth: int,
         name=f"hist_level_d{depth}",
         compiler_params=_vmem_params(plan),
     )(xb3, node3, g3, h3, featp, thrp, *built)
-    out = _cut_lanes(out, F, n_bins, slots)
+    out = _cut_lanes(out, F, n_bins, slots, plan.packed)
     hist = jnp.stack([out[:n_nodes], out[n_nodes : 2 * n_nodes]], axis=-1)
     return hist, node_out
 

@@ -113,7 +113,7 @@ def node_histograms_onehot(xb, g, h, node, n_nodes: int, n_bins: int,
 
 def _hist_kernel(xb_ref, node_ref, g_ref, h_ref, out_ref, *,
                  n_nodes: int, n_bins: int, m_pad: int, n_feat: int, fc: int,
-                 i8: bool):
+                 i8: bool, packed: bool):
     from rabit_tpu.ops import boost
 
     @pl.when(pl.program_id(0) == 0)
@@ -123,7 +123,7 @@ def _hist_kernel(xb_ref, node_ref, g_ref, h_ref, out_ref, *,
     L = boost._gradient_matrix(node_ref[0], g_ref[0], h_ref[0],
                                n_nodes=n_nodes, m_pad=m_pad)
     boost._accum(xb_ref[0], L, out_ref,
-                 n_bins=n_bins, n_feat=n_feat, fc=fc, i8=i8)
+                 n_bins=n_bins, n_feat=n_feat, fc=fc, i8=i8, packed=packed)
 
 
 @functools.partial(
@@ -151,16 +151,17 @@ def node_histograms_pallas(xb, g, h, node, n_nodes: int, n_bins: int,
         node = jnp.pad(node, (0, pad))
     m_pad = _round_up(2 * n_nodes, 8)
     nb = n_pad // R
-    xb3, slots, lanes, fc = boost._one_block(xb.reshape(nb, R, F), n_bins)
+    packed = boost._packed(n_bins, 2 * m_pad)
+    codes, slots, lanes, fc = boost._one_block(F, n_bins, packed)
 
     out = pl.pallas_call(
         functools.partial(
             _hist_kernel, n_nodes=n_nodes, n_bins=n_bins, m_pad=m_pad,
-            n_feat=slots, fc=fc, i8=mxu_i8,
+            n_feat=slots, fc=fc, i8=mxu_i8, packed=packed,
         ),
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((1, R, xb3.shape[2]), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, R, codes), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, R, 1), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, R, 1), lambda i: (i, 0, 0)),
             pl.BlockSpec((1, R, 1), lambda i: (i, 0, 0)),
@@ -170,13 +171,13 @@ def node_histograms_pallas(xb, g, h, node, n_nodes: int, n_bins: int,
         interpret=interpret,
         name=f"node_histograms_n{n_nodes}",
     )(
-        xb3,
+        xb.reshape(nb, R, F),
         node.reshape(nb, R, 1),
         g.reshape(nb, R, 1),
         h.reshape(nb, R, 1),
     )
 
-    out = boost._cut_lanes(out, F, n_bins, slots)
+    out = boost._cut_lanes(out, F, n_bins, slots, packed)
     return jnp.stack([out[:n_nodes], out[n_nodes : 2 * n_nodes]], axis=-1)
 
 

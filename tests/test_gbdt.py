@@ -432,25 +432,31 @@ def test_fused_round_at_the_higgs_shape_is_bitwise_what_it_was():
 
 
 def test_hist_plan():
-    """The plan alone.  HIGGS (F 28, depth 6): today's fc, every level inside
-    Mosaic's default scoped VMEM, so no kernel asks for more.  Every node is
+    """The plan alone.  HIGGS (F 28, depth 6): every node is
     built up to level 4; from level 5 on (4 * 2**d rows of stacked gradient
     matrix: a full MXU tile) one child a parent is, and m_pad, the
-    accumulator block and the VMEM ask are those of the level above.  Criteo
-    (F 67, depth 8): one accumulator block a level, 8.4 MiB at level 7 in
-    two MXU tiles of M, every kernel inside what it may ask for.  The halved
-    block lets levels 8 and 9 through (16.75 and 33.5 MiB: depths 9 and 10);
-    level 10 is refused by name."""
+    accumulator block and the VMEM ask are those of the level above.  Every
+    HIGGS level stacks less than a full tile, so every one packs the codes
+    four a word (matmul groups of ``GROUP_WORDS`` words: 4,096 lanes), and levels 4 and 5
+    ask Mosaic for the stack that the packed kernel's matmul results take
+    (five blocks more).  Criteo (F 67, depth 8): one accumulator block a
+    level, over 68 feature slots (whole words) where the level packs (up to
+    level 5) and over the 67 features from a full tile of stacked rows on,
+    8.4 MiB at level 7 in two MXU tiles of M, every kernel inside what it
+    may ask for.  The halved block lets levels 8 and 9 through (16.75 and
+    33.5 MiB: depths 9 and 10); level 10 is refused by name."""
     from rabit_tpu.ops import boost
 
-    assert boost._pick_fc(28, 256) == 7
+    assert boost._pick_fc(28, 256, True) == 4 * boost.GROUP_WORDS == 16
+    assert boost._pick_fc(28, 256, False) == 7
     for d in range(1, 6):
         p = boost.hist_plan(28, 256, d, 1024)
         built = 2 ** d if d < 5 else 2 ** (d - 1)
         assert (p.nodes_built, p.nodes_derived) == (built, 2 ** d - built)
         assert p.m_pad == max(8, 2 * built)
         assert p.acc_block_bytes == p.m_pad * 28 * 256 * 4
-        assert p.vmem_bytes <= boost.VMEM_DEFAULT
+        assert p.packed and p.regs_a_broadcast == 8
+        assert (p.vmem_bytes <= boost.VMEM_DEFAULT) == (d <= 3)
     assert boost.hist_plan(28, 256, 4, 1024).m_rows == boost.MXU_ROWS // 2
     assert boost.hist_plan(28, 256, 5, 1024).m_rows == boost.MXU_ROWS // 2
     assert boost.hist_plan(28, 256, 0, 1024).nodes_derived == 0
@@ -460,7 +466,8 @@ def test_hist_plan():
             7: (64, 64, 128, 2), 8: (128, 128, 256, 4)}
     for d in range(1, 10):
         p = boost.hist_plan(67, 256, d, 1024)
-        assert p.acc_block_bytes == p.m_pad * 67 * 256 * 4
+        assert p.packed == (d <= 5) == (p.m_rows < boost.MXU_ROWS)
+        assert p.acc_block_bytes == p.m_pad * (68 if p.packed else 67) * 256 * 4
         assert p.acc_block_bytes + (5 << 20) < p.vmem_bytes <= boost.VMEM_MOST
         if d in want:
             assert (p.nodes_built, p.nodes_derived, p.m_pad, p.m_tiles) == want[d]
@@ -468,7 +475,7 @@ def test_hist_plan():
             assert (p.m_pad, p.nodes_built) == (2 * 2 ** (d - 1), 2 ** (d - 1))
     assert boost.hist_plan(67, 256, 7, 1024).acc_block_bytes == 128 * 67 * 1024
     assert boost.hist_plan(67, 256, 7, 1024).vmem_bytes > boost.VMEM_DEFAULT
-    assert boost.hist_plan(67, 256, 5, 1024).vmem_bytes <= boost.VMEM_DEFAULT
+    assert boost.hist_plan(67, 256, 0, 1024).vmem_bytes > boost.VMEM_DEFAULT
 
     with pytest.raises(ValueError, match=r"level 10 of F=67 .*bytes") as e:
         boost.hist_plan(67, 256, 10, 1024)
@@ -682,18 +689,22 @@ def _numpy_histogram(xb, g, h, node, n_nodes, bins):
 @pytest.mark.parametrize("tiling", ["one-tile", "tiled"])
 @pytest.mark.parametrize("d", [0, 2, 5], ids=["root", "built", "derived"])
 @pytest.mark.parametrize("F,bins", [(5, 64), (6, 33), (7, 17), (130, 64),
-                                    (131, 64)])
+                                    (131, 64), (28, 256), (67, 256), (5, 256),
+                                    (6, 128), (130, 256)])
 def test_two_features_a_register_match_a_numpy_histogram(F, bins, d, tiling,
                                                          monkeypatch):
-    """At up to 64 bins a feature takes 64 lanes, two share a register
-    (ops.boost._bins_eff) and the kernels pack the codes four a word
-    (ops.boost._accum): the root, a level with every node built and a
-    derived one (one child a parent) against numpy float64 sums, as ONE
-    accumulator block and walked in feature tiles.  The counts are odd and
-    no whole words (a last word of one, two or three features), fewer bins
-    than lanes (33, 17), and the ragged last tile holds one, two or three
-    features: of 130 and 131 behind a whole tile of 128, of 5, 6 and 7
-    behind a tile of 4 (``TILE_FEATS`` cut for the test)."""
+    """The kernels pack the codes four a word at every width
+    (ops.boost._accum); at up to 64 bins a feature takes 64 lanes and two
+    share a register (ops.boost._bins_eff), at 65 to 128 bins it takes one
+    register and at 256 two, the two of a byte sharing its and: the root, a
+    level with every node built and a derived one (one child a parent)
+    against numpy float64 sums, as ONE accumulator block and walked in
+    feature tiles.  The counts are odd and no whole words (a last word of
+    one, two or three features), fewer bins than lanes (33, 17), HIGGS's
+    and Criteo's widths (28: seven whole words; 67: a 68th slot), and the
+    ragged last tile holds one, two or three features: of 130 and 131
+    behind a whole tile of 128, of the others behind tiles of 4
+    (``TILE_FEATS`` cut for the test)."""
     from rabit_tpu.ops import boost
 
     one_tile = tiling == "one-tile"
@@ -702,78 +713,259 @@ def test_two_features_a_register_match_a_numpy_histogram(F, bins, d, tiling,
     rng = np.random.RandomState(F + bins + d)
     n, block = 512, 256
     plan = boost.hist_plan(F, bins, d, block)
-    assert plan.lanes_a_feature == 64
+    lanes = 64 if bins <= 64 else 128 if bins <= 128 else 256
+    assert plan.lanes_a_feature == lanes
+    assert plan.regs_a_broadcast == {64: 2, 128: 4, 256: 8}[lanes]
     assert (plan.feat_tiles == 1) == one_tile
-    assert plan.acc_block_bytes == plan.m_pad * 4 * 64 * (
+    assert plan.acc_block_bytes == plan.m_pad * 4 * lanes * (
         -(-F // 4) * 4 if one_tile else plan.tile_feats)
     xb = rng.randint(0, bins, size=(n, F))
     g, h = rng.randn(n).astype(np.float32), rng.rand(n).astype(np.float32)
-    xb3, g3, h3 = (boost.block_rows(jnp.asarray(a), block)[0]
-                   for a in (xb.astype(np.int32), g, h))
-    if d == 0:
-        hist = boost.hist_level0.__wrapped__(xb3, g3, h3, n_bins=bins,
-                                             interpret=True)
-        ref = _numpy_histogram(xb, g, h, np.zeros(n, int), 1, bins)
-    else:
-        n_prev = 2 ** (d - 1)
-        node = rng.randint(0, n_prev, size=n)
-        # splits on the last feature (the odd one's half register) too
-        feat = np.r_[F - 1, rng.randint(0, F, size=n_prev - 1)]
-        thr = rng.randint(0, bins, size=n_prev)
-        built_right = rng.randint(0, 2, size=n_prev) if plan.nodes_derived else None
-        hist, node_out = boost.hist_level.__wrapped__(
-            xb3, boost.block_rows(jnp.asarray(node, jnp.int32), block)[0],
-            g3, h3, jnp.asarray(feat, jnp.int32), jnp.asarray(thr, jnp.int32),
-            None if built_right is None else jnp.asarray(built_right, jnp.int32),
-            depth=d, n_bins=bins, interpret=True)
-        routed = 2 * node + (xb[np.arange(n), feat[node]] > thr[node])
-        np.testing.assert_array_equal(
-            np.asarray(boost.unblock_rows(node_out, n)), routed)
-        ref = _numpy_histogram(xb, g, h, routed, 2 * n_prev, bins)
-        if plan.nodes_derived:
-            ref = ref[2 * np.arange(n_prev) + built_right]
+    hist, ref = _level_and_numpy(boost, plan, xb, g, h, rng, bins, d, block)
     assert hist.shape == ref.shape == (plan.nodes_built, F, bins, 2)
     np.testing.assert_allclose(np.asarray(hist), ref, rtol=1e-4, atol=1e-4)
     assert np.abs(ref).sum((0, 2, 3)).min() > 0     # every feature has mass
 
 
-@pytest.mark.parametrize("bins,lanes", [(64, 64), (65, 128), (128, 128),
-                                        (256, 256)])
-def test_lanes_a_feature_follow_the_bins(bins, lanes):
+def _level_and_numpy(boost, plan, xb, g, h, rng, bins, d, block):
+    """Level ``d``'s kernel over ``xb`` (interpreted; routed through random
+    split tables below the root, the last feature among them) and the numpy
+    float64 histogram of the nodes it builds."""
+    n, F = xb.shape
+    xb3, g3, h3 = (boost.block_rows(jnp.asarray(a), block)[0]
+                   for a in (xb.astype(np.int32), g, h))
+    if d == 0:
+        hist = boost.hist_level0.__wrapped__(xb3, g3, h3, n_bins=bins,
+                                             interpret=True)
+        return hist, _numpy_histogram(xb, g, h, np.zeros(n, int), 1, bins)
+    n_prev = 2 ** (d - 1)
+    node = rng.randint(0, n_prev, size=n)
+    # splits on the last feature (the odd one's half register) too
+    feat = np.r_[F - 1, rng.randint(0, F, size=n_prev - 1)]
+    thr = rng.randint(0, bins, size=n_prev)
+    built_right = rng.randint(0, 2, size=n_prev) if plan.nodes_derived else None
+    hist, node_out = boost.hist_level.__wrapped__(
+        xb3, boost.block_rows(jnp.asarray(node, jnp.int32), block)[0],
+        g3, h3, jnp.asarray(feat, jnp.int32), jnp.asarray(thr, jnp.int32),
+        None if built_right is None else jnp.asarray(built_right, jnp.int32),
+        depth=d, n_bins=bins, interpret=True)
+    routed = 2 * node + (xb[np.arange(n), feat[node]] > thr[node])
+    np.testing.assert_array_equal(
+        np.asarray(boost.unblock_rows(node_out, n)), routed)
+    ref = _numpy_histogram(xb, g, h, routed, 2 * n_prev, bins)
+    if plan.nodes_derived:
+        ref = ref[2 * np.arange(n_prev) + built_right]
+    return hist, ref
+
+
+@pytest.mark.parametrize("d", [0, 2, 5], ids=["root", "built", "derived"])
+@pytest.mark.parametrize("F", [8, 67, 130], ids=["words", "criteo", "tiled"])
+def test_byte_3_of_a_word_meets_the_sign_bit(F, d):
+    """256 bins, every code 255 or 128 (one of the two a row, the same in
+    every slot): each word of four packed codes is 0xFFFFFFFF or 0x80808080,
+    so byte 3's mask (255 << 24) and its keys (bins 128 to 255, shifted into
+    the sign bit) are met in every word, and a word compares as a negative
+    int32.  All the mass lies in bins 128 and 255 of every feature, in the
+    numpy sums' amounts; a key that overflowed, or a mask that lost its top
+    bit, would leave byte 3's features (the last quarter of the slots)
+    empty or put their mass elsewhere."""
+    from rabit_tpu.ops import boost
+
+    rng = np.random.RandomState(300 + F + d)
+    n, block, bins = 512, 256, 256
+    plan = boost.hist_plan(F, bins, d, block)
+    assert plan.regs_a_broadcast == 8
+    xb = np.repeat(np.where(rng.rand(n, 1) < 0.5, 255, 128), F, axis=1)
+    # a few rows of mixed words, so that routing below the root has two sides
+    xb[::7] = rng.choice([128, 255], size=xb[::7].shape)
+    g, h = rng.randn(n).astype(np.float32), rng.rand(n).astype(np.float32)
+    hist, ref = _level_and_numpy(boost, plan, xb, g, h, rng, bins, d, block)
+    hist = np.asarray(hist)
+    np.testing.assert_allclose(hist, ref, rtol=1e-4, atol=1e-4)
+    others = np.delete(hist, [128, 255], axis=2)
+    assert not others.any()
+    last_quarter = hist[:, -(-F // 4) * 3:]            # byte 3's features
+    assert np.abs(last_quarter[:, :, [128, 255], 1]).sum((0, 2)).min() > 0
+
+
+@pytest.mark.parametrize("bins,lanes,regs", [(64, 64, 2), (65, 128, 4),
+                                             (128, 128, 4), (256, 256, 8)])
+def test_lanes_a_feature_follow_the_bins(bins, lanes, regs):
     """``_bins_eff``: 64 lanes a feature at up to 64 bins, else the bins
     padded to whole 128-lane registers; the matmul groups and the plan's
-    blocks follow it."""
+    blocks follow it.  Below a full MXU tile of stacked rows the kernel
+    packs the codes four a word, and the registers one lane broadcast
+    serves are four features': 2, 4, 8; its groups are whole words of four
+    slots, seven at up to 64 bins, ``GROUP_WORDS`` above.  From a full tile
+    on (level 6) the 64-lane kernel still packs and the wider ones
+    broadcast a code column a feature: one register, or two.  Codes wider
+    than a byte are never packed."""
     from rabit_tpu.ops import boost
 
     assert boost._bins_eff(bins) == lanes
     assert boost._bins_eff(bins - 1 if bins != 65 else 127) == lanes
-    assert boost._pick_fc(2000, bins) == 1792 // lanes
+    assert boost._pick_fc(2000, bins, False) == 1792 // lanes
+    assert boost._pick_fc(2000, bins, True) == (
+        28 if lanes == 64 else 4 * boost.GROUP_WORDS)
+    assert boost._pick_fc(8, bins, True) == 8
     p = boost.hist_plan(100, bins, 3, 1024)
-    assert p.lanes_a_feature == lanes
+    assert (p.lanes_a_feature, p.regs_a_broadcast, p.packed) == (lanes, regs, True)
     assert p.acc_block_bytes == p.m_pad * 100 * lanes * 4
+    assert boost.hist_plan(99, bins, 3, 1024).acc_block_bytes == p.acc_block_bytes
+    full = boost.hist_plan(99, bins, 6, 1024)
+    assert full.m_rows == boost.MXU_ROWS
+    assert (full.packed, full.regs_a_broadcast) == (
+        (True, 2) if lanes == 64 else (False, lanes // 128))
+    assert full.acc_block_bytes == full.m_pad * (100 if full.packed else 99) * lanes * 4
+    wide = boost.hist_plan(100, 257, 3, 1024)
+    assert (wide.lanes_a_feature, wide.regs_a_broadcast, wide.packed) == (
+        384, 3, False)
 
 
 @pytest.mark.parametrize("F,last", [(28, 5), (67, 9)], ids=["higgs", "criteo"])
 def test_hist_plan_at_256_bins_is_field_for_field_what_it_was(F, last):
-    """Nothing of the plan moves above 64 bins: every level's plan at the
-    HIGGS and the Criteo width is, field for field, what the parent of PR 35
-    reckoned (the formulas as they stood there), with ``lanes_a_feature``
-    256 beside them."""
+    """What PR 37 moved of the plan at 256 bins, and nothing else.  From a
+    full MXU tile of stacked rows on (levels 6 to 9 of Criteo) every field is
+    what the parent of PR 35 reckoned (the formulas as they stood there),
+    with ``lanes_a_feature`` 256 and ``regs_a_broadcast`` 2 beside them.
+    Below it the kernel packs the codes four a word
+    (``regs_a_broadcast`` 8): HIGGS's 28 features are seven whole words, so
+    its block is what it was, and Criteo's grows by the 68th slot alone;
+    both ask five blocks more of VMEM for the packed kernel's stack."""
     from rabit_tpu.ops import boost
 
     for d in range(last + 1):
         built = 2 ** d if d < 5 else 2 ** (d - 1)
         m_pad = max(8, 2 * built)
-        acc = m_pad * F * 256 * 4
-        vmem = acc + 2 * 4 * 1024 * (128 + 4 * 128) + boost.VMEM_STACK
+        packed = d <= 5
+        acc = m_pad * (-(-F // 4) * 4 if packed else F) * 256 * 4
+        vmem = acc + 2 * 4 * 1024 * (128 + 4 * 128) + boost.VMEM_STACK + (
+            5 * acc if packed else 0)
         assert boost.hist_plan(F, 256, d, 1024) == boost.HistPlan(
             level=d, nodes_derived=2 ** d - built, m_pad=m_pad,
             acc_block_bytes=acc, vmem_bytes=vmem, tile_feats=F, feat_tiles=1,
-            lanes_a_feature=256)
+            lanes_a_feature=256, regs_a_broadcast=8 if packed else 2)
+    # HIGGS, level 5: the block's 917,504 bytes as they were, 19,136,512 asked
+    # (14,548,992 + five blocks)
     assert boost.hist_plan(28, 256, 5, 1024)[:7] == (
-        5, 16, 32, 917504, 14548992, 28, 1)
+        5, 16, 32, 917504, 19136512, 28, 1)
+    # Criteo, level 5: 32 x 68 x 256 x 4 = 2,228,224 bytes (2,195,456 at 67
+    # slots), 27,000,832 asked; level 7, not packed, is what it was
+    assert boost.hist_plan(67, 256, 5, 1024)[:7] == (
+        5, 16, 32, 2228224, 27000832, 67, 1)
     assert boost.hist_plan(67, 256, 7, 1024)[:7] == (
         7, 64, 128, 8781824, 22413312, 67, 1)
+
+
+def _kernel_jaxpr(fn, *shapes):
+    """The jaxpr of the (last) Pallas kernel that ``fn`` traces to."""
+    calls = [e for e in jax.make_jaxpr(fn)(*shapes).jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    return calls[-1].params["jaxpr"]
+
+
+def _count_eqns(jaxpr, pick, into=None):
+    """Equations of ``jaxpr`` and of every jaxpr nested in it, by ``pick``'s
+    name for them (None: not counted)."""
+    import collections
+
+    into = collections.Counter() if into is None else into
+    for e in jaxpr.eqns:
+        name = pick(e)
+        if name:
+            into[name] += 1
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _count_eqns(sub, pick, into)
+    return into
+
+
+def _build_eqn(e):
+    """roll, dot_general, and the lane broadcast of a column of packed
+    words: an ``and`` of a one-lane operand with a register-wide mask."""
+    name = e.primitive.name
+    if name in ("roll", "dot_general"):
+        return name
+    if name == "and" and e.outvars[0].aval.shape[-1:] == (128,) and any(
+            getattr(v.aval, "shape", ())[-1:] == (1,) for v in e.invars):
+        return "lane_broadcast"
+    return None
+
+
+@pytest.mark.parametrize("d", [0, 5, 7])
+def test_the_epsilon_kernels_are_the_parent_of_pr_37s(d):
+    """The control: at Epsilon's shape (F 2,000 x 64 bins, 391 row blocks)
+    the packed build was there already, and PR 37, which packs at every
+    width, changes nothing of it.  ``hist_plan`` is field for field what it
+    was at levels 0, 5 and 7, and the traced tile kernel has the parent's
+    equations: a tile of 128 features is four matmul groups of eight words,
+    so 3 rolls a block, 4 ``dot_general`` and 64 lane broadcasts (one a
+    word, serving two registers each: ``regs_a_broadcast`` 2) — counted off
+    the parent's jaxpr (commit 54925f3) and off this tree's alike, and the
+    whole jaxprs' digests (tests/test_round_jaxpr.py's own) are equal
+    too."""
+    from rabit_tpu.ops import boost
+    from tests.test_round_jaxpr import digest
+
+    nb, R, F, B = 391, 1024, 2000, 64
+    built = 2 ** d if d < 5 else 2 ** (d - 1)
+    m_pad = max(8, 2 * built)
+    acc = m_pad * 128 * 64 * 4
+    plan = boost.hist_plan(F, B, d, R)
+    assert plan == boost.HistPlan(
+        level=d, nodes_derived=2 ** d - built, m_pad=m_pad,
+        acc_block_bytes=acc,
+        vmem_bytes=2 * acc + 2 * 4 * R * 4 * 128 + boost.VMEM_STACK,
+        tile_feats=128, feat_tiles=16, lanes_a_feature=64, regs_a_broadcast=2)
+    assert plan.packed
+    sds = jax.ShapeDtypeStruct
+    rows = lambda dt: sds((nb, R, 1), dt)
+    if d == 0:
+        fn = functools.partial(boost.hist_level0.__wrapped__, n_bins=B)
+        shapes = [sds((nb, R, F), jnp.int32), rows(jnp.float32),
+                  rows(jnp.float32)]
+    else:
+        fn = functools.partial(boost.hist_level.__wrapped__, depth=d, n_bins=B)
+        tab = sds((2 ** (d - 1),), jnp.int32)
+        shapes = [sds((nb, R, F), jnp.int32), rows(jnp.int32),
+                  rows(jnp.float32), rows(jnp.float32), tab, tab] + (
+                      [tab] if plan.nodes_derived else [])
+    assert _count_eqns(_kernel_jaxpr(fn, *shapes), _build_eqn) == {
+        "roll": 3, "dot_general": 4, "lane_broadcast": 64}
+    # recorded on the parent of PR 37 (54925f3), this file's shapes
+    assert digest(fn, *shapes) == {0: "fc67337e22ece3c0", 5: "3380c8a958a19bec",
+                                   7: "54a9c6e195654f00"}[d]
+
+
+def test_a_register_count_of_the_packed_build_at_256_bins():
+    """At HIGGS's shape and 256 bins the level kernel rolls the block three
+    times, contracts its seven words of four codes in two groups and
+    broadcasts a word once a BYTE in the
+    jaxpr — 28 ``and`` equations, which Mosaic folds to one broadcast a
+    word, as it did the two of a 64-lane word — each feeding two compares:
+    56 registers for 28 features at 256 lanes, eight a word.  The seven
+    words go in groups of ``GROUP_WORDS``: 4 + 3."""
+    from rabit_tpu.ops import boost
+
+    sds = jax.ShapeDtypeStruct
+    rows = lambda dt: sds((4, 1024, 1), dt)
+    tab = sds((4,), jnp.int32)
+    jaxpr = _kernel_jaxpr(
+        functools.partial(boost.hist_level.__wrapped__, depth=3, n_bins=256),
+        sds((4, 1024, 28), jnp.int32), rows(jnp.int32), rows(jnp.float32),
+        rows(jnp.float32), tab, tab)
+    assert _count_eqns(jaxpr, _build_eqn) == {
+        "roll": 3, "dot_general": -(-7 // boost.GROUP_WORDS),
+        "lane_broadcast": 28}
+    def register(e):
+        """A compare of two register-wide operands: one of the indicator."""
+        if e.primitive.name == "eq" and all(
+                v.aval.shape == (1024, 128) for v in e.invars):
+            return "register"
+
+    assert _count_eqns(jaxpr, register) == {"register": 56}
 
 
 @pytest.mark.parametrize("F,bins,depth,tiles",
@@ -787,7 +979,11 @@ def test_hist_plan_span_and_rows_streamed_gauge(F, bins, depth, tiles):
     features and ONE tile's block, and the gauge counts a sweep a tile a
     level and a routing pass a level.  ``lanes_a_feature`` is the bins' (64
     at Epsilon's 64 bins, 256 at 256) and the gauge
-    ``gbdt_hist_feats_a_register`` 2 and 1.  ``nodes_derived`` is 0 up to level
+    ``gbdt_hist_feats_a_register`` 2 and 1; ``regs_a_broadcast`` is the
+    registers one lane broadcast serves — 2 at Epsilon's 64 lanes a feature,
+    at 256 bins 8 where the level packs the codes (below a full MXU tile of
+    stacked rows: up to level 5) and 2 from level 6 on — and the gauge
+    ``gbdt_hist_regs_a_broadcast`` the root's: 2 and 8.  ``nodes_derived`` is 0 up to level
     4 and half the level's nodes from level 5 on, and the gauge
     ``gbdt_hist_nodes_derived_per_round`` their sum: 16 at the HIGGS shape,
     16 + 32 + 64 at depth 8."""
@@ -810,6 +1006,8 @@ def test_hist_plan_span_and_rows_streamed_gauge(F, bins, depth, tiles):
     # two features a 128-lane register at 64 bins, one feature two at 256
     feats = obs.get_registry().gauge("gbdt_hist_feats_a_register")
     assert feats.value == {64: 2, 256: 1}[bins]
+    regs = obs.get_registry().gauge("gbdt_hist_regs_a_broadcast")
+    assert regs.value == {64: 2, 256: 8}[bins]
     spans = [e.fields for e in obs.get_recorder().snapshot()
              if e.ts >= t0 and e.kind == "span"
              and e.fields.get("name") == "gbdt.hist_plan"]
@@ -822,6 +1020,8 @@ def test_hist_plan_span_and_rows_streamed_gauge(F, bins, depth, tiles):
             plan.acc_block_bytes, plan.vmem_bytes)
         assert (s["feat_tiles"], s["tile_feats"]) == (tiles, min(F, 128))
         assert s["lanes_a_feature"] == bins == plan.lanes_a_feature
+        assert s["regs_a_broadcast"] == plan.regs_a_broadcast == (
+            2 if bins == 64 or s["level"] >= 6 else 8)
         assert s["nodes_derived"] == (2 ** (s["level"] - 1)
                                       if s["level"] >= 5 else 0)
         assert s["nodes_built"] + s["nodes_derived"] == 2 ** s["level"]

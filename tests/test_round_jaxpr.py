@@ -1,14 +1,18 @@
 """The three rounds the benchmark's accepted cells run, as jaxprs, at the
 HIGGS and the Criteo shape.
 
-Levels 0 to 4 are the program they were before any level was derived: a
-depth-5 round at the HIGGS shape (every node of every level built) traces
-to the jaxpr it traced to on the parent of PR 32 (commit 335edd8), character
-for character — the ``higgs-d5`` digests, recorded there.  From level 5 on
-``ops.boost.hist_plan`` builds one child a parent and derives its sibling
-(PR 32), so the six whole rounds were re-recorded on PR 32's tree; before,
-they were the parent of PR 31's (934b79c: one tile of features is the
-program it was before the kernels walked features in tiles).
+All nine were re-recorded on PR 37's tree: below a full MXU tile of stacked
+rows (levels 0 to 5) the kernels now pack the codes four a word at 256 bins
+too, one lane broadcast for eight registers (``ops.boost._packed``), and
+take the codes in a block one 128-lane tile wide; levels 6 and 7 of the
+Criteo shape are the kernels they were.  Before, levels 0 to 4 (the
+``higgs-d5`` digests, a depth-5 round at the HIGGS shape with every node of
+every level built) had been the parent of PR 32's (commit 335edd8),
+character for character, and the six whole rounds PR 32's tree (from level
+5 on ``ops.boost.hist_plan`` builds one child a parent and derives its
+sibling).  The round at the Epsilon shape, which packed already, is held
+to PR 37's parent in tests/test_gbdt.py
+(``test_the_epsilon_kernels_are_the_parent_of_pr_37s``).
 
 The digests are of ``str(jax.make_jaxpr(...))`` with addresses stripped, by
 this file's own ``digest``; tracing needs shapes only, so the real row
@@ -35,16 +39,16 @@ from rabit_tpu.parallel import create_mesh
 SHAPES = {"higgs": (2_625_000, 28, 6), "criteo": (2_621_440, 67, 8),
           "higgs-d5": (2_625_000, 28, 5)}
 WANT = {
-    ("higgs", "fused"): "3f3c80735c0daa3f",
-    ("higgs", "hybrid"): "ef40f4892ec7bab6",
-    ("higgs", "dp_fused"): "797a5f6d6d9030e5",
-    ("criteo", "fused"): "a9d2175949153e23",
-    ("criteo", "hybrid"): "0ad9a7e8afc1b35f",
-    ("criteo", "dp_fused"): "59f0703b1f50c556",
-    # levels 0-4 alone: the parent's, recorded on 335edd8
-    ("higgs-d5", "fused"): "db3f2cfb968b83b9",
-    ("higgs-d5", "hybrid"): "ffe80bf26b7e912b",
-    ("higgs-d5", "dp_fused"): "ff079d267d88a086",
+    ("higgs", "fused"): "7ca445915a49e1d2",
+    ("higgs", "hybrid"): "ea8fedafbec8e0ef",
+    ("higgs", "dp_fused"): "4f038790436455c1",
+    ("criteo", "fused"): "a94cc66df5b20306",
+    ("criteo", "hybrid"): "09bd272c7c645eb7",
+    ("criteo", "dp_fused"): "5bd484934e5769a4",
+    # levels 0-4 alone
+    ("higgs-d5", "fused"): "152bd5e782d1855d",
+    ("higgs-d5", "hybrid"): "ed8e1ee0100078f9",
+    ("higgs-d5", "dp_fused"): "1ef785c9d0fd6356",
 }
 
 

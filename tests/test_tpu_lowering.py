@@ -207,11 +207,12 @@ def test_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d, i8):
 # depth 8.  Levels 5 to 7 build one child a parent: 16, 32 and 64 nodes, the
 # stacked gradient matrix of levels 4 to 6 (half, one and two MXU tiles), an
 # accumulator block of 2.1, 4.2 and 8.4 MiB where every node built took 4.2,
-# 8.4 and 16.75.
+# 8.4 and 16.75.  Below a full tile of stacked rows (up to level 5) the
+# kernel packs the codes four a word: 68 feature slots for the 67.
 NB_CRITEO, F_CRITEO = 2560, 67
 
 
-@pytest.mark.parametrize("d", (5, 6, 7, 8,
+@pytest.mark.parametrize("d", (3, 5, 6, 7, 8,
                                pytest.param(9, marks=pytest.mark.slow)))
 def test_criteo_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d):
     """What ``hist_plan`` lets through, the chip's compiler takes, with the
@@ -221,19 +222,30 @@ def test_criteo_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d):
     refuses for the kernel on its own, here and on the chip alike ("Scoped
     allocation with size 21.75M and limit 16.00M"; PR 27), and level 9
     (33.5 MiB, 46.5 asked; a 54 s compile, ``-m slow``) is the deepest the
-    plan lets through at this width."""
+    plan lets through at this width.  Levels 3 and 5 (32 and 64 stacked
+    rows) pack the codes four a word, eight registers a lane broadcast:
+    a block of 68 feature slots, and five blocks more asked for the stack
+    on which every matmul group's result lives at once (level 5: 25.8 MiB
+    for a 2.1 MiB block, where the default's 16 was refused: "Scoped
+    allocation with size 19.03M"; PR 37)."""
     plan = boost.hist_plan(F_CRITEO, B, d, R)
-    assert plan.nodes_built == plan.nodes_derived == 1 << (d - 1)
-    assert plan.acc_block_bytes == (1 << d) * F_CRITEO * B * 4
-    assert plan.vmem_bytes == plan.acc_block_bytes + (5 << 20) + boost.VMEM_STACK
+    built = 1 << (d - 1 if d >= 5 else d)
+    assert (plan.nodes_built, plan.nodes_derived) == (built, (1 << d) - built)
+    assert plan.packed == (d <= 5) and plan.regs_a_broadcast == (8 if d <= 5 else 2)
+    slots = 68 if plan.packed else F_CRITEO
+    assert plan.acc_block_bytes == 2 * built * slots * B * 4
+    assert plan.vmem_bytes == ((6 if plan.packed else 1) * plan.acc_block_bytes
+                               + (5 << 20) + boost.VMEM_STACK)
     xb3, g3, node3 = _blocked(one_chip, NB_CRITEO, F_CRITEO)
     c = _compile(functools.partial(boost.hist_level, depth=d, n_bins=B),
                  xb3, node3, g3, g3,
                  *_tables(d, lambda n: _sds((n,), jnp.int32, one_chip)))
     text = c.as_text()
     assert "tpu_custom_call" in text
+    # the codes go in as they are: no copy of them padded to the block's lanes
+    assert f"s32[{NB_CRITEO},{R},128]" not in text
     assert (str(plan.vmem_bytes) in text) == (plan.vmem_bytes > boost.VMEM_DEFAULT)
-    assert (plan.vmem_bytes > boost.VMEM_DEFAULT) == (d >= 6)
+    assert (plan.vmem_bytes > boost.VMEM_DEFAULT) == (d >= 3)
 
 
 # benchmark/configs/epsilon-400k.json: 400,000 rows (391 row blocks) x 2,000
@@ -275,15 +287,9 @@ def test_epsilon_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d):
         assert plan.vmem_bytes == 20 << 20
 
 
-@pytest.mark.parametrize("f,bins,d", ((28, 64, 0), (27, 64, 5), (100, 33, 3)))
-def test_one_tile_kernel_at_up_to_64_bins_compiles_for_v5e(
-        one_chip, no_compile_cache, f, bins, d):
-    """The one-block kernels at 64 lanes a feature, which no cell runs
-    (HIGGS's width at LightGBM's ``max_bin`` 63, an odd count, fewer bins
-    than lanes): the codes go in padded to one 128-lane tile and the kernel
-    packs them four a word with whole-register rolls."""
+def _compile_one_tile(one_chip, f, bins, d):
+    """A one-block level kernel over eight row blocks of ``f`` features."""
     plan = boost.hist_plan(f, bins, d, R)
-    assert (plan.feat_tiles, plan.lanes_a_feature) == (1, 64)
     xb3, g3, node3 = _blocked(one_chip, 8, f)
     if d == 0:
         c = _compile(functools.partial(boost.hist_level0, n_bins=bins),
@@ -293,6 +299,34 @@ def test_one_tile_kernel_at_up_to_64_bins_compiles_for_v5e(
         c = _compile(functools.partial(boost.hist_level, depth=d, n_bins=bins),
                      xb3, node3, g3, g3, *(tab,) * (3 if plan.nodes_derived else 2))
     assert "tpu_custom_call" in c.as_text()
+    return plan
+
+
+@pytest.mark.parametrize("f,bins,d", ((28, 64, 0), (27, 64, 5), (100, 33, 3)))
+def test_one_tile_kernel_at_up_to_64_bins_compiles_for_v5e(
+        one_chip, no_compile_cache, f, bins, d):
+    """The one-block kernels at 64 lanes a feature, which no cell runs
+    (HIGGS's width at LightGBM's ``max_bin`` 63, an odd count, fewer bins
+    than lanes): the kernel packs the codes four a word with whole-register
+    rolls; the block is one 128-lane tile over the narrower matrix, no
+    padded copy."""
+    plan = _compile_one_tile(one_chip, f, bins, d)
+    assert (plan.feat_tiles, plan.lanes_a_feature) == (1, 64)
+    assert plan.packed and plan.regs_a_broadcast == 2
+
+
+@pytest.mark.parametrize("f,bins,d", ((28, 128, 0), (27, 100, 5), (67, 65, 3)))
+def test_one_tile_kernel_at_128_lanes_a_feature_compiles_for_v5e(
+        one_chip, no_compile_cache, f, bins, d):
+    """The one-block kernels at 65 to 128 bins, which no cell runs: a
+    feature takes one register, the block is one 128-lane tile over the
+    narrower matrix, its codes packed four a word, and one lane broadcast
+    serves four registers (HIGGS's width at 128 bins, an odd count below
+    the register's lanes, Criteo's width at the fewest bins that take a
+    whole register)."""
+    plan = _compile_one_tile(one_chip, f, bins, d)
+    assert (plan.feat_tiles, plan.lanes_a_feature) == (1, 128)
+    assert plan.packed and plan.regs_a_broadcast == 4
 
 
 def test_route_level_compiles_for_v5e(one_chip, no_compile_cache):
