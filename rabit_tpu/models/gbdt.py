@@ -434,7 +434,8 @@ def train_round_fused(
     When the round is lowered, each level leaves one ``gbdt.hist_plan`` span
     with what ``ops.boost.hist_plan`` reckoned for its kernel
     (``nodes_built``, ``nodes_derived``, ``lanes_a_feature``,
-    ``regs_a_broadcast``), the gauge
+    ``regs_a_broadcast``, ``mxu_holds``: ``"indicator"`` or, from a full
+    MXU tile of stacked rows on, ``"gradients"``), the gauge
     ``gbdt_hist_rows_streamed_per_round`` takes rows x (tile sweeps of every
     level + routing passes), ``gbdt_hist_nodes_derived_per_round`` the
     nodes a round reads off a subtraction (16 at depth 6, 112 at depth 8),
@@ -442,7 +443,8 @@ def train_round_fused(
     register of the indicator (2 at up to 64 bins, else 1), and
     ``gbdt_hist_regs_a_broadcast`` the registers one lane broadcast of a
     word of four packed codes serves (2 at up to 64 bins, 4 at up to 128, 8
-    at 256).
+    at 256), and ``gbdt_hist_levels_gradients_held`` the levels whose kernel
+    holds the gradient planes in the MXU (2 at depth 8, 0 at depth 6).
 
     ``xb3`` is the pre-blocked quantized matrix from ``ops.boost.block_rows``
     (built once per fit).  ``combine`` is the histogram allreduce hook
@@ -495,10 +497,12 @@ def train_round_fused(
     feats = [jnp.zeros(max_nodes, jnp.int32).at[:1].set(feat)]
     thrs = [jnp.zeros(max_nodes, jnp.int32).at[:1].set(thr)]
     node3 = jnp.zeros_like(g3, shape=g3.shape, dtype=jnp.int32)
-    nodes_derived = 0
+    nodes_derived = held = 0
     for d in range(1, cfg.depth):
-        plan = boost.hist_plan(xb3.shape[2], cfg.n_bins, d, block)
+        plan = boost.hist_plan(xb3.shape[2], cfg.n_bins, d, block,
+                               mxu_i8=cfg.mxu_i8, r_split=cfg.r_split)
         nodes_derived += plan.nodes_derived
+        held += plan.mxu_holds == "gradients"
         level = functools.partial(
             boost.hist_level, xb3, node3, g3, h3, feat, thr, depth=d,
             n_bins=cfg.n_bins, interpret=interpret, mxu_i8=cfg.mxu_i8,
@@ -510,6 +514,7 @@ def train_round_fused(
                 feat_tiles=plan.feat_tiles, tile_feats=plan.tile_feats,
                 lanes_a_feature=plan.lanes_a_feature,
                 regs_a_broadcast=plan.regs_a_broadcast,
+                mxu_holds=plan.mxu_holds,
                 acc_block_bytes=plan.acc_block_bytes,
                 vmem_bytes=plan.vmem_bytes, missing=cfg.missing):
             if plan.nodes_derived:
@@ -540,6 +545,7 @@ def train_round_fused(
         max(1, 128 // root.lanes_a_feature))
     obs.get_registry().gauge("gbdt_hist_regs_a_broadcast").set(
         root.regs_a_broadcast)
+    obs.get_registry().gauge("gbdt_hist_levels_gradients_held").set(held)
     # Leaf (g, h) masses come straight off the final combined histogram
     # (split_child_masses) — already globally reduced, so no leaf collective
     # and no histogram work in the last row pass (depth collectives per

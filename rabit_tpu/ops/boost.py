@@ -27,11 +27,17 @@ stacked rows is the lane broadcast of a code column, so there — where the
 codes fit a byte (up to 256 bins); at 64 lanes a feature at every level —
 the kernel packs the block's codes four a word first and ONE broadcast
 serves four features: two registers at 64 lanes a feature, four at 128,
-eight at 256 (``_accum``, ``_packed``, ``HistPlan.regs_a_broadcast``).  From
-a full tile on the MXU's streaming hides the build, and at 128 and 256
-lanes a feature a code column is broadcast a feature, as it always was.
-The rule reads ``n_bins`` and the level's stacked rows alone; no option
-chooses.
+eight at 256 (``_accum``, ``_packed``, ``HistPlan.regs_a_broadcast``).  There
+the MXU holds the indicator, a 128 x 128 tile of it loaded for every
+128-row tile of stacked gradient matrix streamed through it.  From a full
+tile of stacked rows on the MXU holds the gradient planes instead and the
+indicator, built transposed — a feature's bins down the sublanes, one
+sublane broadcast of a row of codes for all its registers — streams
+through them (``_holds_gradients``, ``_accum_held``): the same work of the
+MXU for a seventh of the tile loads at Criteo's level 7, its feature groups
+a loop where the unrolled grid step would outgrow the instruction memory
+(``_groups_a_body``).  The rules
+read ``n_bins`` and the level's stacked rows alone; no option chooses.
 
 ``hist_plan`` reckons what a level asks of the chip from the shape alone.
 The stacked gradient matrix has 4 rows a node built (g and h, hi and lo
@@ -103,8 +109,27 @@ def _packed(n_bins: int, m_rows: int) -> bool:
     kernels alone, a code column a feature | packed: 46.8 | 35.2 ms at 16
     stacked rows, 46.9 | 36.7 at 32, 48.3 | 46.2 at 64, 70.5 | 78.4 at 128,
     150.2 | 158.3 at 256; F = 28: 24.1 | 19.4 at 32, 25.3 | 25.4 at 64 (my
-    chip runs, PR 37)."""
+    chip runs, PR 37).  Where the MXU holds the gradient planes
+    (``_holds_gradients``: from a full tile on) nothing is packed."""
     return n_bins <= 256 and (_bins_eff(n_bins) < 128 or m_rows < MXU_ROWS)
+
+
+def _holds_gradients(m_rows: int, mxu_i8: bool = False, r_split: int = 1) -> bool:
+    """Whether a kernel over ``m_rows`` stacked rows of gradient matrix
+    contracts with the gradient planes as the operand the MXU holds and the
+    indicator, transposed, as the one it streams (``_accum_held``).  The
+    MXU charges some 27 cycles to load a 128 x 128 tile of the operand it
+    holds and one a row streamed through it (PERF.md section 5, PR 37).
+    Holding the indicator, a block of F features x B_eff lanes loads
+    R / 128 x F x B_eff / 128 tiles and streams ``m_rows`` through each
+    (Criteo: 1,072 loads a block for 128 or 256 rows); holding the
+    gradients, it loads R / 128 x ``m_rows`` / 128 tiles a matmul group
+    (8 or 16) and streams the group's features x B_eff rows (2,048: 144
+    loads a block at Criteo's level 7, 72 at level 6).  The work of the MXU is
+    the same; the loads go where the rows streamed fill a tile or more:
+    ``m_rows >= MXU_ROWS``, the line ``_packed`` draws.  The int8 planes and
+    ``r_split > 1`` keep the indicator held: no cell runs them."""
+    return m_rows >= MXU_ROWS and not mxu_i8 and r_split == 1
 
 
 def _block_feats(n_feat: int, packed: bool) -> int:
@@ -148,6 +173,42 @@ def _pick_tile_fc(n_bins: int) -> int:
     return max(1, 2048 // _bins_eff(n_bins))
 
 
+def _pick_held_fc(n_bins: int) -> int:
+    """Features a matmul group where the MXU holds the gradient planes
+    (``_accum_held``): 2,048 rows of indicator a dot at every width — 32
+    features at 64 lanes a feature, 16 at 128, 8 at 256.  Kernels alone
+    (``_dev/time39.py``; my chip runs, PR 39), ms.  F = 67 x 256 bins x
+    2,621,440 rows, every group unrolled, groups of 2 | 4 | 6 | 8 | 12 | 16
+    features: level 6 66.2 | 65.4 | 64.6 | 63.7 | 63.6 | 63.6 (the parent
+    70.5); one group a loop step, 4 | 6 | 8 | 16: level 6 74.7 | 72.2 | 70.9
+    | 68.9, level 7 129.5 | 128.1 | 127.2 | 126.1 (the parent 150.3).  F =
+    2,000 x 64 bins x 400,384 rows, unrolled, groups of 4 | 8 | 16 | 24 |
+    32: level 6 112.6 | 94.3 | 85.2 | 83.3 | 81.2 (the parent 101.7), level
+    7 163.7 | 152.9 | 147.5 | 147.2 | 145.8 (162.2); 64 features and more
+    a group run out of VMEM.  How many groups a loop step takes is
+    ``_groups_a_body``'s."""
+    return max(1, 2048 // _bins_eff(n_bins))
+
+
+#: MXU cycles of work that the groups traced into one step of a held
+#: kernel's loop may hold with one group more: a grid step is about as many
+#: bundles as its MXU has cycles, and past 65,536 bundles, the instruction
+#: memory, it runs slower than its schedule (``_accum_held``; PR 39)
+HELD_STEP_CYCLES = 49_152
+
+
+def _groups_a_body(full: int, group_cycles: int) -> int:
+    """Whole feature groups of ``group_cycles`` MXU cycles each traced into
+    one step of ``_accum_held``'s loop: the most that divides ``full`` and,
+    with one group more (the ragged last group, the routing, the
+    transposes), stays within ``HELD_STEP_CYCLES``; ``full`` (no loop)
+    where the block fits.  Criteo's level 6 (8 groups of 4,096 cycles) and
+    Epsilon's tile (4 of 4,096 or 8,192) are unrolled whole, Criteo's level
+    7 (8 of 8,192) is a loop of two steps of four groups."""
+    return max(k for k in range(1, full + 1) if full % k == 0 and (
+        k == 1 or (k + 1) * group_cycles <= HELD_STEP_CYCLES))
+
+
 #: features of one tile of blocked codes (its 128 lanes): up to here a level
 #: is one block, beyond it the kernels walk the features a tile at a time
 TILE_FEATS = 128
@@ -170,7 +231,17 @@ class HistPlan(NamedTuple):
     is *derived*: one child of every parent is accumulated and its sibling
     is the parent's histogram less the built one's (``derive_siblings``), so
     level d runs the matmul, the accumulator block and the VMEM of level
-    d - 1."""
+    d - 1.
+
+    ``mxu_holds`` says which operand the MXU holds: ``"indicator"`` below a
+    full tile of stacked rows (``m_rows < MXU_ROWS``: levels 0 to 5), the
+    gradient matrix streamed through its tiles, and ``"gradients"`` from a
+    full tile on (``_holds_gradients``: levels 6 and 7 of Criteo and
+    Epsilon), the indicator built transposed and streamed through the
+    gradient planes; the accumulator block is then ``(F * B_eff, m_pad)``,
+    a feature's bins down the sublanes, and ``regs_a_broadcast`` counts the
+    registers one SUBLANE broadcast of a row of codes serves (``B_eff /
+    8``: 32 at 256 bins, 8 at 64)."""
 
     level: int
     nodes_derived: int    # nodes read off parent - sibling: half a derived level, else 0
@@ -180,7 +251,8 @@ class HistPlan(NamedTuple):
     tile_feats: int       # features a tile: all of F where one tile holds them
     feat_tiles: int       # tiles a level; each sweeps every row block
     lanes_a_feature: int  # 64 at up to 64 bins (two features a register), else 128, 256, ...
-    regs_a_broadcast: int  # registers of the indicator one lane broadcast serves
+    regs_a_broadcast: int  # registers of the indicator one broadcast of codes serves
+    mxu_holds: str = "indicator"  # the operand the MXU holds: or "gradients"
 
     @property
     def nodes_built(self) -> int:
@@ -200,10 +272,12 @@ class HistPlan(NamedTuple):
         """Whether the level's kernel packs the codes four a word
         (``_packed``): then one lane broadcast serves four features' registers
         (2 at 64 lanes a feature, 4 at 128, 8 at 256), else one feature's."""
-        return self.regs_a_broadcast * 128 == 4 * self.lanes_a_feature
+        return (self.mxu_holds == "indicator"
+                and self.regs_a_broadcast * 128 == 4 * self.lanes_a_feature)
 
 
-def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int) -> HistPlan:
+def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int, *,
+              mxu_i8: bool = False, r_split: int = 1) -> HistPlan:
     """A level's feature tiles, one tile's accumulator block and the scoped
     VMEM its kernel takes, from the shape alone; ValueError for a shape no
     tiling holds.
@@ -235,19 +309,34 @@ def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int) -> HistPlan
     From the level whose every node, built, stacks ``MXU_ROWS`` rows of
     gradient matrix (4 * 2**level: level 5 at any width) half the nodes are
     built and half derived; below it M is padded to the tile anyway and
-    every node is built."""
+    every node is built.
+
+    From the level whose BUILT nodes stack a full MXU tile (level 6, at
+    any width) the MXU holds the gradient planes (``_holds_gradients``;
+    ``mxu_i8`` and ``r_split > 1`` keep the indicator held): the block is
+    ``(tile_feats * B_eff, m_pad)``, its m_pad lanes counted whole 128-lane
+    registers (level 6's 64 take the VMEM of 128), codes come in at 128
+    lanes as where they are packed, and the kernel transposes them into a
+    ``[128, block_rows]`` int32 scratch, counted too.  (F = 67, level 7:
+    8.4 MiB of block, 5 of row blocks, 0.5 of scratch, 21.9 MiB with the
+    stack.)"""
     derived = 2 ** (level - 1) if level >= 1 and 4 * 2 ** level >= MXU_ROWS else 0
     m_pad = _round_up(2 * (2 ** level - derived), 8)
     tile = min(n_feat, TILE_FEATS)
     tiles = -(-n_feat // tile)
     be = _bins_eff(n_bins)
-    packed = _packed(n_bins, 2 * m_pad)
-    acc = m_pad * _block_feats(tile, packed) * be * 4
+    held = _holds_gradients(2 * m_pad, mxu_i8, r_split)
+    packed = not held and _packed(n_bins, 2 * m_pad)
+    if held:
+        acc = tile * be * _round_up(m_pad, 128) * 4
+    else:
+        acc = m_pad * _block_feats(tile, packed) * be * 4
     if tiles == 1:
         blocks = acc + 2 * 4 * block_rows * (_round_up(n_feat, 128) + 4 * 128)
     else:
         blocks = 2 * acc + 2 * 4 * block_rows * (tile + 3 * 128)
-    need = blocks + VMEM_STACK + (5 * acc if packed and be >= 128 else 0)
+    need = blocks + VMEM_STACK + (5 * acc if packed and be >= 128 else 0) + (
+        4 * block_rows * _round_up(tile, 128) if held else 0)
     if need > VMEM_MOST:
         raise ValueError(
             f"hist_plan: level {level} of F={n_feat} features x {n_bins} bins "
@@ -255,6 +344,9 @@ def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int) -> HistPlan
             f"of one {tile}-feature tile is {acc} bytes and the kernel needs "
             f"{need} of {VMEM_MOST} bytes of VMEM (a depth of {level + 1} is "
             "one level too many)")
+    if held:
+        return HistPlan(level, derived, m_pad, acc, need, tile, tiles, be,
+                        be // 8, "gradients")
     return HistPlan(level, derived, m_pad, acc, need, tile, tiles, be,
                     (4 if packed else 1) * be // 128)
 
@@ -269,7 +361,13 @@ def _encode_bf16(L):
     1,000,000 rows x 28 on an older chip).  From level 5 on one child a
     parent is built (``hist_plan``), so level 5 stacks half a tile again,
     level 6 one and level 7 two (``HistPlan.m_tiles``).  The result splits
-    back and sums in f32, bitwise identical to the two-matmul form."""
+    back and sums in f32, bitwise identical to the two-matmul form.
+
+    ``l2`` is ``[R, 2 * m]``, rows on sublanes.  Up to level 5 the MXU holds
+    the indicator and these planes stream through it, M rows a tile; from
+    level 6 on it holds these planes themselves (``_accum_held``: R / 128 x
+    2m / 128 tiles a block) and ``decode`` is not used: the planes then lie
+    along the result's lanes."""
     lhi = L.astype(jnp.bfloat16)
     llo = (L - lhi.astype(jnp.float32)).astype(jnp.bfloat16)
     l2 = jnp.concatenate([lhi, llo], axis=1)
@@ -311,11 +409,79 @@ def _encode_i8(L):
     return l2, jnp.int8, jnp.int32, decode
 
 
+def _accum_held(xb_blk, L, out_ref, codes_t, *, n_bins: int, n_feat: int,
+                fc: int, feats_left=None):
+    """out_ref[f*Beff+b, m] += sum_r [xb_blk[r, f] == b] * L[r, m]: the
+    histogram transposed, with the hi/lo gradient planes ``[R, 2m]`` as the
+    operand the MXU holds and the indicator, built transposed, as the one
+    it streams (``_holds_gradients``).  The block's codes ``[R, lanes]`` go
+    through the XLU once into ``codes_t`` ``[lanes, R]``; a feature's row of
+    codes, broadcast down the sublanes, is compared with a sublane iota of
+    its bins, so one sublane broadcast serves all ``B_eff / 8`` registers of
+    the feature (32 at 256 bins) and no lane broadcast is paid.  A group of
+    ``fc`` features stacks ``fc * B_eff`` rows of indicator on the sublanes
+    and is one ``jnp.dot`` against the planes; the planes' two halves are
+    summed per block, as ``_encode_bf16``'s decode does.  Natural feature
+    order: no codes packed.  ``feats_left``: as ``_accum``'s.
+
+    The whole groups are traced one after another, as many a step of a
+    ``fori_loop`` as ``_groups_a_body`` allows (all of them, and no loop,
+    where the block's work fits), the ragged last group after them.  A
+    grid step is about as many bundles as the MXU has cycles of work in
+    it, and past 65,536 bundles, the instruction memory's size, a step
+    runs slower than its schedule: Criteo's level 7 every group unrolled
+    read 122 ms at 64,097 bundles and 146–157 at 65,995–72,968, by group
+    size and missing code (PERF.md section 5, PR 39).  A loop of one group
+    a step loses the overlap of one group's build with the last one's
+    matmuls at every back edge: 127.2 ms at level 7, and 70.9 at level 6
+    against 63.7 unrolled (my chip runs, PR 39)."""
+    be = _bins_eff(n_bins)
+    r, m = L.shape
+    l2 = _encode_bf16(L)[0]
+    codes_t[...] = xb_blk.T
+    bins = lax.broadcasted_iota(jnp.int32, (be, r), 0)
+
+    def group(f0, k):
+        onehot = jnp.concatenate(
+            [codes_t[pl.ds(f0 + j, 1), :] == bins for j in range(k)],
+            axis=0).astype(jnp.bfloat16)
+        acc2 = jnp.dot(onehot, l2, preferred_element_type=jnp.float32)
+        at = f0 * be if isinstance(f0, int) else pl.multiple_of(f0 * be, be)
+        out_ref[pl.ds(at, k * be), :] += acc2[:, :m] + acc2[:, m:]
+
+    def live(f0, k):
+        if feats_left is None:
+            group(f0, k)
+        else:
+            pl.when(f0 < feats_left)(functools.partial(group, f0, k))
+
+    full, last = divmod(n_feat, fc)
+    if full:
+        # MXU cycles a group: its indicator rows through every K-tile of
+        # rows and N-tile of planes, four MXUs
+        per = _groups_a_body(full, fc * be * (r // 128) * -(-2 * m // 128) // 4)
+
+        def step(i, carry):
+            for j in range(per):
+                live((i * per + j) * fc, fc)
+            return carry
+
+        if per == full:
+            step(0, None)
+        else:
+            lax.fori_loop(0, full // per, step, 0)
+    if last:
+        live(full * fc, last)
+
+
 def _accum(xb_blk, L, out_ref, *, n_bins: int, n_feat: int, fc: int, i8: bool,
-           packed: bool, r_split: int = 1, feats_left=None):
+           packed: bool, r_split: int = 1, feats_left=None, codes_t=None):
     """out_ref[m, f*Beff+b] += sum_r L[r, m] * [xb_blk[r, f] == b], via the
     MXU: the encoded gradient planes are contracted against per-feature-
-    group bin-indicator matrices built in VMEM.  Where the kernel packs the
+    group bin-indicator matrices built in VMEM, the MXU holding the
+    indicator.  Given ``codes_t`` (a ``[lanes, R]`` int32 scratch) the MXU
+    holds the planes instead and ``out_ref`` is the transposed block
+    (``_accum_held``).  Where the kernel packs the
     codes (``packed``: ``_packed``) ``n_feat`` counts the block's feature
     SLOTS, whole words of four, slot 4 * q + b of ``out_ref`` is feature
     b * n_feat / 4 + q (``_cut_lanes`` puts them back in order), and
@@ -336,6 +502,9 @@ def _accum(xb_blk, L, out_ref, *, n_bins: int, n_feat: int, fc: int, i8: bool,
     scalar of the grid.  A group that starts at or past it — the ragged
     last tile's tail, whose code lanes hold nothing — is skipped; lanes
     past it inside a group fall into columns the wrapper cuts off."""
+    if codes_t is not None:
+        return _accum_held(xb_blk, L, out_ref, codes_t, n_bins=n_bins,
+                           n_feat=n_feat, fc=fc, feats_left=feats_left)
     be = _bins_eff(n_bins)
     l2, onehot_dtype, acc_dtype, decode = (_encode_i8 if i8 else _encode_bf16)(L)
     r = xb_blk.shape[0]
@@ -520,9 +689,11 @@ def _level0_kernel(xb_ref, g_ref, h_ref, out_ref, *, n_bins, n_feat, fc, i8,
 
 def _level_kernel(xb_ref, node_ref, g_ref, h_ref, feat_ref, thr_ref, *refs,
                   n_nodes, n_bins, n_feat, m_pad, p_pad, fc, i8, packed,
-                  r_split=1, missing_code=None):
+                  held=False, r_split=1, missing_code=None):
     """``refs``: at a derived level the built-child table, then the
-    accumulator (``n_nodes`` built nodes) and the routed node ids."""
+    accumulator (``n_nodes`` built nodes), the routed node ids and, where
+    the MXU holds the gradients (``held``), the codes' transposed scratch."""
+    *refs, codes_t = refs if held else (*refs, None)
     *built_ref, out_ref, node_out_ref = refs
 
     @pl.when(pl.program_id(0) == 0)
@@ -536,19 +707,21 @@ def _level_kernel(xb_ref, node_ref, g_ref, h_ref, feat_ref, thr_ref, *refs,
         node, g_ref[0], h_ref[0], n_nodes=n_nodes, m_pad=m_pad,
         built_row=built_ref[0][0:1, :m_pad] if built_ref else None)
     _accum(xb_ref[0], L, out_ref, n_bins=n_bins, n_feat=n_feat, fc=fc, i8=i8,
-           packed=packed, r_split=r_split)
+           packed=packed, r_split=r_split, codes_t=codes_t)
 
 
 # -- one feature tile of a level wider than TILE_FEATS: histogram only ------
 
 
 def _tile_kernel(xb_ref, *refs, n_feat, tile, n_nodes, n_bins, m_pad, fc, i8,
-                 packed, r_split=1):
+                 packed, held=False, r_split=1):
     """Grid (feature tile, row block), the rows innermost: ``out_ref`` is
     this tile's accumulator block, zeroed at its first row block.  The rows
     come routed: ``refs`` is their node ids (not at the root, where every
-    row is at node 0), at a derived level the built-child table, then g, h
-    and the output."""
+    row is at node 0), at a derived level the built-child table, then g, h,
+    the output and, where the MXU holds the gradients (``held``), the
+    codes' transposed scratch."""
+    *refs, codes_t = refs if held else (*refs, None)
     *lead, g_ref, h_ref, out_ref = refs
 
     @pl.when(pl.program_id(1) == 0)
@@ -562,7 +735,7 @@ def _tile_kernel(xb_ref, *refs, n_feat, tile, n_nodes, n_bins, m_pad, fc, i8,
         built_row=lead[1][0:1, :m_pad] if len(lead) > 1 else None)
     _accum(xb_ref[0], L, out_ref, n_bins=n_bins, n_feat=tile, fc=fc, i8=i8,
            packed=packed, r_split=r_split,
-           feats_left=n_feat - pl.program_id(0) * tile)
+           feats_left=n_feat - pl.program_id(0) * tile, codes_t=codes_t)
 
 
 # -- routing-only pass (leaf assignment without histogramming) -------------
@@ -754,35 +927,60 @@ def _cut_lanes(out, n_feat: int, n_bins: int, tile: int, packed: bool):
     return out.reshape(out.shape[0], -1, be)[:, :n_feat, :n_bins]
 
 
+def _held_hists(out, plan: HistPlan, n_feat: int, n_bins: int):
+    """A transposed accumulator ``(features * B_eff, m_pad)`` (the MXU held
+    the gradients: ``_accum_held``) as the ``[nodes built, F, B, 2]`` the
+    caller gets: the pad rows of every feature and the features past F cut
+    off, g and h out of the node lanes, and one transpose (an XLA pass over
+    the level's histogram, outside the kernel)."""
+    n = plan.nodes_built
+    o = out.reshape(-1, plan.lanes_a_feature, plan.m_pad)[:n_feat, :n_bins]
+    return jnp.stack([o[..., :n], o[..., n : 2 * n]], axis=-1).transpose(
+        2, 0, 1, 3)
+
+
 def _hist_tiles(plan: HistPlan, xb3, node3, g3, h3, *, n_bins, interpret,
                 mxu_i8, r_split, name, built=()):
-    """A level wider than one tile: the ``(m_pad, F, B)`` sums of rows that
-    come routed (``node3`` None at the root; ``built`` holds the built-child
-    table of a derived level), a sweep of every row block a feature tile.
-    The kernel's output is whole tiles wide; the ragged last tile's tail is
-    cut off here."""
+    """A level wider than one tile: the ``[nodes built, F, B, 2]`` sums of
+    rows that come routed (``node3`` None at the root; ``built`` holds the
+    built-child table of a derived level), a sweep of every row block a
+    feature tile.  The kernel's output is whole tiles wide (tall, where the
+    MXU holds the gradients); the ragged last tile's tail is cut off
+    here."""
     nb, R, F = xb3.shape
     be = plan.lanes_a_feature
     tile, tiles, m_pad = plan.tile_feats, plan.feat_tiles, plan.m_pad
+    held = plan.mxu_holds == "gradients"
     row = pl.BlockSpec((1, R, 1), lambda t, i: (i, 0, 0))
     rows = [a for a in (node3, g3, h3) if a is not None]
     specs = [row] * len(rows)
     rows[1:1] = built
     specs[1:1] = [pl.BlockSpec(b.shape, lambda t, i: (0, 0)) for b in built]
+    if held:
+        acc, acc_at = (tile * be, m_pad), lambda t, i: (t, 0)
+        fc, shape = _pick_held_fc(n_bins), (tiles * tile * be, m_pad)
+    else:
+        acc, acc_at = (m_pad, tile * be), lambda t, i: (0, t)
+        fc, shape = _pick_tile_fc(n_bins), (m_pad, tiles * tile * be)
     out = pl.pallas_call(
         functools.partial(
             _tile_kernel, n_feat=F, tile=tile, n_nodes=plan.nodes_built,
-            n_bins=n_bins, m_pad=m_pad, fc=_pick_tile_fc(n_bins), i8=mxu_i8,
-            packed=plan.packed, r_split=r_split),
+            n_bins=n_bins, m_pad=m_pad, fc=fc, i8=mxu_i8,
+            packed=plan.packed, held=held, r_split=r_split),
         grid=(tiles, nb),
         in_specs=[pl.BlockSpec((1, R, tile), lambda t, i: (i, 0, t))] + specs,
-        out_specs=pl.BlockSpec((m_pad, tile * be), lambda t, i: (0, t)),
-        out_shape=jax.ShapeDtypeStruct((m_pad, tiles * tile * be), jnp.float32),
+        out_specs=pl.BlockSpec(acc, acc_at),
+        out_shape=jax.ShapeDtypeStruct(shape, jnp.float32),
+        scratch_shapes=[pltpu.VMEM((tile, R), jnp.int32)] if held else (),
         interpret=interpret,
         name=name,
         compiler_params=_vmem_params(plan),
     )(xb3, *rows)
-    return _cut_lanes(out, F, n_bins, tile, plan.packed)
+    if held:
+        return _held_hists(out, plan, F, n_bins)
+    out = _cut_lanes(out, F, n_bins, tile, plan.packed)
+    n = plan.nodes_built
+    return jnp.stack([out[:n], out[n : 2 * n]], axis=-1)
 
 
 @functools.partial(
@@ -795,10 +993,9 @@ def hist_level0(xb3, g3, h3, *, n_bins: int, interpret: bool = False,
     _check_r_split(R, r_split)
     plan = hist_plan(F, n_bins, 0, R)
     if plan.feat_tiles > 1:
-        out = _hist_tiles(plan, xb3, None, g3, h3, n_bins=n_bins,
-                          interpret=interpret, mxu_i8=mxu_i8, r_split=r_split,
-                          name="hist_level0")
-        return jnp.stack([out[0:1], out[1:2]], axis=-1)
+        return _hist_tiles(plan, xb3, None, g3, h3, n_bins=n_bins,
+                           interpret=interpret, mxu_i8=mxu_i8, r_split=r_split,
+                           name="hist_level0")
     codes, slots, lanes, fc = _one_block(F, n_bins, plan.packed)
     out = pl.pallas_call(
         functools.partial(_level0_kernel, n_bins=n_bins, n_feat=slots, fc=fc,
@@ -835,7 +1032,10 @@ def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, *, depth: int,
     the other child is routed and summed nowhere.  The caller gets the
     siblings from the parents' histogram (``derive_siblings``), after its
     collective.  The routed node ids and the kernel's name are a built
-    level's.
+    level's.  From the level whose built nodes stack a full MXU tile (6, at
+    any width) the kernel holds the gradient planes in the MXU and
+    accumulates the histogram transposed (``_accum_held``); the caller gets
+    the same ``[nodes, F, B, 2]``, one XLA transpose of it later.
 
     Wider than ``TILE_FEATS`` features routing and histogram are two
     kernels: ``route_level``'s tiled pass, then a histogram sweep a feature
@@ -849,7 +1049,7 @@ def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, *, depth: int,
     costs the round nothing (PR 27)."""
     nb, R, F = xb3.shape
     _check_r_split(R, r_split)
-    plan = hist_plan(F, n_bins, depth, R)
+    plan = hist_plan(F, n_bins, depth, R, mxu_i8=mxu_i8, r_split=r_split)
     n_nodes, m_pad = plan.nodes_built, plan.m_pad
     n_prev = 2 ** (depth - 1)
     if bool(plan.nodes_derived) != (built_right is not None):
@@ -861,20 +1061,27 @@ def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, *, depth: int,
     if plan.feat_tiles > 1:
         node_out = route_level(xb3, node3, feat, thr, depth=depth,
                                interpret=interpret, missing_code=missing_code)
-        out = _hist_tiles(plan, xb3, node_out, g3, h3, n_bins=n_bins,
-                          interpret=interpret, mxu_i8=mxu_i8, r_split=r_split,
-                          name=f"hist_level_d{depth}", built=built)
-        hist = jnp.stack([out[:n_nodes], out[n_nodes : 2 * n_nodes]], axis=-1)
+        hist = _hist_tiles(plan, xb3, node_out, g3, h3, n_bins=n_bins,
+                           interpret=interpret, mxu_i8=mxu_i8, r_split=r_split,
+                           name=f"hist_level_d{depth}", built=built)
         return hist, node_out
     p_pad = _round_up(n_prev, 128)
-    codes, slots, lanes, fc = _one_block(F, n_bins, plan.packed)
+    held = plan.mxu_holds == "gradients"
+    if held:
+        # the codes a whole 128-lane tile, transposed in the kernel; the
+        # block (F * B_eff, m_pad), a feature's bins down the sublanes
+        codes, slots = _round_up(F, 128), F
+        acc, fc = (F * plan.lanes_a_feature, m_pad), _pick_held_fc(n_bins)
+    else:
+        codes, slots, lanes, fc = _one_block(F, n_bins, plan.packed)
+        acc = (m_pad, lanes)
     featp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(feat)
     thrp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(thr)
     out, node_out = pl.pallas_call(
         functools.partial(
             _level_kernel, n_nodes=n_nodes, n_bins=n_bins, n_feat=slots,
             m_pad=m_pad, p_pad=p_pad, fc=fc, i8=mxu_i8, packed=plan.packed,
-            r_split=r_split, missing_code=missing_code,
+            held=held, r_split=r_split, missing_code=missing_code,
         ),
         grid=(nb,),
         in_specs=[
@@ -883,17 +1090,20 @@ def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, *, depth: int,
             pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
         ] + [pl.BlockSpec(b.shape, lambda i: (0, 0)) for b in built],
         out_specs=[
-            pl.BlockSpec((m_pad, lanes), lambda i: (0, 0)),
+            pl.BlockSpec(acc, lambda i: (0, 0)),
             _blk(R, 1),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m_pad, lanes), jnp.float32),
+            jax.ShapeDtypeStruct(acc, jnp.float32),
             jax.ShapeDtypeStruct((nb, R, 1), jnp.int32),
         ],
+        scratch_shapes=[pltpu.VMEM((codes, R), jnp.int32)] if held else (),
         interpret=interpret,
         name=f"hist_level_d{depth}",
         compiler_params=_vmem_params(plan),
     )(xb3, node3, g3, h3, featp, thrp, *built)
+    if held:
+        return _held_hists(out, plan, F, n_bins), node_out
     out = _cut_lanes(out, F, n_bins, slots, plan.packed)
     hist = jnp.stack([out[:n_nodes], out[n_nodes : 2 * n_nodes]], axis=-1)
     return hist, node_out

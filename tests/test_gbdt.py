@@ -442,9 +442,11 @@ def test_hist_plan():
     (five blocks more).  Criteo (F 67, depth 8): one accumulator block a
     level, over 68 feature slots (whole words) where the level packs (up to
     level 5) and over the 67 features from a full tile of stacked rows on,
-    8.4 MiB at level 7 in two MXU tiles of M, every kernel inside what it
-    may ask for.  The halved block lets levels 8 and 9 through (16.75 and
-    33.5 MiB: depths 9 and 10); level 10 is refused by name."""
+    where the MXU holds the gradient planes and the block is the transposed
+    ``(67 x 256, m_pad)``, its node lanes whole registers (level 6's 64 take
+    128): 8.4 MiB at levels 6 and 7, every kernel inside what it may ask
+    for.  The halved block lets levels 8 and 9 through (16.75 and 33.5 MiB:
+    depths 9 and 10); level 10 is refused by name."""
     from rabit_tpu.ops import boost
 
     assert boost._pick_fc(28, 256, True) == 4 * boost.GROUP_WORDS == 16
@@ -467,7 +469,9 @@ def test_hist_plan():
     for d in range(1, 10):
         p = boost.hist_plan(67, 256, d, 1024)
         assert p.packed == (d <= 5) == (p.m_rows < boost.MXU_ROWS)
-        assert p.acc_block_bytes == p.m_pad * (68 if p.packed else 67) * 256 * 4
+        assert p.mxu_holds == ("indicator" if d <= 5 else "gradients")
+        assert p.acc_block_bytes == (p.m_pad * 68 if p.packed else
+                                     max(p.m_pad, 128) * 67) * 256 * 4
         assert p.acc_block_bytes + (5 << 20) < p.vmem_bytes <= boost.VMEM_MOST
         if d in want:
             assert (p.nodes_built, p.nodes_derived, p.m_pad, p.m_tiles) == want[d]
@@ -490,14 +494,18 @@ def test_hist_plan_tiles_a_wide_matrix():
     128, the last one ragged, and reckons ONE tile's accumulator block,
     counted twice: Epsilon (F 2000, 64 bins at 64 lanes a feature, two
     features a register, depth 8) is sixteen tiles a level, and with one
-    child a parent built from level 5 on a 4 MiB block and 20 MiB asked at
-    level 7 (8 and 28 at 128 lanes a feature, before PR 35; 16 and 44 with
-    every node built besides) — a width whose one-block accumulator nothing
-    holds.  Level 7 is the first to pass Mosaic's default (level 6 takes it
-    whole, 16 MiB).  The halved lanes let levels 8 and 9 through (8 and 16
-    MiB blocks, 28 and 44 MiB: depths 9 and 10); level 10 is refused by
-    name, with the tile's block in the text.  At 65 bins and more a feature
-    takes whole registers and the groups and blocks are what they were."""
+    child a parent built from level 5 on a 4 MiB block and 20.5 MiB asked
+    at level 7 (8 and 28 at 128 lanes a feature, before PR 35; 16 and 44
+    with every node built besides) — a width whose one-block accumulator
+    nothing holds.  From level 6 on the MXU holds the gradient planes: the
+    block is the transposed ``(128 x 64, m_pad)``, its node lanes whole
+    registers (level 6's 64 take 128: 4 MiB, not 2), and the ``[128,
+    1024]`` int32 scratch the codes are transposed into is counted, 0.5
+    MiB; so level 6 is the first to pass Mosaic's default.  The halved
+    lanes let levels 8 and 9 through (8 and 16 MiB blocks, 28.5 and 44.5
+    MiB: depths 9 and 10); level 10 is refused by name, with the tile's
+    block in the text.  At 65 bins and more a feature takes whole registers
+    and the groups and blocks are what they were."""
     from rabit_tpu.ops import boost
 
     assert boost._pick_tile_fc(64) == 2 * boost._pick_tile_fc(128) == 32
@@ -509,20 +517,23 @@ def test_hist_plan_tiles_a_wide_matrix():
         built = 2 ** d if d < 5 else 2 ** (d - 1)
         assert (p.nodes_built, p.nodes_derived) == (built, 2 ** d - built)
         assert p.m_pad == max(8, 2 * built)
-        assert p.acc_block_bytes == p.m_pad * 128 * 64 * 4
+        held = d >= 6
+        assert p.mxu_holds == ("gradients" if held else "indicator")
+        node_lanes = max(p.m_pad, 128) if held else p.m_pad
+        assert p.acc_block_bytes == node_lanes * 128 * 64 * 4
         assert p.vmem_bytes == (2 * p.acc_block_bytes + 2 * 4 * 1024 * 4 * 128
-                                + boost.VMEM_STACK)
+                                + boost.VMEM_STACK + (512 << 10) * held)
         wide = boost.hist_plan(2000, 128, d, 1024) if d < 9 else None
         if wide:
-            assert wide.acc_block_bytes == p.m_pad * 128 * 128 * 4
+            assert wide.acc_block_bytes == node_lanes * 128 * 128 * 4
             assert wide[:3] + wide[5:7] == p[:3] + p[5:7]
-    assert p.acc_block_bytes == 16 << 20 and p.vmem_bytes == 44 << 20
+    assert p.acc_block_bytes == 16 << 20 and p.vmem_bytes == 89 << 19
     p = boost.hist_plan(2000, 64, 7, 1024)
-    assert p.acc_block_bytes == 4 << 20 and p.vmem_bytes == 20 << 20
-    assert boost.hist_plan(2000, 128, 7, 1024)[3:5] == (8 << 20, 28 << 20)
+    assert p.acc_block_bytes == 4 << 20 and p.vmem_bytes == 41 << 19
+    assert boost.hist_plan(2000, 128, 7, 1024)[3:5] == (8 << 20, 57 << 19)
     assert boost.VMEM_DEFAULT < p.vmem_bytes <= boost.VMEM_MOST
-    assert boost.hist_plan(2000, 64, 7, 1024).vmem_bytes > boost.VMEM_DEFAULT
-    assert boost.hist_plan(2000, 64, 6, 1024).vmem_bytes == boost.VMEM_DEFAULT
+    assert boost.hist_plan(2000, 64, 6, 1024)[3:5] == (4 << 20, 41 << 19)
+    assert boost.hist_plan(2000, 64, 5, 1024).vmem_bytes == 14 << 20
     with pytest.raises(ValueError, match=r"level 10 of F=2000 .*128-feature "
                                          r"tile is 33554432 bytes"):
         boost.hist_plan(2000, 64, 10, 1024)
@@ -797,9 +808,14 @@ def test_lanes_a_feature_follow_the_bins(bins, lanes, regs):
     packs the codes four a word, and the registers one lane broadcast
     serves are four features': 2, 4, 8; its groups are whole words of four
     slots, seven at up to 64 bins, ``GROUP_WORDS`` above.  From a full tile
-    on (level 6) the 64-lane kernel still packs and the wider ones
-    broadcast a code column a feature: one register, or two.  Codes wider
-    than a byte are never packed."""
+    on (level 6) the MXU holds the gradient planes at every width: nothing
+    is packed, a feature's bins lie down the sublanes of the transposed
+    block and one sublane broadcast of a row of codes serves its
+    ``lanes / 8`` registers (8, 16, 32), in groups of 32, 16 and 8
+    features (2,048 rows of indicator a dot at every width).  Under
+    ``mxu_i8`` the MXU holds the indicator there as before: the 64-lane kernel packs and the wider ones broadcast a code
+    column a feature, one register or two.  Codes wider than a byte are
+    never packed."""
     from rabit_tpu.ops import boost
 
     assert boost._bins_eff(bins) == lanes
@@ -814,9 +830,15 @@ def test_lanes_a_feature_follow_the_bins(bins, lanes, regs):
     assert boost.hist_plan(99, bins, 3, 1024).acc_block_bytes == p.acc_block_bytes
     full = boost.hist_plan(99, bins, 6, 1024)
     assert full.m_rows == boost.MXU_ROWS
-    assert (full.packed, full.regs_a_broadcast) == (
-        (True, 2) if lanes == 64 else (False, lanes // 128))
-    assert full.acc_block_bytes == full.m_pad * (100 if full.packed else 99) * lanes * 4
+    assert (full.mxu_holds, full.packed, full.regs_a_broadcast) == (
+        "gradients", False, lanes // 8)
+    assert full.acc_block_bytes == 128 * 99 * lanes * 4
+    assert boost._pick_held_fc(bins) == {64: 32, 128: 16, 256: 8}[lanes]
+    i8 = boost.hist_plan(99, bins, 6, 1024, mxu_i8=True)
+    assert (i8.mxu_holds, i8.packed, i8.regs_a_broadcast) == (
+        ("indicator", True, 2) if lanes == 64 else
+        ("indicator", False, lanes // 128))
+    assert i8.acc_block_bytes == i8.m_pad * (100 if i8.packed else 99) * lanes * 4
     wide = boost.hist_plan(100, 257, 3, 1024)
     assert (wide.lanes_a_feature, wide.regs_a_broadcast, wide.packed) == (
         384, 3, False)
@@ -824,37 +846,43 @@ def test_lanes_a_feature_follow_the_bins(bins, lanes, regs):
 
 @pytest.mark.parametrize("F,last", [(28, 5), (67, 9)], ids=["higgs", "criteo"])
 def test_hist_plan_at_256_bins_is_field_for_field_what_it_was(F, last):
-    """What PR 37 moved of the plan at 256 bins, and nothing else.  From a
-    full MXU tile of stacked rows on (levels 6 to 9 of Criteo) every field is
-    what the parent of PR 35 reckoned (the formulas as they stood there),
-    with ``lanes_a_feature`` 256 and ``regs_a_broadcast`` 2 beside them.
-    Below it the kernel packs the codes four a word
-    (``regs_a_broadcast`` 8): HIGGS's 28 features are seven whole words, so
-    its block is what it was, and Criteo's grows by the 68th slot alone;
-    both ask five blocks more of VMEM for the packed kernel's stack."""
+    """What PRs 37 and 39 moved of the plan at 256 bins, and nothing else.
+    Below a full MXU tile of stacked rows the kernel packs the codes four a
+    word (``regs_a_broadcast`` 8): HIGGS's 28 features are seven whole
+    words, so its block is what it was, and Criteo's grows by the 68th slot
+    alone; both ask five blocks more of VMEM for the packed kernel's stack.
+    From a full tile on (levels 6 to 9 of Criteo) the MXU holds the
+    gradient planes (PR 39): the block is ``(F x 256, m_pad)`` with its node
+    lanes whole registers, the row blocks and the stack are what the
+    parent of PR 35 reckoned, the ``[128, 1024]`` int32 scratch of the
+    transposed codes comes on top, and ``regs_a_broadcast`` is 32, a
+    sublane broadcast a feature."""
     from rabit_tpu.ops import boost
 
     for d in range(last + 1):
         built = 2 ** d if d < 5 else 2 ** (d - 1)
         m_pad = max(8, 2 * built)
-        packed = d <= 5
-        acc = m_pad * (-(-F // 4) * 4 if packed else F) * 256 * 4
+        packed, held = d <= 5, d >= 6
+        acc = (F * max(m_pad, 128) if held else
+               m_pad * (-(-F // 4) * 4 if packed else F)) * 256 * 4
         vmem = acc + 2 * 4 * 1024 * (128 + 4 * 128) + boost.VMEM_STACK + (
-            5 * acc if packed else 0)
+            5 * acc if packed else 0) + 4 * 1024 * 128 * held
         assert boost.hist_plan(F, 256, d, 1024) == boost.HistPlan(
             level=d, nodes_derived=2 ** d - built, m_pad=m_pad,
             acc_block_bytes=acc, vmem_bytes=vmem, tile_feats=F, feat_tiles=1,
-            lanes_a_feature=256, regs_a_broadcast=8 if packed else 2)
+            lanes_a_feature=256, regs_a_broadcast=32 if held else 8,
+            mxu_holds="gradients" if held else "indicator")
     # HIGGS, level 5: the block's 917,504 bytes as they were, 19,136,512 asked
     # (14,548,992 + five blocks)
     assert boost.hist_plan(28, 256, 5, 1024)[:7] == (
         5, 16, 32, 917504, 19136512, 28, 1)
     # Criteo, level 5: 32 x 68 x 256 x 4 = 2,228,224 bytes (2,195,456 at 67
-    # slots), 27,000,832 asked; level 7, not packed, is what it was
+    # slots), 27,000,832 asked; level 7, the gradients held, is the parent's
+    # block and 22,413,312 bytes asked, and 524,288 more for the scratch
     assert boost.hist_plan(67, 256, 5, 1024)[:7] == (
         5, 16, 32, 2228224, 27000832, 67, 1)
     assert boost.hist_plan(67, 256, 7, 1024)[:7] == (
-        7, 64, 128, 8781824, 22413312, 67, 1)
+        7, 64, 128, 8781824, 22413312 + 524288, 67, 1)
 
 
 def _kernel_jaxpr(fn, *shapes):
@@ -894,12 +922,14 @@ def _build_eqn(e):
     return None
 
 
-@pytest.mark.parametrize("d", [0, 5, 7])
+@pytest.mark.parametrize("d", [0, 5])
 def test_the_epsilon_kernels_are_the_parent_of_pr_37s(d):
     """The control: at Epsilon's shape (F 2,000 x 64 bins, 391 row blocks)
     the packed build was there already, and PR 37, which packs at every
     width, changes nothing of it.  ``hist_plan`` is field for field what it
-    was at levels 0, 5 and 7, and the traced tile kernel has the parent's
+    was at levels 0 and 5 (level 7, where the MXU holds the gradients since
+    PR 39, is ``test_held_kernel_streams_the_indicator_through_the_planes``'s),
+    and the traced tile kernel has the parent's
     equations: a tile of 128 features is four matmul groups of eight words,
     so 3 rolls a block, 4 ``dot_general`` and 64 lane broadcasts (one a
     word, serving two registers each: ``regs_a_broadcast`` 2) — counted off
@@ -935,8 +965,203 @@ def test_the_epsilon_kernels_are_the_parent_of_pr_37s(d):
     assert _count_eqns(_kernel_jaxpr(fn, *shapes), _build_eqn) == {
         "roll": 3, "dot_general": 4, "lane_broadcast": 64}
     # recorded on the parent of PR 37 (54925f3), this file's shapes
-    assert digest(fn, *shapes) == {0: "fc67337e22ece3c0", 5: "3380c8a958a19bec",
-                                   7: "54a9c6e195654f00"}[d]
+    assert digest(fn, *shapes) == {0: "fc67337e22ece3c0",
+                                   5: "3380c8a958a19bec"}[d]
+
+
+def _level_shapes(nb, R, F, d, derived):
+    """``hist_level``'s arguments at a shape, as ShapeDtypeStructs."""
+    sds = jax.ShapeDtypeStruct
+    rows = lambda dt: sds((nb, R, 1), dt)
+    tab = sds((2 ** (d - 1),), jnp.int32)
+    return [sds((nb, R, F), jnp.int32), rows(jnp.int32), rows(jnp.float32),
+            rows(jnp.float32), tab, tab] + ([tab] if derived else [])
+
+
+@pytest.mark.parametrize("d", [6, 7])
+@pytest.mark.parametrize("shape", ["criteo", "epsilon"])
+def test_held_kernel_streams_the_indicator_through_the_planes(shape, d):
+    """From a full MXU tile of stacked rows on (levels 6 and 7 at depth 8)
+    the MXU holds the gradient planes: at Criteo's shape (one block of 67
+    features x 256 bins) and Epsilon's (tiles of 128 features x 64 bins)
+    the traced kernel transposes its block of codes once, rolls nothing,
+    pays no lane broadcast, compares one ``(B_eff, R)`` register block a
+    feature (a row of codes broadcast down the sublanes against a sublane
+    iota) and contracts each group of ``_pick_held_fc`` features as
+    ``[k * B_eff, R] @ [R, 2 m]``, the planes the right-hand operand, the
+    one the MXU holds.  The whole groups (8 of 8 features at Criteo, 4 of
+    32 at Epsilon) are traced one after another where the block's MXU work
+    fits a grid step (Criteo's level 6, Epsilon's tile) and as a loop of
+    two steps of four groups at Criteo's level 7, whose unrolled step would
+    outgrow the instruction memory (``_groups_a_body``; PERF.md section 5,
+    PR 39); the ragged last group (Criteo's last 3 features) is traced
+    after them."""
+    from rabit_tpu.ops import boost
+
+    nb, F, B = {"criteo": (2560, 67, 256), "epsilon": (391, 2000, 64)}[shape]
+    R = 1024
+    plan = boost.hist_plan(F, B, d, R)
+    be = plan.lanes_a_feature
+    assert (plan.mxu_holds, plan.packed, plan.regs_a_broadcast) == (
+        "gradients", False, be // 8)
+    assert plan.m_rows == 2 ** (d + 1) >= boost.MXU_ROWS
+    fc = boost._pick_held_fc(B)
+    feats = plan.tile_feats
+    jaxpr = _kernel_jaxpr(
+        functools.partial(boost.hist_level.__wrapped__, depth=d, n_bins=B),
+        *_level_shapes(nb, R, F, d, True))
+
+    def pick(e):
+        name = e.primitive.name
+        if name == "dot_general":
+            lhs, rhs = (v.aval.shape for v in e.invars)
+            assert e.params["dimension_numbers"] == (((1,), (0,)), ((), ()))
+            assert rhs == (R, 2 * plan.m_pad) and lhs[1] == R
+            return name
+        if name == "eq" and e.outvars[0].aval.shape == (be, R):
+            return "sublane_broadcast"
+        if name in ("scan", "while"):
+            return f"loop of {e.params.get('length')}"
+        return _build_eqn(e) or (name if name == "transpose" else None)
+
+    full, last = divmod(feats, fc)
+    assert (full, last) == {"criteo": (8, 3), "epsilon": (4, 0)}[shape]
+    per = 4 if (shape, d) == ("criteo", 7) else full
+    loop = {f"loop of {full // per}": 1} if per < full else {}
+    assert _count_eqns(jaxpr, pick) == {
+        "transpose": 1, **loop, "dot_general": per + (last > 0),
+        "sublane_broadcast": per * fc + last}
+
+
+@pytest.mark.parametrize("full,cycles,want", [
+    (8, 4096, 8), (8, 8192, 4), (4, 8192, 4), (4, 16384, 2), (4, 4096, 4),
+    (9, 8192, 3), (7, 8192, 1), (1, 65536, 1)],
+    ids=["criteo-d6", "criteo-d7", "epsilon-d7", "criteo-d7-of-16",
+         "epsilon-d6", "nine", "prime", "one-huge"])
+def test_groups_a_body_keeps_a_step_inside_the_instruction_memory(
+        full, cycles, want):
+    """``_groups_a_body``: the most whole groups a loop step that divide the
+    block's groups and, with one group more, stay within
+    ``HELD_STEP_CYCLES`` of the MXU — all of them (no loop) at Criteo's
+    level 6 and Epsilon's tile, four of eight at Criteo's level 7 (two
+    steps), one where nothing else divides or fits."""
+    from rabit_tpu.ops import boost
+
+    per = boost._groups_a_body(full, cycles)
+    assert per == want and full % per == 0
+    assert per == 1 or (per + 1) * cycles <= boost.HELD_STEP_CYCLES
+
+
+@pytest.mark.parametrize("F,bins,d,n", [
+    (67, 256, 6, 1024), (67, 256, 7, 1024), (2000, 64, 6, 512),
+    (2000, 64, 7, 512)], ids=["criteo-6", "criteo-7", "epsilon-6", "epsilon-7"])
+def test_held_sums_are_numpy_and_the_parents_kernel(F, bins, d, n,
+                                                    monkeypatch):
+    """Where the MXU holds the gradient planes (levels 6 and 7) the sums
+    of a derived level, routed through random split tables, are numpy's
+    float64 ones at the real widths on a few row blocks — Criteo's one
+    block of 67 features x 256 bins, Epsilon's sixteen tiles of 64-bin
+    features, the ragged last one of 80 — and bit for bit what the parent's
+    kernel (the MXU holding the indicator, ``_holds_gradients`` off) gives
+    on the same rows: the products are exact in both forms, and the
+    interpreter adds a lane's rows in the same order."""
+    from rabit_tpu.ops import boost
+
+    block = 256
+    plan = boost.hist_plan(F, bins, d, block)
+    assert plan.mxu_holds == "gradients" and plan.nodes_derived
+    assert F % plan.tile_feats == (80 if F == 2000 else 0)
+    rng = np.random.RandomState(F + d)
+    xb = rng.randint(0, bins, size=(n, F))
+    g, h = rng.randn(n).astype(np.float32), rng.rand(n).astype(np.float32)
+    hist, ref = _level_and_numpy(boost, plan, xb, g, h,
+                                 np.random.RandomState(d), bins, d, block)
+    assert hist.shape == ref.shape == (2 ** (d - 1), F, bins, 2)
+    np.testing.assert_allclose(np.asarray(hist), ref, rtol=1e-4, atol=1e-4)
+    assert np.abs(ref).sum((0, 2, 3)).min() > 0     # every feature has mass
+    monkeypatch.setattr(boost, "_holds_gradients", lambda *a, **k: False)
+    parent = boost.hist_plan(F, bins, d, block)
+    assert parent.mxu_holds == "indicator"
+    was, _ = _level_and_numpy(boost, parent, xb, g, h,
+                              np.random.RandomState(d), bins, d, block)
+    np.testing.assert_array_equal(np.asarray(hist), np.asarray(was))
+
+
+@pytest.mark.parametrize("d", [6, 7])
+def test_held_levels_route_the_missing_code_and_sum_codes_0_128_255(
+        d, monkeypatch):
+    """Criteo's width with ``missing_code`` 255 at the two held levels,
+    derived (``built_right``): codes 0, 128 and 255 lie in every feature
+    (the transposed compare's first, middle and last sublane register of a
+    feature: twenty rows each, the same code in every feature), a fifth of
+    the rows are missing in every feature, and half
+    the thresholds send the missing code left.  The routing is
+    ``went_right``'s, the sums numpy's at the built children, and both the
+    parent's kernel's bit for bit."""
+    from rabit_tpu.ops import boost
+
+    F, B, n, block = 67, 256, 1024, 256
+    rng = np.random.RandomState(70 + d)
+    xb = rng.randint(0, B, size=(n, F))
+    xb[rng.rand(n) < 0.2] = B - 1
+    xb[:60] = np.tile([0, 128, 255], 20)[:, None]
+    g, h = rng.randn(n).astype(np.float32), rng.rand(n).astype(np.float32)
+    n_prev = 2 ** (d - 1)
+    node = rng.randint(0, n_prev, size=n)
+    feat = rng.randint(0, F, size=n_prev)
+    thr = rng.randint(0, B - 1, size=n_prev) | np.where(
+        rng.rand(n_prev) < 0.5, boost.DEFAULT_LEFT, 0)
+    right = rng.randint(0, 2, size=n_prev)
+    args = [boost.block_rows(jnp.asarray(a), block)[0] for a in (
+        xb.astype(np.int32), node.astype(np.int32), g, h)] + [
+        jnp.asarray(a, jnp.int32) for a in (feat, thr, right)]
+
+    def level():
+        return boost.hist_level.__wrapped__(*args, depth=d, n_bins=B,
+                                            interpret=True, missing_code=B - 1)
+
+    assert boost.hist_plan(F, B, d, block).mxu_holds == "gradients"
+    hist, node_out = level()
+    xv, t = xb[np.arange(n), feat[node]], thr[node]
+    routed = 2 * node + np.where(xv == B - 1, t < boost.DEFAULT_LEFT,
+                                 xv > (t & 255))
+    np.testing.assert_array_equal(
+        np.asarray(boost.unblock_rows(node_out, n)), routed)
+    ref = _numpy_histogram(xb, g, h, routed, 2 * n_prev, B)[
+        2 * np.arange(n_prev) + right]
+    np.testing.assert_allclose(np.asarray(hist), ref, rtol=1e-4, atol=1e-4)
+    assert (np.abs(ref[..., [0, 128, 255], 1]).sum(0) > 0).all()
+    monkeypatch.setattr(boost, "_holds_gradients", lambda *a, **k: False)
+    was, was_node = level()
+    np.testing.assert_array_equal(np.asarray(hist), np.asarray(was))
+    np.testing.assert_array_equal(np.asarray(node_out), np.asarray(was_node))
+
+
+def test_hist_plan_holds_the_gradients_from_a_full_tile():
+    """``mxu_holds`` is ``"gradients"`` exactly where the level's built
+    nodes stack a full MXU tile (``m_rows >= MXU_ROWS``: level 6 on), at
+    every width and bin count, one block or tiled; nothing is packed there.
+    ``mxu_i8`` and ``r_split > 1`` keep the indicator held, and their plan
+    is field for field the one the indicator form reckons."""
+    from rabit_tpu.ops import boost
+
+    for F in (5, 28, 67, 130, 2000):
+        for bins in (16, 64, 128, 256, 300):
+            for d in range(9):
+                try:
+                    p = boost.hist_plan(F, bins, d, 1024)
+                except ValueError:
+                    assert d >= 8
+                    continue
+                held = p.m_rows >= boost.MXU_ROWS
+                assert (p.mxu_holds == "gradients") == held == (d >= 6)
+                assert not (held and p.packed)
+                for kw in ({"mxu_i8": True}, {"r_split": 2}):
+                    q = boost.hist_plan(F, bins, d, 1024, **kw)
+                    assert q.mxu_holds == "indicator"
+                    assert q.packed == boost._packed(bins, q.m_rows)
+                    if not held:
+                        assert q == p
 
 
 def test_a_register_count_of_the_packed_build_at_256_bins():
@@ -982,8 +1207,11 @@ def test_hist_plan_span_and_rows_streamed_gauge(F, bins, depth, tiles):
     ``gbdt_hist_feats_a_register`` 2 and 1; ``regs_a_broadcast`` is the
     registers one lane broadcast serves — 2 at Epsilon's 64 lanes a feature,
     at 256 bins 8 where the level packs the codes (below a full MXU tile of
-    stacked rows: up to level 5) and 2 from level 6 on — and the gauge
-    ``gbdt_hist_regs_a_broadcast`` the root's: 2 and 8.  ``nodes_derived`` is 0 up to level
+    stacked rows: up to level 5) — and from level 6 on, where the MXU holds
+    the gradients (``mxu_holds``), the registers one sublane broadcast
+    serves, 8 and 32; the gauge ``gbdt_hist_regs_a_broadcast`` is the
+    root's: 2 and 8, and ``gbdt_hist_levels_gradients_held`` counts the held
+    levels: 2 at depth 8, 0 at HIGGS's depth 6.  ``nodes_derived`` is 0 up to level
     4 and half the level's nodes from level 5 on, and the gauge
     ``gbdt_hist_nodes_derived_per_round`` their sum: 16 at the HIGGS shape,
     16 + 32 + 64 at depth 8."""
@@ -1008,6 +1236,8 @@ def test_hist_plan_span_and_rows_streamed_gauge(F, bins, depth, tiles):
     assert feats.value == {64: 2, 256: 1}[bins]
     regs = obs.get_registry().gauge("gbdt_hist_regs_a_broadcast")
     assert regs.value == {64: 2, 256: 8}[bins]
+    held = obs.get_registry().gauge("gbdt_hist_levels_gradients_held")
+    assert held.value == {6: 0, 8: 2}[depth]
     spans = [e.fields for e in obs.get_recorder().snapshot()
              if e.ts >= t0 and e.kind == "span"
              and e.fields.get("name") == "gbdt.hist_plan"]
@@ -1020,8 +1250,11 @@ def test_hist_plan_span_and_rows_streamed_gauge(F, bins, depth, tiles):
             plan.acc_block_bytes, plan.vmem_bytes)
         assert (s["feat_tiles"], s["tile_feats"]) == (tiles, min(F, 128))
         assert s["lanes_a_feature"] == bins == plan.lanes_a_feature
+        held = s["level"] >= 6
+        assert s["mxu_holds"] == plan.mxu_holds == (
+            "gradients" if held else "indicator")
         assert s["regs_a_broadcast"] == plan.regs_a_broadcast == (
-            2 if bins == 64 or s["level"] >= 6 else 8)
+            bins // 8 if held else 2 if bins == 64 else 8)
         assert s["nodes_derived"] == (2 ** (s["level"] - 1)
                                       if s["level"] >= 5 else 0)
         assert s["nodes_built"] + s["nodes_derived"] == 2 ** s["level"]
@@ -1029,6 +1262,34 @@ def test_hist_plan_span_and_rows_streamed_gauge(F, bins, depth, tiles):
     assert spans[-1]["m_tiles"] == {6: 1, 8: 2}[depth]
     if tiles > 1:
         assert spans[-1]["acc_block_bytes"] == 128 * 128 * 64 * 4
+
+
+@pytest.mark.parametrize("kw,want", [
+    ({}, 2), ({"mxu_i8": True}, 0), ({"r_split": 2}, 0)],
+    ids=["bf16", "i8", "rsplit"])
+def test_levels_gradients_held_gauge_at_the_criteo_shape(kw, want):
+    """At the Criteo shape (F 67 x 256 bins, depth 8) lowering the round
+    sets ``gbdt_hist_levels_gradients_held`` to the levels whose kernel
+    holds the gradient planes in the MXU, levels 6 and 7; under
+    ``mxu_i8`` or ``r_split > 1`` the indicator stays held at every level
+    and the gauge reads 0, and each span says so."""
+    import time
+
+    from rabit_tpu import obs
+
+    n, block, F = 2048, 1024, 67
+    cfg = gbdt.GBDTConfig(n_features=F, n_trees=1, depth=8, n_bins=256, **kw)
+    t0 = time.time()
+    jax.jit(functools.partial(gbdt.train_round_fused, cfg=cfg, interpret=True)
+            ).lower(gbdt.init_state(cfg, n),
+                    jnp.zeros((n // block, block, F), jnp.int32),
+                    jnp.zeros(n, jnp.float32))
+    gauge = obs.get_registry().gauge("gbdt_hist_levels_gradients_held")
+    assert gauge.value == want
+    holds = [e.fields["mxu_holds"] for e in obs.get_recorder().snapshot()
+             if e.ts >= t0 and e.kind == "span"
+             and e.fields.get("name") == "gbdt.hist_plan"]
+    assert holds == ["indicator"] * (7 - want) + ["gradients"] * want
 
 
 def test_train_round_fused_i8_matches_reference():

@@ -5,7 +5,14 @@ All nine were re-recorded on PR 37's tree: below a full MXU tile of stacked
 rows (levels 0 to 5) the kernels now pack the codes four a word at 256 bins
 too, one lane broadcast for eight registers (``ops.boost._packed``), and
 take the codes in a block one 128-lane tile wide; levels 6 and 7 of the
-Criteo shape are the kernels they were.  Before, levels 0 to 4 (the
+Criteo shape were the kernels they had been.  The three Criteo rounds were
+re-recorded on PR 39's tree: from a full MXU tile of stacked rows on
+(levels 6 and 7) the MXU holds the gradient planes and the indicator,
+built transposed, streams through them (``ops.boost._holds_gradients``),
+its whole feature groups unrolled, or at level 7 a loop of two steps of
+four (``ops.boost._accum_held``, ``_groups_a_body``); the six
+HIGGS rounds, whose every level stacks less than a tile, are
+PR 37's, character for character.  Before, levels 0 to 4 (the
 ``higgs-d5`` digests, a depth-5 round at the HIGGS shape with every node of
 every level built) had been the parent of PR 32's (commit 335edd8),
 character for character, and the six whole rounds PR 32's tree (from level
@@ -42,9 +49,9 @@ WANT = {
     ("higgs", "fused"): "7ca445915a49e1d2",
     ("higgs", "hybrid"): "ea8fedafbec8e0ef",
     ("higgs", "dp_fused"): "4f038790436455c1",
-    ("criteo", "fused"): "a94cc66df5b20306",
-    ("criteo", "hybrid"): "09bd272c7c645eb7",
-    ("criteo", "dp_fused"): "5bd484934e5769a4",
+    ("criteo", "fused"): "0c25d691a1637576",
+    ("criteo", "hybrid"): "8e81194d98a68ab5",
+    ("criteo", "dp_fused"): "67caa5ab13074595",
     # levels 0-4 alone
     ("higgs-d5", "fused"): "152bd5e782d1855d",
     ("higgs-d5", "hybrid"): "ed8e1ee0100078f9",

@@ -246,15 +246,22 @@ def test_criteo_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d):
     a block of 68 feature slots, and five blocks more asked for the stack
     on which every matmul group's result lives at once (level 5: 25.8 MiB
     for a 2.1 MiB block, where the default's 16 was refused: "Scoped
-    allocation with size 19.03M"; PR 37)."""
+    allocation with size 19.03M"; PR 37).  From level 6 on the MXU holds the
+    gradient planes: the block is ``(67 x 256, m_pad)``, level 6's 64 node
+    lanes counted as 128, and the ``[128, 1024]`` scratch of the transposed
+    codes, 0.5 MiB, is asked for too (PR 39)."""
     plan = boost.hist_plan(F_CRITEO, B, d, R)
     built = 1 << (d - 1 if d >= 5 else d)
     assert (plan.nodes_built, plan.nodes_derived) == (built, (1 << d) - built)
-    assert plan.packed == (d <= 5) and plan.regs_a_broadcast == (8 if d <= 5 else 2)
+    held = d >= 6
+    assert plan.packed == (d <= 5) and plan.regs_a_broadcast == (
+        32 if held else 8)
+    assert plan.mxu_holds == ("gradients" if held else "indicator")
     slots = 68 if plan.packed else F_CRITEO
-    assert plan.acc_block_bytes == 2 * built * slots * B * 4
+    assert plan.acc_block_bytes == (max(2 * built, 128) if held else
+                                    2 * built) * slots * B * 4
     assert plan.vmem_bytes == ((6 if plan.packed else 1) * plan.acc_block_bytes
-                               + (5 << 20) + boost.VMEM_STACK)
+                               + (5 << 20) + boost.VMEM_STACK + (512 << 10) * held)
     xb3, g3, node3 = _blocked(one_chip, NB_CRITEO, F_CRITEO)
     c = _compile(functools.partial(boost.hist_level, depth=d, n_bins=B),
                  xb3, node3, g3, g3,
@@ -303,17 +310,22 @@ def test_epsilon_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d):
     """The tiled level kernels at the cell's block count, 64 lanes a feature
     and two features a register (64 bins): the root, the last level that
     builds every node (4: two 1 MiB blocks of one tile) and the three that
-    build one child a parent: level 5 is level 4's block, level 6 (two 2
-    MiB blocks) is the default's 16 MiB to the byte and asks nothing, level
-    7 is the first to ask, 20 MiB for its 4 MiB block (28 for 8 at 128
-    lanes a feature, before PR 35).  Each is two custom calls below the
-    root: the routing pass and the sweep of the feature tiles."""
+    build one child a parent: level 5 is level 4's block; from level 6 on
+    the MXU holds the gradient planes and the transposed block's node lanes
+    are whole registers, so level 6 and level 7 both ask 20.5 MiB for a 4
+    MiB block and the ``[128, 1024]`` scratch of the transposed codes (PR
+    39; level 6 had been the default's 16 MiB to the byte, level 7 20 MiB,
+    28 for 8 at 128 lanes a feature before PR 35).  Each is two custom
+    calls below the root: the routing pass and the sweep of the feature
+    tiles."""
     plan = boost.hist_plan(F_EPSILON, B_EPSILON, d, R)
     assert (plan.feat_tiles, plan.tile_feats) == (16, 128)
     built = 1 << (d - 1 if d >= 5 else d)
     assert (plan.nodes_built, plan.nodes_derived) == (built, (1 << d) - built)
     assert plan.lanes_a_feature == 64
-    assert plan.acc_block_bytes == max(8, 2 * built) * 128 * 64 * 4
+    assert plan.mxu_holds == ("gradients" if d >= 6 else "indicator")
+    assert plan.acc_block_bytes == max(128 if d >= 6 else 8,
+                                       2 * built) * 128 * 64 * 4
     xb3, g3, node3 = _blocked(one_chip, NB_EPSILON, F_EPSILON)
     if d == 0:
         c = _compile(functools.partial(boost.hist_level0, n_bins=B_EPSILON),
@@ -326,9 +338,9 @@ def test_epsilon_level_kernel_compiles_for_v5e(one_chip, no_compile_cache, d):
     text = c.as_text()
     assert text.count("tpu_custom_call") >= (2 if d else 1)
     assert (str(plan.vmem_bytes) in text) == (plan.vmem_bytes > boost.VMEM_DEFAULT)
-    assert (plan.vmem_bytes > boost.VMEM_DEFAULT) == (d >= 7)
-    if d == 7:
-        assert plan.vmem_bytes == 20 << 20
+    assert (plan.vmem_bytes > boost.VMEM_DEFAULT) == (d >= 6)
+    if d >= 6:
+        assert plan.vmem_bytes == 41 << 19
 
 
 def _compile_one_tile(one_chip, f, bins, d):
