@@ -39,9 +39,7 @@ from rabit_tpu import obs
 from rabit_tpu.obs.stream import series_name
 
 
-class GBDTConfig(NamedTuple):
-    """Static hyperparameters (hashable: usable as a jit static arg)."""
-
+class _Hyper(NamedTuple):
     n_features: int
     n_trees: int = 20
     depth: int = 6
@@ -85,9 +83,37 @@ class GBDTConfig(NamedTuple):
     # take codes 0..n_bins-2 (compute_bin_edges, quantize).  Codes have to
     # fit a byte: n_bins <= 256.  False: dense codes, ``bin > threshold``.
     missing: bool = False
+    # The features whose codes are categories, not ordered bins: a split on
+    # one sends a SET of codes left (LightGBM's and XGBoost's partition
+    # split, Fisher 1958): the present codes (positive hessian mass) ordered
+    # by G / (H + lambda), ties by code, every prefix of that order scored
+    # (ops/boost.py cat_split, a Pallas kernel a level), the best kept as a
+    # 256-bit set (Forest.sets) and tested by membership in every routing
+    # pass.  The other features keep ``bin > threshold``.  Any sequence of
+    # indices, kept as a sorted tuple (a JSON list is no jit static
+    # argument).  Codes have to fit a byte, and the missing code is not run
+    # with it.
+    categorical: tuple = ()
 
 
-class Forest(NamedTuple):
+class GBDTConfig(_Hyper):
+    """Static hyperparameters (hashable: usable as a jit static arg)."""
+
+    __slots__ = ()
+
+    def __new__(cls, *args, **kw):
+        cfg = super().__new__(cls, *args, **kw)
+        cats = tuple(sorted({int(f) for f in cfg.categorical}))
+        if cats and not (0 <= cats[0] and cats[-1] < cfg.n_features
+                         and cfg.n_bins <= 256 and not cfg.missing):
+            raise ValueError(
+                f"GBDTConfig.categorical {list(cats)}: indices of the "
+                f"{cfg.n_features} features, codes that fit a byte (n_bins "
+                f"{cfg.n_bins} <= 256), and no missing code")
+        return cfg if cats == cfg.categorical else cfg._replace(categorical=cats)
+
+
+class Forest(tuple):
     """A stack of perfect binary trees in level order.
 
     ``feature``/``threshold``: [n_trees, depth, 2**(depth-1)] — level d of a
@@ -98,11 +124,39 @@ class Forest(NamedTuple):
     255`` (``ops.boost.went_right``); dense, no threshold reaches 256.
     ``leaf``: [n_trees, 2**depth] leaf weights.  Untrained trees are
     all-zero and contribute nothing to predictions.
+
+    ``sets`` (under ``GBDTConfig.categorical`` alone): [n_trees, 8,
+    2**depth] int32, word ``w`` of node ``i`` of level ``d`` at ``[t, w,
+    2**d - 1 + i]`` (the last lane unused): a categorical split's set of
+    codes, bit ``c & 31`` of word ``c >> 5`` set where code ``c`` goes left
+    (its threshold is 0), zeros at a numeric node.  The nodes lie along the
+    last axis: with the words there ([n_trees, 255, 8]) the chip's copy to
+    the host came back transposed, not contiguous, 4.0 ms to copy and 6.6
+    to pickle a commit where this layout takes 1.2 and 0.05 (PERF.md
+    section 6).  ``node_sets`` reads a level's.  A forest without
+    categorical features is the three tables alone, so its pytree, its
+    jitted rounds and its checkpoint are what they were; iterating it gives
+    its tables in this order.
     """
 
-    feature: jax.Array
-    threshold: jax.Array
-    leaf: jax.Array
+    _fields = ("feature", "threshold", "leaf")
+    __slots__ = ()
+
+    def __new__(cls, feature, threshold, leaf, sets=None):
+        tables = (feature, threshold, leaf)
+        return super().__new__(cls, tables if sets is None else (*tables, sets))
+
+    def __getnewargs__(self):
+        return tuple(self)
+
+    feature = property(lambda self: self[0])
+    threshold = property(lambda self: self[1])
+    leaf = property(lambda self: self[2])
+    sets = property(lambda self: self[3] if len(self) > 3 else None)
+
+
+jax.tree_util.register_pytree_node(
+    Forest, lambda f: (tuple(f), None), lambda _, tables: Forest(*tables))
 
 
 class TrainState(NamedTuple):
@@ -117,6 +171,8 @@ def init_forest(cfg: GBDTConfig) -> Forest:
         feature=jnp.zeros((cfg.n_trees, cfg.depth, max_nodes), jnp.int32),
         threshold=jnp.zeros((cfg.n_trees, cfg.depth, max_nodes), jnp.int32),
         leaf=jnp.zeros((cfg.n_trees, 2 ** cfg.depth), jnp.float32),
+        sets=jnp.zeros((cfg.n_trees, 8, 2 ** cfg.depth), jnp.int32)
+        if cfg.categorical else None,
     )
 
 
@@ -147,14 +203,25 @@ def compute_bin_edges(X: np.ndarray, n_bins: int,
     return np.nanquantile(X, qs, axis=0).T.astype(np.float32)
 
 
-def quantize(X: jax.Array, edges: jax.Array, missing: bool = False) -> jax.Array:
+def quantize(X: jax.Array, edges: jax.Array, missing: bool = False,
+             categorical: tuple = ()) -> jax.Array:
     """Map features to integer bins in [0, n_bins): bin = #edges <= x.  With
     ``missing`` (edges from ``compute_bin_edges(..., missing=True)``) a NaN
-    takes the top code, ``edges.shape[1] + 1`` = n_bins - 1."""
+    takes the top code, ``edges.shape[1] + 1`` = n_bins - 1.  A
+    ``categorical`` column holds category codes and keeps them: a code of
+    ``n_bins - 1`` or more, a negative one or a NaN is folded into the last
+    code, ``n_bins - 1`` ("every other category")."""
     find = lambda col, e: jnp.searchsorted(e, col, side="right")
     codes = jax.vmap(find, in_axes=(1, 0), out_axes=1)(X, edges).astype(jnp.int32)
     if missing:
         codes = jnp.where(jnp.isnan(X), edges.shape[1] + 1, codes)
+    if categorical:
+        cats = jnp.asarray(categorical)
+        top = edges.shape[1]
+        v = X[:, cats]
+        own = jnp.where(jnp.isnan(v) | (v < 0) | (v >= top), top,
+                        jnp.floor(jnp.nan_to_num(v)))
+        codes = codes.at[:, cats].set(own.astype(jnp.int32))
     return codes
 
 
@@ -205,6 +272,9 @@ def best_splits(hist: jax.Array, cfg: GBDTConfig):
     score = lambda a, b: a * a / (b + cfg.reg_lambda)
     gain = score(GL, HL) + score(GR, HR) - score(G, H)
     valid = (HL >= cfg.min_child_weight) & (HR >= cfg.min_child_weight)
+    if cfg.categorical:     # their splits are sets: cat_split's
+        valid = valid & ~jnp.isin(jnp.arange(hist.shape[1]),
+                                  jnp.asarray(cfg.categorical))[:, None]
     gain = jnp.where(valid, gain, -jnp.inf)
     flat = gain.reshape(gain.shape[0], -1)
     best = jnp.argmax(flat, axis=-1)
@@ -225,16 +295,37 @@ def _missing_code(cfg: GBDTConfig):
 
 def level_splits(hist: jax.Array, cfg: GBDTConfig, level: int,
                  interpret: bool = False):
-    """A level's splits: ``best_splits`` where the codes are dense, the
-    two-way scan of ``ops.boost.split_scan`` (one Pallas kernel a level,
-    ``split_scan_d<level>``) under ``cfg.missing``."""
-    if not cfg.missing:
-        return best_splits(hist, cfg)
+    """A level's splits ``(feature, threshold, gain, sets)``: ``best_splits``
+    where the codes are dense, the two-way scan of ``ops.boost.split_scan``
+    (one Pallas kernel a level, ``split_scan_d<level>``) under
+    ``cfg.missing``; ``sets`` is None but under ``cfg.categorical``.
+
+    There the numeric features' best split (``best_splits``) and the
+    categorical features' (``ops.boost.cat_split``, one Pallas kernel a
+    level, ``cat_split_d<level>``) meet: the higher gain wins, then the
+    lower feature, and a node's set ``[nodes, 8]`` is its categorical
+    winner's, zeros where a numeric split won (threshold 0 where a set
+    did).  A node with no valid candidate gets feature 0, threshold 0 and
+    gain ``-inf``, as ``best_splits`` gives it."""
     from rabit_tpu.ops import boost
 
-    return boost.split_scan(hist, depth=level, reg_lambda=cfg.reg_lambda,
-                            min_child_weight=cfg.min_child_weight,
-                            interpret=interpret)
+    if cfg.categorical:
+        feat, thr, gain = best_splits(hist, cfg)
+        cats = jnp.asarray(cfg.categorical)
+        _count_cat_split()
+        slot, _prefix, cat_gain, words = boost.cat_split(
+            hist[:, cats], depth=level, reg_lambda=cfg.reg_lambda,
+            min_child_weight=cfg.min_child_weight, interpret=interpret)
+        cat_feat = cats[slot]
+        wins = (cat_gain > gain) | ((cat_gain == gain) & (cat_feat < feat))
+        return (jnp.where(wins, cat_feat, feat), jnp.where(wins, 0, thr),
+                jnp.maximum(cat_gain, gain),
+                jnp.where(wins[:, None], words, 0))
+    if not cfg.missing:
+        return (*best_splits(hist, cfg), None)
+    return (*boost.split_scan(hist, depth=level, reg_lambda=cfg.reg_lambda,
+                              min_child_weight=cfg.min_child_weight,
+                              interpret=interpret), None)
 
 
 def _count_split_scan(cfg: GBDTConfig) -> None:
@@ -245,8 +336,30 @@ def _count_split_scan(cfg: GBDTConfig) -> None:
         directions="2" if cfg.missing else "1")).inc()
 
 
-def _goes_left(n_bins: int, thr: jax.Array, missing: bool) -> jax.Array:
-    """``[nodes, n_bins]``: whether a code goes left at each node's split."""
+def _count_cat_split() -> None:
+    """``gbdt_cat_split_lowered_total``: a ``cat_split_d<d>`` kernel lowered,
+    depth of them a round."""
+    obs.get_registry().counter("gbdt_cat_split_lowered_total").inc()
+
+
+def _route_words(thr, sets):
+    """The level's routing sets where it has categorical features
+    (``ops.boost.route_words``), else None."""
+    if sets is None:
+        return None
+    from rabit_tpu.ops import boost
+
+    return boost.route_words(thr, sets)
+
+
+def _goes_left(n_bins: int, thr: jax.Array, missing: bool,
+               words=None) -> jax.Array:
+    """``[nodes, n_bins]``: whether a code goes left at each node's split
+    (``words``: the level's routing sets, ``_route_words``)."""
+    if words is not None:
+        from rabit_tpu.ops import boost
+
+        return boost.set_bits(words, n_bins)
     if not missing:
         return jnp.arange(n_bins) <= thr[:, None]
     from rabit_tpu.ops import boost
@@ -255,18 +368,19 @@ def _goes_left(n_bins: int, thr: jax.Array, missing: bool) -> jax.Array:
 
 
 def split_child_masses(hist: jax.Array, feat: jax.Array, thr: jax.Array,
-                       missing: bool = False) -> jax.Array:
+                       missing: bool = False, words=None) -> jax.Array:
     """Leaf (g, h) masses read off the parent histogram at the chosen split
     — XGBoost's histogram identity (children sums = split cumsums), so the
     leaf fit needs no extra row pass over the data.  ``hist`` is the final
     level's COMBINED [n_nodes, F, B, 2] histogram; returns [2*n_nodes, 2]
     interleaved (left_0, right_0, left_1, right_1, ...) in leaf order
     (leaf = 2*node + went_right).  With ``missing`` the top code's mass goes
-    to the child the threshold's default direction names."""
+    to the child the threshold's default direction names; given the level's
+    routing sets (``words``) the codes of a node's set go left."""
     n_nodes = hist.shape[0]
-    if missing:
+    if missing or words is not None:
         sel = hist[jnp.arange(n_nodes), feat]             # [nodes, B, 2]
-        left = _goes_left(hist.shape[2], thr, True)[..., None]
+        left = _goes_left(hist.shape[2], thr, missing, words)[..., None]
         return jnp.stack([jnp.sum(jnp.where(left, sel, 0.0), 1),
                           jnp.sum(jnp.where(left, 0.0, sel), 1)],
                          axis=1).reshape(2 * n_nodes, 2)
@@ -284,7 +398,7 @@ def split_child_masses(hist: jax.Array, feat: jax.Array, thr: jax.Array,
 
 
 def smaller_child(hist: jax.Array, feat: jax.Array, thr: jax.Array,
-                  missing: bool = False) -> jax.Array:
+                  missing: bool = False, words=None) -> jax.Array:
     """Which child of each node to BUILD at a level that derives the other
     from ``parent - built`` (ops.boost.hist_plan): 1 where the right child's
     hessian mass at the chosen split, ``H - HL``, is under the left's
@@ -293,9 +407,10 @@ def smaller_child(hist: jax.Array, feat: jax.Array, thr: jax.Array,
     because a derived node carries its parent's absolute summation error: on
     the heavier child that is at most its own error to a small factor, on a
     child of a few rows it would be many times its own.  With ``missing``
-    the top code's mass lies on its default direction's side."""
+    the top code's mass lies on its default direction's side; given the
+    level's routing sets (``words``) a node's set is its left child's."""
     h = hist[jnp.arange(hist.shape[0]), feat, :, 1]           # [nodes, B]
-    left = _goes_left(h.shape[1], thr, missing)
+    left = _goes_left(h.shape[1], thr, missing, words)
     hl = jnp.sum(jnp.where(left, h, 0.0), -1)
     hr = jnp.sum(jnp.where(left, 0.0, h), -1)
     return (hr < hl).astype(jnp.int32)
@@ -327,7 +442,9 @@ def train_round(
     reference workload's Allreduce placement (doc/guide.md:130-140).
 
     Under ``cfg.missing`` each level's splits come from the split-scan
-    kernel (``level_splits``), interpreted off a TPU.
+    kernel (``level_splits``), interpreted off a TPU; under
+    ``cfg.categorical`` the categorical features' from ``cat_split``, and a
+    row goes left at a node with a set iff its code is in it.
     """
     from rabit_tpu.ops import boost
 
@@ -340,20 +457,25 @@ def train_round(
     max_nodes = 2 ** (cfg.depth - 1)
     g, h = gradients(cfg, state.margin, y)
     node = jnp.zeros(n, jnp.int32)
-    feats, thrs = [], []
+    feats, thrs, sets = [], [], []
     for d in range(cfg.depth):
         n_nodes = 2 ** d
         with jax.named_scope(f"level{d}"):
             hist = hist_fn(xb, g, h, node, n_nodes, cfg.n_bins)
-            feat, thr, _gain = level_splits(hist, cfg, d, interpret)
+            feat, thr, _gain, level_sets = level_splits(hist, cfg, d, interpret)
             feats.append(jnp.zeros(max_nodes, jnp.int32).at[:n_nodes].set(feat))
             thrs.append(jnp.zeros(max_nodes, jnp.int32).at[:n_nodes].set(thr))
+            words = _route_words(thr, level_sets)
+            if words is not None:
+                sets.append(level_sets)
             # Route every row one level down: right iff bin > threshold
-            # (and the missing code by the split's default direction).
+            # (the missing code by the split's default direction; a code
+            # outside its node's set, where the level has sets).
             fsel = feat[node]                                        # [n]
             xv = jnp.take_along_axis(xb, fsel[:, None], 1)[:, 0]
             node = node * 2 + boost.went_right(
-                xv, thr[node], missing_code).astype(jnp.int32)
+                xv, thr[node], missing_code,
+                None if words is None else words[node]).astype(jnp.int32)
     # Leaf weights from summed per-leaf gradient mass.
     from rabit_tpu.ops import hist as _hist
 
@@ -373,8 +495,24 @@ def train_round(
             state.forest.threshold, jnp.stack(thrs), t, 0
         ),
         leaf=lax.dynamic_update_index_in_dim(state.forest.leaf, leaf, t, 0),
+        sets=_tree_sets(state.forest, sets, t),
     )
     return TrainState(forest=forest, margin=margin, round=t + 1)
+
+
+def _tree_sets(forest: Forest, levels: list, t):
+    """The forest's set table with tree ``t``'s levels' sets (each ``[2**d,
+    8]``) written in, or None where it has none."""
+    if not levels:
+        return None
+    tree = jnp.concatenate(levels + [jnp.zeros((1, 8), jnp.int32)]).T
+    return lax.dynamic_update_index_in_dim(forest.sets, tree, t, 0)
+
+
+def node_sets(tree_sets, level: int):
+    """Level ``level``'s sets ``[2**level, 8]`` of one tree's set table
+    ``[8, 2**depth]`` (``Forest.sets[t]``)."""
+    return tree_sets[:, 2 ** level - 1: 2 ** (level + 1) - 1].T
 
 
 def train_round_dp(state, xb, y, cfg, dp_axis: str = "dp", fp_axis: str | None = None):
@@ -459,7 +597,12 @@ def train_round_fused(
     kernel (``level_splits``: ``split_scan_d<d>``), the span says
     ``missing=True``, and every routing pass, the built child's choice and
     the leaves' masses send the missing code by each split's default
-    direction.
+    direction.  Under ``cfg.categorical`` every level's categorical
+    features are searched by ``cat_split_d<d>`` beside the numeric scan, the
+    span says how many (``categorical``), and every routing pass, the built
+    child's choice and the leaves' masses test a node's set (the level's
+    ``ops.boost.route_words``), the kernels given the set table only then;
+    the tree's sets go to ``Forest.sets``.
 
     ``combine_leaf``, where given, is one more collective a round, for a
     deployment whose collective sequence has a leaf hop (train_round_hybrid):
@@ -493,20 +636,23 @@ def train_round_fused(
                                   interpret=interpret, mxu_i8=cfg.mxu_i8,
                                   r_split=cfg.r_split)
         hist = combine(local)
-        feat, thr, _ = level_splits(hist, cfg, 0, interpret)
+        feat, thr, _, level_sets = level_splits(hist, cfg, 0, interpret)
     feats = [jnp.zeros(max_nodes, jnp.int32).at[:1].set(feat)]
     thrs = [jnp.zeros(max_nodes, jnp.int32).at[:1].set(thr)]
+    sets = [] if level_sets is None else [level_sets]
+    words = _route_words(thr, level_sets)
     node3 = jnp.zeros_like(g3, shape=g3.shape, dtype=jnp.int32)
     nodes_derived = held = 0
     for d in range(1, cfg.depth):
         plan = boost.hist_plan(xb3.shape[2], cfg.n_bins, d, block,
-                               mxu_i8=cfg.mxu_i8, r_split=cfg.r_split)
+                               mxu_i8=cfg.mxu_i8, r_split=cfg.r_split,
+                               categorical=len(cfg.categorical))
         nodes_derived += plan.nodes_derived
         held += plan.mxu_holds == "gradients"
         level = functools.partial(
-            boost.hist_level, xb3, node3, g3, h3, feat, thr, depth=d,
-            n_bins=cfg.n_bins, interpret=interpret, mxu_i8=cfg.mxu_i8,
-            r_split=cfg.r_split, missing_code=missing_code)
+            boost.hist_level, xb3, node3, g3, h3, feat, thr, words=words,
+            depth=d, n_bins=cfg.n_bins, interpret=interpret,
+            mxu_i8=cfg.mxu_i8, r_split=cfg.r_split, missing_code=missing_code)
         with jax.named_scope(f"level{d}"), obs.span(
                 "gbdt.hist_plan", level=d, nodes_built=plan.nodes_built,
                 nodes_derived=plan.nodes_derived,
@@ -516,11 +662,13 @@ def train_round_fused(
                 regs_a_broadcast=plan.regs_a_broadcast,
                 mxu_holds=plan.mxu_holds,
                 acc_block_bytes=plan.acc_block_bytes,
-                vmem_bytes=plan.vmem_bytes, missing=cfg.missing):
+                vmem_bytes=plan.vmem_bytes, missing=cfg.missing,
+                categorical=plan.categorical):
             if plan.nodes_derived:
                 # one child a parent through the kernel and the collective,
                 # its sibling from the parents' combined histogram after it
-                built_right = smaller_child(hist, feat, thr, cfg.missing)
+                built_right = smaller_child(hist, feat, thr, cfg.missing,
+                                            words)
                 built, node3 = level(built_right)
                 hist = boost.derive_siblings(hist, combine(built), built_right)
                 if combine_leaf is not None:
@@ -528,9 +676,12 @@ def train_round_fused(
             else:
                 local, node3 = level()
                 hist = combine(local)
-            feat, thr, _ = level_splits(hist, cfg, d, interpret)
+            feat, thr, _, level_sets = level_splits(hist, cfg, d, interpret)
         feats.append(jnp.zeros(max_nodes, jnp.int32).at[: 2 ** d].set(feat))
         thrs.append(jnp.zeros(max_nodes, jnp.int32).at[: 2 ** d].set(thr))
+        words = _route_words(thr, level_sets)
+        if words is not None:
+            sets.append(level_sets)
     # a sweep a feature tile a level, the root's included; the leaves'
     # routing pass, and one a level below the root where routing is a pass
     # of its own (more tiles than one)
@@ -558,21 +709,21 @@ def train_round_fused(
     # GBDTConfig.fused_final docstring (RESULTS/final_pass.jsonl).
     with jax.named_scope("leaf"):
         if combine_leaf is None:
-            leaf_gh = split_child_masses(hist, feat, thr, cfg.missing)
+            leaf_gh = split_child_masses(hist, feat, thr, cfg.missing, words)
         else:
             leaf_gh = combine_leaf(
-                split_child_masses(local, feat, thr, cfg.missing))
+                split_child_masses(local, feat, thr, cfg.missing, words))
         leaf = (-cfg.learning_rate * leaf_gh[:, 0]
                 / (leaf_gh[:, 1] + cfg.reg_lambda))
         if cfg.fused_final:
             margin3, _ = boost.block_rows(state.margin, block)
             margin3, _node3 = boost.route_margin_level(
-                xb3, node3, margin3, feat, thr, leaf, depth=cfg.depth,
+                xb3, node3, margin3, feat, thr, leaf, words, depth=cfg.depth,
                 interpret=interpret, missing_code=missing_code)
             margin = boost.unblock_rows(margin3, n)
         else:
-            node3 = boost.route_level(xb3, node3, feat, thr, depth=cfg.depth,
-                                      interpret=interpret,
+            node3 = boost.route_level(xb3, node3, feat, thr, words,
+                                      depth=cfg.depth, interpret=interpret,
                                       missing_code=missing_code)
             margin = state.margin + leaf[boost.unblock_rows(node3, n)]
     t = state.round
@@ -584,6 +735,7 @@ def train_round_fused(
             state.forest.threshold, jnp.stack(thrs), t, 0
         ),
         leaf=lax.dynamic_update_index_in_dim(state.forest.leaf, leaf, t, 0),
+        sets=_tree_sets(state.forest, sets, t),
     )
     return TrainState(forest=forest, margin=margin, round=t + 1)
 
@@ -628,9 +780,10 @@ def train_round_hybrid(
       ``segment_sum`` for the leaves.
 
     The counter ``gbdt_hybrid_round_lowered_total{path=fused|xla}`` says
-    which, once a lowering.  ``cfg.missing`` is honoured on both paths but
-    the mesh form, which refuses it: its split scan would be a Pallas kernel
-    outside ``shard_map``, which Mosaic cannot partition.
+    which, once a lowering.  ``cfg.missing`` and ``cfg.categorical`` are
+    honoured on both paths but the mesh form, which refuses them: their
+    split search would be a Pallas kernel outside ``shard_map``, which
+    Mosaic cannot partition.
 
     ``engine_allreduce`` is a host fn ``np.ndarray -> np.ndarray`` (e.g.
     ``lambda a: rabit_tpu.allreduce(a, rt.SUM)``); None means solo (the
@@ -673,6 +826,10 @@ def train_round_hybrid(
     if cfg.missing and mesh is not None:
         raise ValueError("train_round_hybrid: GBDTConfig.missing is not run "
                          "with a mesh (the split-scan kernel would lie "
+                         "outside shard_map); pass mesh=None")
+    if cfg.categorical and mesh is not None:
+        raise ValueError("train_round_hybrid: GBDTConfig.categorical is not "
+                         "run with a mesh (the cat_split kernel would lie "
                          "outside shard_map); pass mesh=None")
     fused = mesh is None and (interpret or jax.default_backend() == "tpu")
     obs.get_registry().counter(series_name(
@@ -753,21 +910,26 @@ def train_round_dp_fused(state, xb3, y, cfg, dp_axis: str = "dp",
 def predict_margin(forest: Forest, xb: jax.Array, cfg: GBDTConfig) -> jax.Array:
     """Sum of leaf values over all trees; [n].  Untrained (zero) trees
     contribute 0, so this is valid mid-training.  Under ``cfg.missing`` the
-    missing code goes by each split's default direction."""
+    missing code goes by each split's default direction; with the forest's
+    set table (``cfg.categorical``) a code in its node's set goes left."""
     from rabit_tpu.ops import boost
 
     n = xb.shape[0]
     missing_code = _missing_code(cfg)
 
     def one_tree(margin, tree):
-        feature, threshold, leaf = tree
+        feature, threshold, leaf, *sets = tree
         pos = jnp.zeros(n, jnp.int32)
         for d in range(cfg.depth):
             f = feature[d][pos]
             thr = threshold[d][pos]
             xv = jnp.take_along_axis(xb, f[:, None], 1)[:, 0]
+            words = None
+            if sets:
+                words = boost.route_words(threshold[d][: 2 ** d],
+                                          node_sets(sets[0], d))[pos]
             pos = pos * 2 + boost.went_right(
-                xv, thr, missing_code).astype(jnp.int32)
+                xv, thr, missing_code, words).astype(jnp.int32)
         return margin + leaf[pos], None
 
     margin, _ = lax.scan(one_tree, jnp.zeros(n, jnp.float32), forest)
@@ -814,12 +976,21 @@ class GBDT:
         self.forest: Forest | None = None
         self.edges: np.ndarray | None = None
 
-    def fit(self, X: np.ndarray, y: np.ndarray, warm_state: TrainState | None = None):
+    def fit(self, X: np.ndarray, y: np.ndarray, warm_state: TrainState | None = None,
+            categorical_features=()):
+        """``categorical_features``: the columns of ``X`` that hold category
+        codes (whole numbers; ``quantize`` folds what lies outside
+        ``0..n_bins-2`` into the last code), split by sets of codes
+        (``GBDTConfig.categorical``)."""
         X = np.asarray(X, np.float32)
         y = np.asarray(y, np.float32)
-        self.cfg = GBDTConfig(n_features=X.shape[1], **self._hyper)
+        hyper = dict(self._hyper)
+        if len(categorical_features):
+            hyper["categorical"] = categorical_features
+        self.cfg = GBDTConfig(n_features=X.shape[1], **hyper)
         self.edges = compute_bin_edges(X, self.cfg.n_bins, self.cfg.missing)
-        xb = quantize(jnp.asarray(X), jnp.asarray(self.edges), self.cfg.missing)
+        xb = quantize(jnp.asarray(X), jnp.asarray(self.edges), self.cfg.missing,
+                      self.cfg.categorical)
         state = warm_state or init_state(self.cfg, X.shape[0])
 
         if self._engine_allreduce is None:
@@ -862,7 +1033,8 @@ class GBDT:
         if self.forest is None:
             raise RuntimeError("GBDT.predict called before fit")
         xb = quantize(jnp.asarray(np.asarray(X, np.float32)),
-                      jnp.asarray(self.edges), self.cfg.missing)
+                      jnp.asarray(self.edges), self.cfg.missing,
+                      self.cfg.categorical)
         fn = jax.jit(functools.partial(predict_margin, cfg=self.cfg))
         return np.asarray(fn(jax.tree.map(jnp.asarray, self.forest), xb))
 

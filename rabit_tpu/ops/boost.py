@@ -58,6 +58,23 @@ level.  The plan asks Mosaic for the scoped VMEM that the blocks take
 where they pass its default, or refuses the shape by name where no tiling
 holds it.
 
+Categorical features (``GBDTConfig.categorical``) split by sets of codes
+(``cat_split``, one kernel a level, ``cat_split_d<d>``).  For node n and
+categorical feature f let (G_c, H_c) be the gradient and hessian sums of
+code c = 0..255 among n's rows; the candidates are the present codes,
+H_c > 0.  They are ordered by k_c = G_c / (H_c + lambda) ascending, ties by
+code ascending; each prefix of that order of size 1 .. K' - 1 (K' present
+codes) is a left set S, GL = sum_{c in S} G_c and HL likewise, scored by
+XGBoost's gain GL^2/(HL+lambda) + GR^2/(HR+lambda) - G^2/(H+lambda) under
+the floor HL, HR >= min_child_weight; the best prefix is n's candidate on
+f.  A row goes LEFT iff its code is in S — an absent code is in no set and
+goes right — and numeric features keep ``code > thr``.  A node's winner
+over all features: the highest gain, then the lowest feature, then the
+lowest bin (numeric) or the shortest prefix (categorical).  A set is eight
+int32 words, code c at bit c & 31 of word c >> 5 (``SET_WORDS``); the
+routing kernels take a level's sets as a table only where the
+configuration has categorical features (``route_words``, ``_set_table``).
+
 All wrappers take pre-blocked arrays (nb, R, ...) so padding/reshaping
 happens once per fit, not once per level.  ``interpret=True`` runs the
 kernels in the Pallas interpreter, which is how the CPU test suite checks
@@ -253,6 +270,7 @@ class HistPlan(NamedTuple):
     lanes_a_feature: int  # 64 at up to 64 bins (two features a register), else 128, 256, ...
     regs_a_broadcast: int  # registers of the indicator one broadcast of codes serves
     mxu_holds: str = "indicator"  # the operand the MXU holds: or "gradients"
+    categorical: int = 0  # categorical features: the routing tests membership in a set
 
     @property
     def nodes_built(self) -> int:
@@ -277,7 +295,8 @@ class HistPlan(NamedTuple):
 
 
 def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int, *,
-              mxu_i8: bool = False, r_split: int = 1) -> HistPlan:
+              mxu_i8: bool = False, r_split: int = 1,
+              categorical: int = 0) -> HistPlan:
     """A level's feature tiles, one tile's accumulator block and the scoped
     VMEM its kernel takes, from the shape alone; ValueError for a shape no
     tiling holds.
@@ -319,7 +338,11 @@ def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int, *,
     lanes as where they are packed, and the kernel transposes them into a
     ``[128, block_rows]`` int32 scratch, counted too.  (F = 67, level 7:
     8.4 MiB of block, 5 of row blocks, 0.5 of scratch, 21.9 MiB with the
-    stack.)"""
+    stack.)
+
+    ``categorical`` is passed through: the features whose splits send a set
+    of codes left (``GBDTConfig.categorical``), so that the level's routing
+    tests membership (``_went_right_by_set``); it changes no block."""
     derived = 2 ** (level - 1) if level >= 1 and 4 * 2 ** level >= MXU_ROWS else 0
     m_pad = _round_up(2 * (2 ** level - derived), 8)
     tile = min(n_feat, TILE_FEATS)
@@ -346,9 +369,9 @@ def hist_plan(n_feat: int, n_bins: int, level: int, block_rows: int, *,
             "one level too many)")
     if held:
         return HistPlan(level, derived, m_pad, acc, need, tile, tiles, be,
-                        be // 8, "gradients")
+                        be // 8, "gradients", categorical)
     return HistPlan(level, derived, m_pad, acc, need, tile, tiles, be,
-                    (4 if packed else 1) * be // 128)
+                    (4 if packed else 1) * be // 128, categorical=categorical)
 
 
 def _encode_bf16(L):
@@ -642,7 +665,7 @@ def _split_of(node, feat_row, thr_row, *, p_pad: int):
 DEFAULT_LEFT = 256
 
 
-def went_right(xv, thr, missing_code=None):
+def went_right(xv, thr, missing_code=None, words=None):
     """Whether a row whose split feature holds code ``xv`` goes right at a
     node of threshold ``thr``: ``xv > thr`` where the codes are dense
     (``missing_code`` None); with a missing code, that code goes the way the
@@ -650,21 +673,93 @@ def went_right(xv, thr, missing_code=None):
     it is above ``thr & 255``.  Elementwise, in a kernel or in XLA alike;
     booleans are and-ed, never selected (Mosaic refuses a select of i1
     vectors), which holds because a threshold's bin lies below the missing
-    code."""
+    code.
+
+    ``words`` (XLA only): the row's node's routing set ``[..., SET_WORDS]``
+    (``route_words``), where the configuration has categorical features;
+    then the row goes right iff its code is NOT in the set: bit ``xv & 31``
+    of word ``xv >> 5`` is clear."""
+    if words is not None:
+        word = jnp.take_along_axis(words, (xv >> 5)[..., None], -1)[..., 0]
+        return (jnp.right_shift(word, xv & 31) & 1) == 0
     if missing_code is None:
         return xv > thr
     return (xv > (thr & (DEFAULT_LEFT - 1))) & ~(
         (xv == missing_code) & (thr >= DEFAULT_LEFT))
 
 
+#: int32 words of a node's set of codes: 256 bits, a bit a code of a byte
+SET_WORDS = 8
+
+
+def route_words(thr, sets):
+    """The sets a level's routing tests, ``[n, SET_WORDS]`` int32: a node's
+    own set where it has one (a categorical split sends the codes of its set
+    left), else the codes ``0..thr`` that ``code > thr`` keeps left, so that
+    every node of a level with categorical features routes by membership
+    alone.  ``sets`` holds zeros at a numeric node (``Forest.sets``)."""
+    lo = 32 * jnp.arange(SET_WORDS, dtype=jnp.int32)
+    n_in = jnp.clip(thr[:, None] + 1 - lo, 0, 32)
+    prefix = jnp.where(n_in >= 32, -1, jnp.left_shift(
+        jnp.int32(1), jnp.minimum(n_in, 31)) - 1)
+    return jnp.where(jnp.any(sets != 0, axis=1, keepdims=True), sets, prefix)
+
+
+def set_bits(words, n_bins: int):
+    """``[n, n_bins]`` bool: whether each code lies in each row's set."""
+    c = jnp.arange(n_bins)
+    return (jnp.right_shift(words[:, c >> 5], c & 31) & 1) == 1
+
+
+def _set_table(words, p_pad: int):
+    """A level's routing sets (``route_words``) as the kernels take them: row
+    ``n`` of a ``(p_pad, 128)`` bfloat16 table holds node ``n``'s set a byte
+    a lane (lane ``j``: codes ``8j..8j+7``), every byte exact in
+    bfloat16."""
+    j = jnp.arange(4 * SET_WORDS)
+    byts = jnp.right_shift(words[:, j >> 2], 8 * (j & 3)) & 255
+    return jnp.zeros((p_pad, 128), jnp.bfloat16).at[
+        :words.shape[0], :4 * SET_WORDS].set(byts.astype(jnp.bfloat16))
+
+
+def _set_lookup(node, feat_row, tab, *, p_pad: int):
+    """``(feat[node], the node's set)`` a row where the level has sets: the
+    rows' one-hot over the nodes, built once, picks the feature by a
+    lane-masked sum and the set's bytes (lanes 0..31 of an ``(R, 128)``
+    float32) against the set table on the MXU — an exact pick, one term a
+    lane, where a lane-masked sum a word would cost eight passes over ``(R,
+    p_pad)``.  No threshold is read: a numeric node's threshold is its set
+    (``route_words``)."""
+    pm = node == lax.broadcasted_iota(jnp.int32, (node.shape[0], p_pad), 1)
+    fsel = jnp.sum(jnp.where(pm, feat_row, 0), axis=1, keepdims=True)
+    return fsel, jnp.dot(pm.astype(jnp.bfloat16), tab,
+                         preferred_element_type=jnp.float32)
+
+
+def _went_right_by_set(xv, byts):
+    """Whether code ``xv`` is NOT in the row's set: bit ``xv & 7`` of byte
+    ``xv >> 3`` of its ``_set_lookup`` row, which is bit ``xv & 31`` of word
+    ``xv >> 5``."""
+    lane = lax.broadcasted_iota(jnp.int32, byts.shape, 1)
+    byte = jnp.sum(jnp.where(lane == (xv >> 3), byts, 0.0), axis=1,
+                   keepdims=True)
+    return (jnp.right_shift(byte.astype(jnp.int32), xv & 7) & 1) == 0
+
+
 def _route(xb_blk, node, feat_row, thr_row, *, p_pad: int, n_feat: int,
-           missing_code=None):
+           missing_code=None, set_tab=None):
     """node' = 2*node + [x[feat[node]] goes right] (``went_right``) —
     split-table lookup and feature select via lane-masked reductions (no
-    gathers)."""
-    fsel, tsel = _split_of(node, feat_row, thr_row, p_pad=p_pad)
+    gathers).  Given the level's ``set_tab`` (``_set_table``) the row goes
+    right iff its code is not in its node's set (``_set_lookup``)."""
+    if set_tab is not None:
+        fsel, byts = _set_lookup(node, feat_row, set_tab, p_pad=p_pad)
+    else:
+        fsel, tsel = _split_of(node, feat_row, thr_row, p_pad=p_pad)
     f_iota = lax.broadcasted_iota(jnp.int32, (node.shape[0], n_feat), 1)
     xv = jnp.sum(jnp.where(f_iota == fsel, xb_blk, 0), axis=1, keepdims=True)
+    if set_tab is not None:
+        return node * 2 + _went_right_by_set(xv, byts).astype(jnp.int32)
     return node * 2 + went_right(xv, tsel, missing_code).astype(jnp.int32)
 
 
@@ -689,11 +784,13 @@ def _level0_kernel(xb_ref, g_ref, h_ref, out_ref, *, n_bins, n_feat, fc, i8,
 
 def _level_kernel(xb_ref, node_ref, g_ref, h_ref, feat_ref, thr_ref, *refs,
                   n_nodes, n_bins, n_feat, m_pad, p_pad, fc, i8, packed,
-                  held=False, r_split=1, missing_code=None):
-    """``refs``: at a derived level the built-child table, then the
+                  held=False, r_split=1, missing_code=None, cats=False):
+    """``refs``: with categorical features (``cats``) the level's set table
+    (``_set_table``), at a derived level the built-child table, then the
     accumulator (``n_nodes`` built nodes), the routed node ids and, where
     the MXU holds the gradients (``held``), the codes' transposed scratch."""
     *refs, codes_t = refs if held else (*refs, None)
+    set_ref, *refs = refs if cats else (None, *refs)
     *built_ref, out_ref, node_out_ref = refs
 
     @pl.when(pl.program_id(0) == 0)
@@ -701,7 +798,8 @@ def _level_kernel(xb_ref, node_ref, g_ref, h_ref, feat_ref, thr_ref, *refs,
         out_ref[:] = jnp.zeros_like(out_ref)
 
     node = _route(xb_ref[0], node_ref[0], feat_ref[0:1], thr_ref[0:1],
-                  p_pad=p_pad, n_feat=xb_ref.shape[2], missing_code=missing_code)
+                  p_pad=p_pad, n_feat=xb_ref.shape[2], missing_code=missing_code,
+                  set_tab=None if set_ref is None else set_ref[...])
     node_out_ref[0] = node
     L = _gradient_matrix(
         node, g_ref[0], h_ref[0], n_nodes=n_nodes, m_pad=m_pad,
@@ -741,28 +839,40 @@ def _tile_kernel(xb_ref, *refs, n_feat, tile, n_nodes, n_bins, m_pad, fc, i8,
 # -- routing-only pass (leaf assignment without histogramming) -------------
 
 
-def _route_kernel(xb_ref, node_ref, feat_ref, thr_ref, node_out_ref, *,
-                  p_pad, n_feat, missing_code=None):
+def _route_kernel(xb_ref, node_ref, feat_ref, thr_ref, *refs, p_pad, n_feat,
+                  missing_code=None):
+    """``refs``: the level's set table where it has categorical features
+    (``_set_table``), then the routed node ids."""
+    *set_ref, node_out_ref = refs
     node_out_ref[0] = _route(xb_ref[0], node_ref[0], feat_ref[0:1],
                              thr_ref[0:1], p_pad=p_pad, n_feat=n_feat,
-                             missing_code=missing_code)
+                             missing_code=missing_code,
+                             set_tab=set_ref[0][...] if set_ref else None)
 
 
-def _route_tile_kernel(xb_ref, node_ref, feat_ref, thr_ref, node_out_ref,
-                       fsel_ref, tsel_ref, *, p_pad, tile, missing_code=None):
+def _route_tile_kernel(xb_ref, node_ref, feat_ref, thr_ref, *refs, p_pad, tile,
+                       missing_code=None, cats=False):
     """Grid (row block, feature tile), the tiles innermost: the code of the
     feature a row's node splits on lies in one tile, so the lane-masked sums
     of all tiles add up to it in ``node_out_ref``, which the last tile turns
     into the node one level down.  The split-table lookup is the row
     block's, made at its first tile and kept in scratch.  (Lanes past F in
-    the ragged last tile match no feature.)"""
+    the ragged last tile match no feature.)  With categorical features
+    (``cats``) ``refs`` begins with the level's set table and ends with a
+    scratch that keeps the row block's sets (``_set_lookup``)."""
+    set_ref, *refs = refs if cats else (None, *refs)
+    node_out_ref, fsel_ref, tsel_ref, *byts_ref = refs
     t = pl.program_id(1)
     node = node_ref[0]
 
     @pl.when(t == 0)
     def _first():
-        fsel_ref[...], tsel_ref[...] = _split_of(
-            node, feat_ref[0:1], thr_ref[0:1], p_pad=p_pad)
+        if cats:
+            fsel_ref[...], byts_ref[0][...] = _set_lookup(
+                node, feat_ref[0:1], set_ref[...], p_pad=p_pad)
+        else:
+            fsel_ref[...], tsel_ref[...] = _split_of(
+                node, feat_ref[0:1], thr_ref[0:1], p_pad=p_pad)
         node_out_ref[0] = jnp.zeros_like(node)
 
     f_iota = lax.broadcasted_iota(jnp.int32, (node.shape[0], tile), 1)
@@ -772,18 +882,26 @@ def _route_tile_kernel(xb_ref, node_ref, feat_ref, thr_ref, node_out_ref,
 
     @pl.when(t == pl.num_programs(1) - 1)
     def _turn():
-        node_out_ref[0] = node * 2 + went_right(
-            node_out_ref[0], tsel_ref[...], missing_code).astype(jnp.int32)
+        twice = node * 2
+        if cats:
+            right = _went_right_by_set(node_out_ref[0], byts_ref[0][...])
+        else:
+            right = went_right(node_out_ref[0], tsel_ref[...], missing_code)
+        node_out_ref[0] = twice + right.astype(jnp.int32)
 
 
 # -- final pass: route to leaves + margin update in one kernel -------------
 
 
 def _route_margin_kernel(xb_ref, node_ref, margin_ref, feat_ref, thr_ref,
-                         leaf_ref, margin_out_ref, node_out_ref, *,
-                         p_pad, l_pad, n_feat, missing_code=None):
+                         leaf_ref, *refs, p_pad, l_pad, n_feat,
+                         missing_code=None):
+    """``refs``: the set table where the level has categorical features
+    (``_set_table``), then the margin and the node ids out."""
+    *set_ref, margin_out_ref, node_out_ref = refs
     node = _route(xb_ref[0], node_ref[0], feat_ref[0:1], thr_ref[0:1],
-                  p_pad=p_pad, n_feat=n_feat, missing_code=missing_code)
+                  p_pad=p_pad, n_feat=n_feat, missing_code=missing_code,
+                  set_tab=set_ref[0][...] if set_ref else None)
     node_out_ref[0] = node
     # margin += leaf[node] without a gather: the leaf table is tiny (64
     # entries at depth 6, 256 at depth 8), so the same lane-masked
@@ -798,20 +916,21 @@ def _route_margin_kernel(xb_ref, node_ref, margin_ref, feat_ref, thr_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("depth", "interpret", "missing_code"))
-def route_margin_level(xb3, node3, margin3, feat, thr, leaf, *, depth: int,
-                       interpret: bool = False, missing_code=None):
+def route_margin_level(xb3, node3, margin3, feat, thr, leaf, words=None, *,
+                       depth: int, interpret: bool = False, missing_code=None):
     """Final fused pass: route rows through the level-(depth-1) split tables
     to their leaves AND apply the margin update ``margin += leaf[node]`` in
     the same streaming pass.  Returns (margin3', leaf_node3).  Replaces
     route_level + a host-level gather: XLA lowers a gather of every row
     from a 2**depth-entry table poorly on TPU, while the in-kernel
     lane-masked sum is a few VPU ops per row.  ``missing_code``: see
-    ``went_right``."""
+    ``went_right``; ``words``: the level's routing sets (``route_words``)
+    where the configuration has categorical features."""
     nb, R, F = xb3.shape
     if F > TILE_FEATS:
         # wider than one tile of codes: the tiled routing pass, and the
         # leaf lookup left to XLA
-        node3 = route_level(xb3, node3, feat, thr, depth=depth,
+        node3 = route_level(xb3, node3, feat, thr, words, depth=depth,
                             interpret=interpret, missing_code=missing_code)
         return margin3 + leaf[node3], node3
     n_prev = 2 ** (depth - 1)
@@ -821,6 +940,7 @@ def route_margin_level(xb3, node3, margin3, feat, thr, leaf, *, depth: int,
     featp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(feat)
     thrp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(thr)
     leafp = jnp.zeros((8, l_pad), jnp.float32).at[0, :n_leaves].set(leaf)
+    sets = [] if words is None else [_set_table(words, p_pad)]
     return pl.pallas_call(
         functools.partial(_route_margin_kernel, p_pad=p_pad, l_pad=l_pad,
                           n_feat=F, missing_code=missing_code),
@@ -830,7 +950,7 @@ def route_margin_level(xb3, node3, margin3, feat, thr, leaf, *, depth: int,
             pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
             pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
             pl.BlockSpec((8, l_pad), lambda i: (0, 0)),
-        ],
+        ] + [pl.BlockSpec((p_pad, 128), lambda i: (0, 0)) for _ in sets],
         out_specs=[_blk(R, 1), _blk(R, 1)],
         out_shape=[
             jax.ShapeDtypeStruct((nb, R, 1), jnp.float32),
@@ -838,40 +958,44 @@ def route_margin_level(xb3, node3, margin3, feat, thr, leaf, *, depth: int,
         ],
         interpret=interpret,
         name=f"route_margin_d{depth}",
-    )(xb3, node3, margin3, featp, thrp, leafp)
+    )(xb3, node3, margin3, featp, thrp, leafp, *sets)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("depth", "interpret", "missing_code"))
-def route_level(xb3, node3, feat, thr, *, depth: int, interpret: bool = False,
-                missing_code=None):
+def route_level(xb3, node3, feat, thr, words=None, *, depth: int,
+                interpret: bool = False, missing_code=None):
     """Route rows one level down through the level-(depth-1) split tables —
     no histogram: the leaf (g, h) masses are read off the final level's
     histogram instead (models.gbdt.split_child_masses), so the last row
     pass only needs the leaf assignment for the margin update.
-    ``missing_code``: see ``went_right``."""
+    ``missing_code``: see ``went_right``; ``words``: the level's routing
+    sets (``route_words``) where the configuration has categorical
+    features."""
     nb, R, F = xb3.shape
     n_prev = 2 ** (depth - 1)
     p_pad = _round_up(n_prev, 128)
     featp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(feat)
     thrp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(thr)
+    sets = [] if words is None else [_set_table(words, p_pad)]
     if F > TILE_FEATS:
         tab = pl.BlockSpec((8, p_pad), lambda i, t: (0, 0))
         row = pl.BlockSpec((1, R, 1), lambda i, t: (i, 0, 0))
         return pl.pallas_call(
             functools.partial(_route_tile_kernel, p_pad=p_pad, tile=TILE_FEATS,
-                              missing_code=missing_code),
+                              missing_code=missing_code, cats=bool(sets)),
             grid=(nb, -(-F // TILE_FEATS)),
             in_specs=[
                 pl.BlockSpec((1, R, TILE_FEATS), lambda i, t: (i, 0, t)),
                 row, tab, tab,
-            ],
+            ] + [pl.BlockSpec((p_pad, 128), lambda i, t: (0, 0)) for _ in sets],
             out_specs=row,
             out_shape=jax.ShapeDtypeStruct((nb, R, 1), jnp.int32),
-            scratch_shapes=[pltpu.VMEM((R, 1), jnp.int32)] * 2,
+            scratch_shapes=[pltpu.VMEM((R, 1), jnp.int32)] * 2 + [
+                pltpu.VMEM((R, 128), jnp.float32) for _ in sets],
             interpret=interpret,
             name=f"route_level_d{depth}",
-        )(xb3, node3, featp, thrp)
+        )(xb3, node3, featp, thrp, *sets)
     return pl.pallas_call(
         functools.partial(_route_kernel, p_pad=p_pad, n_feat=F,
                           missing_code=missing_code),
@@ -880,12 +1004,12 @@ def route_level(xb3, node3, feat, thr, *, depth: int, interpret: bool = False,
             _blk(R, F), _blk(R, 1),
             pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
             pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
-        ],
+        ] + [pl.BlockSpec((p_pad, 128), lambda i: (0, 0)) for _ in sets],
         out_specs=_blk(R, 1),
         out_shape=jax.ShapeDtypeStruct((nb, R, 1), jnp.int32),
         interpret=interpret,
         name=f"route_level_d{depth}",
-    )(xb3, node3, featp, thrp)
+    )(xb3, node3, featp, thrp, *sets)
 
 
 # -- host wrappers (pre-blocked (nb, R, .) arrays) -------------------------
@@ -1016,13 +1140,16 @@ def hist_level0(xb3, g3, h3, *, n_bins: int, interpret: bool = False,
     static_argnames=("depth", "n_bins", "interpret", "mxu_i8", "r_split",
                      "missing_code"),
 )
-def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, *, depth: int,
-               n_bins: int, interpret: bool = False, mxu_i8: bool = False,
-               r_split: int = 1, missing_code=None):
+def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, words=None, *,
+               depth: int, n_bins: int, interpret: bool = False,
+               mxu_i8: bool = False, r_split: int = 1, missing_code=None):
     """Route one level down and histogram; returns
     ([nodes built, F, B, 2], node3').  ``feat``/``thr`` are the level-(depth-1)
     split tables, shape [2**(depth-1)].  ``r_split``: see _accum;
-    ``missing_code``: see ``went_right``.
+    ``missing_code``: see ``went_right``; ``words``: the level-(depth-1)
+    routing sets (``route_words``) where the configuration has categorical
+    features, taken by the kernel as a set table (``_set_table``) only
+    then.
 
     Up to level 4 every node is built: ``[2**depth, F, B, 2]``.  From the
     level ``hist_plan`` derives (5, at any width) the histogram is of ONE
@@ -1059,7 +1186,7 @@ def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, *, depth: int,
                 f"[{n_prev}]" if plan.nodes_derived else "None"))
     built = [] if built_right is None else [_built_table(built_right, m_pad)]
     if plan.feat_tiles > 1:
-        node_out = route_level(xb3, node3, feat, thr, depth=depth,
+        node_out = route_level(xb3, node3, feat, thr, words, depth=depth,
                                interpret=interpret, missing_code=missing_code)
         hist = _hist_tiles(plan, xb3, node_out, g3, h3, n_bins=n_bins,
                            interpret=interpret, mxu_i8=mxu_i8, r_split=r_split,
@@ -1077,18 +1204,20 @@ def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, *, depth: int,
         acc = (m_pad, lanes)
     featp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(feat)
     thrp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(thr)
+    sets = [] if words is None else [_set_table(words, p_pad)]
     out, node_out = pl.pallas_call(
         functools.partial(
             _level_kernel, n_nodes=n_nodes, n_bins=n_bins, n_feat=slots,
             m_pad=m_pad, p_pad=p_pad, fc=fc, i8=mxu_i8, packed=plan.packed,
             held=held, r_split=r_split, missing_code=missing_code,
+            cats=bool(sets),
         ),
         grid=(nb,),
         in_specs=[
             _blk(R, codes), _blk(R, 1), _blk(R, 1), _blk(R, 1),
             pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
             pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
-        ] + [pl.BlockSpec(b.shape, lambda i: (0, 0)) for b in built],
+        ] + [pl.BlockSpec(b.shape, lambda i: (0, 0)) for b in sets + built],
         out_specs=[
             pl.BlockSpec(acc, lambda i: (0, 0)),
             _blk(R, 1),
@@ -1101,7 +1230,7 @@ def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, *, depth: int,
         interpret=interpret,
         name=f"hist_level_d{depth}",
         compiler_params=_vmem_params(plan),
-    )(xb3, node3, g3, h3, featp, thrp, *built)
+    )(xb3, node3, g3, h3, featp, thrp, *sets, *built)
     if held:
         return _held_hists(out, plan, F, n_bins), node_out
     out = _cut_lanes(out, F, n_bins, slots, plan.packed)
@@ -1215,6 +1344,151 @@ def split_scan(hist, *, depth: int, reg_lambda: float, min_child_weight: float,
     key = out[:, 0, 0].astype(jnp.int32)
     fb = key >> 1
     return (fb // B, fb % B + (key & 1) * DEFAULT_LEFT, out[:, 0, 1])
+
+
+# -- categorical split: a set of codes goes left ---------------------------
+
+
+def _bitonic(lane, before, *arrays):
+    """Sort every row of ``arrays`` along the lanes (a power of two of them)
+    by the order ``before(a, b)`` — whether element ``a`` (its values in
+    ``arrays``, in order) precedes ``b`` — a total order: a bitonic network,
+    log2(L) (log2(L) + 1) / 2 compare-exchange steps, each two rolls an
+    array and selects, no gather."""
+    n = lane.shape[1]
+    k = 2
+    while k <= n:
+        j = k // 2
+        while j >= 1:
+            lower = (lane & j) == 0                  # the partner is lane + j
+            # the lower lane keeps the lesser where the run ascends (lane & k
+            # is 0), the greater where it descends: where bits j and k of the
+            # lane agree, this lane keeps the lesser (no compare of booleans,
+            # which Mosaic refuses)
+            keep_min = (((lane // j) ^ (lane // k)) & 1) == 0
+            part = [jnp.where(lower, pltpu.roll(a, n - j, 1), pltpu.roll(a, j, 1))
+                    for a in arrays]
+            less = before(arrays, part)
+            take = (less & keep_min) | ~(less | keep_min)
+            arrays = tuple(jnp.where(take, a, p) for a, p in zip(arrays, part))
+            j //= 2
+        k *= 2
+    return arrays
+
+
+def _cat_split_kernel(g_ref, h_ref, out_ref, *, reg_lambda, min_child_weight):
+    """One node: the ``(slots, L)`` gradient and hessian histograms of its
+    categorical features in (a row a feature, a lane a code; rows past the
+    features and lanes past the codes hold zeros), the best candidate's key,
+    gain and set out in an ``(8, 128)`` block: lane 0 of row 0 the key
+    ``slot * L + prefix - 1``, lane 1 the gain, row 1 the set a byte a lane
+    (lane ``j``: codes ``8j..8j+7``; exact in float32).
+
+    A code is present where its hessian mass is positive.  The present codes
+    are ordered by ``G / (H + lambda)`` ascending, ties by code, the absent
+    ones last (``_bitonic``); two keys are compared by their cross products,
+    ``G_a (H_b + lambda)`` against ``G_b (H_a + lambda)``, because the chip's
+    float32 divide is not correctly rounded and splits ties: at Criteo's
+    first tree, where the codes' sums are small multiples of 0.25, 13 codes
+    of key 0.4 came out of it unequal and a node took a set 4 % short
+    of its best gain (PERF.md section 6), where a product, rounded
+    correctly, keeps equal ratios equal.  Prefix sums run along the sorted
+    lanes as ``split_scan``'s do, and each prefix of 1 to K' - 1 present
+    codes is a left set, scored by XGBoost's gain under the child-weight
+    floor.  The best is the highest gain, then the lowest slot, then the
+    shortest prefix."""
+    g, h = g_ref[0], h_ref[0]
+    n = g.shape[1]
+    lane = lax.broadcasted_iota(jnp.int32, g.shape, 1)
+    G = jnp.sum(g, axis=1, keepdims=True)
+    H = jnp.sum(h, axis=1, keepdims=True)
+    k_present = jnp.sum((h > 0).astype(jnp.int32), axis=1, keepdims=True)
+
+    def before(a, b):
+        (ca, ga, ha), (cb, gb, hb) = a, b
+        pa, pb = ha > 0, hb > 0
+        lhs, rhs = ga * (hb + reg_lambda), gb * (ha + reg_lambda)
+        by_key = (lhs < rhs) | ((lhs == rhs) & (ca < cb))
+        return (pa & ~pb) | (pa & pb & by_key) | (~(pa | pb) & (ca < cb))
+
+    code, GL, HL = _bitonic(lane, before, lane, g, h)
+    step = 1
+    while step < n:
+        GL = GL + jnp.where(lane >= step, pltpu.roll(GL, step, 1), 0.0)
+        HL = HL + jnp.where(lane >= step, pltpu.roll(HL, step, 1), 0.0)
+        step *= 2
+    score = lambda a, b: a * a / (b + reg_lambda)
+    ok = ((lane < k_present - 1) & (HL >= min_child_weight)
+          & (H - HL >= min_child_weight))
+    gain = jnp.where(ok, score(GL, HL) + score(G - GL, H - HL) - score(G, H),
+                     -jnp.inf)
+    best = jnp.max(jnp.max(gain, axis=1, keepdims=True), axis=0,
+                   keepdims=True)
+    slot = lax.broadcasted_iota(jnp.int32, g.shape, 0)
+    flat = (slot * n + lane).astype(jnp.float32)
+    first = jnp.min(jnp.min(jnp.where(gain == best, flat, jnp.inf), axis=1,
+                            keepdims=True), axis=0, keepdims=True)
+    at = first.astype(jnp.int32)
+    shift = n.bit_length() - 1
+    member = (slot == (at >> shift)) & (lane <= (at & (n - 1)))
+    bit = jnp.left_shift(jnp.int32(1), code & 7).astype(jnp.float32)
+    mine = jnp.where(member, bit, 0.0)
+    col = lax.broadcasted_iota(jnp.int32, out_ref.shape[1:], 1)
+    row = lax.broadcasted_iota(jnp.int32, out_ref.shape[1:], 0)
+    out = jnp.where((row == 0) & (col == 0), first,
+                    jnp.where((row == 0) & (col == 1), best, 0.0))
+    for j in range(n // 8):
+        byte = jnp.sum(jnp.sum(jnp.where((code >> 3) == j, mine, 0.0), axis=1,
+                               keepdims=True), axis=0, keepdims=True)
+        out = jnp.where((row == 1) & (col == j), byte, out)
+    out_ref[0] = out
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "depth", "reg_lambda", "min_child_weight", "interpret"))
+def cat_split(hist, *, depth: int, reg_lambda: float, min_child_weight: float,
+              interpret: bool = False):
+    """Best categorical split a node of a level's COMBINED histogram of the
+    categorical features alone, ``[nodes, C, B, 2]``: ``(slot, prefix,
+    gain, words)``, where ``slot`` indexes the C features, the left set is
+    ``prefix`` + 1 codes and ``words`` ``[nodes, SET_WORDS]`` holds it (bit
+    ``c & 31`` of word ``c >> 5``: code ``c`` goes left).  A code with no
+    hessian mass is absent: in no set, so it goes right.  A node with no
+    valid candidate has gain ``-inf`` (``models.gbdt.level_splits`` then
+    takes the numeric candidate).
+
+    The order is LightGBM's and XGBoost's partition search (Fisher 1958):
+    present codes by ``G / (H + lambda)`` ascending, ties by code, and every
+    prefix of that order scored exactly, with no cap on the set and no
+    smoothing (``_cat_split_kernel``).  One kernel a level,
+    ``cat_split_d<depth>``, a grid step a node; the codes are padded to 128
+    or 256 lanes, the features to whole sublane tiles."""
+    nodes, C, B, _ = hist.shape
+    if not 2 <= B <= 32 * SET_WORDS:
+        raise ValueError(f"cat_split: {C} features x {B} codes: a set holds "
+                         f"2..{32 * SET_WORDS} codes (codes of a byte)")
+    lanes = 128 if B <= 128 else 256
+    slots = _round_up(C, 8)
+    g, h = (jnp.pad(hist[..., k], ((0, 0), (0, slots - C), (0, lanes - B)))
+            for k in (0, 1))
+    block = pl.BlockSpec((1, slots, lanes), lambda i: (i, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_cat_split_kernel, reg_lambda=reg_lambda,
+                          min_child_weight=min_child_weight),
+        grid=(nodes,),
+        in_specs=[block, block],
+        out_specs=pl.BlockSpec((1, 8, 128), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nodes, 8, 128), jnp.float32),
+        interpret=interpret,
+        name=f"cat_split_d{depth}",
+    )(g, h)
+    key = out[:, 0, 0].astype(jnp.int32)
+    byts = jnp.zeros((nodes, 4 * SET_WORDS), jnp.int32).at[:, :lanes // 8].set(
+        out[:, 1, :lanes // 8].astype(jnp.int32))
+    words = jnp.zeros((nodes, SET_WORDS), jnp.int32)
+    for b in range(4):
+        words = words | jnp.left_shift(byts[:, b::4], 8 * b)
+    return key // lanes, key % lanes, out[:, 0, 1], words
 
 
 # -- blocking helpers -------------------------------------------------------

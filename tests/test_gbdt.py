@@ -1647,3 +1647,38 @@ def test_rounds_count_their_split_scans_by_directions(missing):
              if e.ts >= t0 and e.kind == "span"
              and e.fields.get("name") == "gbdt.hist_plan"]
     assert [s["missing"] for s in spans] == [missing] * (cfg.depth - 1)
+
+
+@pytest.mark.parametrize("cats", [(), (1, 3)], ids=["dense", "categorical"])
+def test_rounds_count_their_cat_splits_and_the_plan_says_how_many(cats):
+    """``gbdt_cat_split_lowered_total`` moves by one a ``cat_split_d<d>``
+    kernel lowered — a level of ``train_round_fused`` and of ``train_round``
+    each, under ``GBDTConfig.categorical`` — and not at all where no
+    feature is categorical; every ``gbdt.hist_plan`` span's ``categorical``
+    says how many features the level's routing tests by membership, as
+    ``ops.boost.hist_plan`` reckons it."""
+    import time
+
+    from rabit_tpu import obs
+    from rabit_tpu.ops import boost
+
+    cfg, xb, y = _hybrid_case(n=2048, rounds=1, depth=4)
+    cfg = gbdt.GBDTConfig(**{**cfg._asdict(), "categorical": list(cats)})
+    assert cfg.categorical == cats
+    read = lambda: obs.get_registry().counter(
+        "gbdt_cat_split_lowered_total").value
+    before = read()
+    t0 = time.time()
+    state = gbdt.init_state(cfg, y.shape[0])
+    jax.jit(functools.partial(gbdt.train_round_fused, cfg=cfg,
+                              interpret=True)).lower(
+        state, boost.block_rows(xb)[0], y)
+    jax.jit(functools.partial(gbdt.train_round, cfg=cfg)).lower(state, xb, y)
+    assert read() == before + (2 * cfg.depth if cats else 0)
+    spans = [e.fields for e in obs.get_recorder().snapshot()
+             if e.ts >= t0 and e.kind == "span"
+             and e.fields.get("name") == "gbdt.hist_plan"]
+    assert [s["categorical"] for s in spans] == [len(cats)] * (cfg.depth - 1)
+    assert [boost.hist_plan(5, 16, d, 1024, categorical=len(cats)).categorical
+            for d in range(1, cfg.depth)] == [len(cats)] * (cfg.depth - 1)
+    assert (state.forest.sets is None) == (not cats)

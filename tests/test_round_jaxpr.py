@@ -21,6 +21,14 @@ sibling).  The round at the Epsilon shape, which packed already, is held
 to PR 37's parent in tests/test_gbdt.py
 (``test_the_epsilon_kernels_are_the_parent_of_pr_37s``).
 
+``criteo-missing`` is the Criteo shape under ``GBDTConfig.missing``, as
+``criteo-missing.fused-armed`` runs it, recorded on the tree before
+categorical splits came in; ``criteo-off`` is the Criteo shape with
+``categorical`` given as an empty JSON list, the switch off: its three
+rounds are the plain Criteo digests, character for character — a round
+with no categorical feature passes no set table to any kernel and traces
+what it did before the switch existed.
+
 The digests are of ``str(jax.make_jaxpr(...))`` with addresses stripped, by
 this file's own ``digest``; tracing needs shapes only, so the real row
 counts cost nothing.  A change that is meant to alter those programs
@@ -44,7 +52,10 @@ from rabit_tpu.parallel import create_mesh
 #: rows a chip, features, depth: benchmark/configs/higgs-10m5-quarter.json
 #: (a chip's share of higgs-10m5-dp4.json) and criteo-1tb-share.json
 SHAPES = {"higgs": (2_625_000, 28, 6), "criteo": (2_621_440, 67, 8),
-          "higgs-d5": (2_625_000, 28, 5)}
+          "higgs-d5": (2_625_000, 28, 5), "criteo-missing": (2_621_440, 67, 8),
+          "criteo-off": (2_621_440, 67, 8)}
+#: the switches of ``GBDTConfig`` a shape runs with
+SWITCHES = {"criteo-missing": {"missing": True}, "criteo-off": {"categorical": []}}
 WANT = {
     ("higgs", "fused"): "7ca445915a49e1d2",
     ("higgs", "hybrid"): "ea8fedafbec8e0ef",
@@ -56,6 +67,10 @@ WANT = {
     ("higgs-d5", "fused"): "152bd5e782d1855d",
     ("higgs-d5", "hybrid"): "ed8e1ee0100078f9",
     ("higgs-d5", "dp_fused"): "1ef785c9d0fd6356",
+    ("criteo-missing", "fused"): "f6b902890b7aeaef",
+    ("criteo-off", "fused"): "0c25d691a1637576",
+    ("criteo-off", "hybrid"): "8e81194d98a68ab5",
+    ("criteo-off", "dp_fused"): "67caa5ab13074595",
 }
 
 
@@ -67,7 +82,7 @@ def digest(fn, *args) -> str:
 def traced(shape: str, which: str) -> str:
     n, f, depth = SHAPES[shape]
     cfg = gbdt.GBDTConfig(n_features=f, n_trees=500, depth=depth, n_bins=256,
-                          learning_rate=0.1)
+                          learning_rate=0.1, **SWITCHES.get(shape, {}))
     sds = jax.ShapeDtypeStruct
     if which == "dp_fused":
         # four shards of n rows under shard_map, as higgs-full.dp4-armed

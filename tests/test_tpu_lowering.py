@@ -299,6 +299,32 @@ def test_criteo_split_scan_compiles_for_v5e(one_chip, no_compile_cache, d):
     assert "tpu_custom_call" in c.as_text()
 
 
+@pytest.mark.parametrize("d", (0, 6, 7))
+def test_criteo_cat_split_compiles_for_v5e(one_chip, no_compile_cache, d):
+    """benchmark/configs/criteo-1tb-cat-share.json: the categorical split
+    search of a level, ``[2**d, 26, 256, 2]`` in, a grid step a node (a
+    bitonic network along the lanes: rolls and selects, no compare of
+    booleans); and, at levels 6 and 7, where the MXU holds the gradient
+    planes, the level kernel testing membership in the level's set table,
+    and at level 7 the leaves' routing pass with it."""
+    hist = _sds((1 << d, 26, B, 2), jnp.float32, one_chip)
+    c = _compile(functools.partial(boost.cat_split, depth=d, reg_lambda=1.0,
+                                   min_child_weight=1.0), hist)
+    assert f"cat_split_d{d}" in c.as_text()
+    if d == 0:
+        return
+    xb3, g3, node3 = _blocked(one_chip, NB_CRITEO, F_CRITEO)
+    tab = lambda n: _sds((n,), jnp.int32, one_chip)
+    words = lambda n: _sds((n, boost.SET_WORDS), jnp.int32, one_chip)
+    c = _compile(functools.partial(boost.hist_level, depth=d, n_bins=B),
+                 xb3, node3, g3, g3, *_tables(d, tab), words(1 << (d - 1)))
+    assert "tpu_custom_call" in c.as_text()
+    if d == 7:
+        c = _compile(functools.partial(boost.route_level, depth=d + 1),
+                     xb3, node3, tab(1 << d), tab(1 << d), words(1 << d))
+        assert "tpu_custom_call" in c.as_text()
+
+
 # benchmark/configs/epsilon-400k.json: 400,000 rows (391 row blocks) x 2,000
 # features at 64 bins, depth 8: sixteen feature tiles a level, the last one
 # of 80 features, and routing a pass of its own.
