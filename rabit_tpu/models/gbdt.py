@@ -56,18 +56,6 @@ class _Hyper(NamedTuple):
     # fused and hook-based rounds alike; non-TPU backends (exact-f32
     # scatter) ignore it.
     mxu_i8: bool = False
-    # Final leaf pass of train_round_fused: True runs the fused Pallas
-    # route+margin kernel (ops/boost.py route_margin_level); False runs
-    # the routing-only kernel and leaves ``margin += leaf[node]`` to XLA
-    # (a gather of every row from a 2**depth-entry table).  Both are exact.
-    # Figures of an older chip at 1,000,000 rows x 28, depth 6, not
-    # re-measured (RESULTS/final_pass.jsonl): XLA-final won whole-round in
-    # both MXU modes (73.8 vs 78.1 ms bf16, 77.3 vs 78.7 ms i8), so False is
-    # the default.  No tool races the fused kernel any more, and ROADMAP.md
-    # D2 deletes it once the benchmark stops passing this field.  The
-    # benchmark's cells (PERF.md section 5) run 2.6M rows; at depth 8 the
-    # XLA gather is 21.5 ms of a round.
-    fused_final: bool = False
     # Split each row block into this many independent sub-contractions in
     # the level kernels' histogram accumulation (ops/boost.py _accum):
     # sub-block i's MXU matmul has no dependency on sub-block i+1's VPU
@@ -556,7 +544,8 @@ def train_round_fused(
     """One boosting round via the fused Pallas kernels (ops.boost): routing,
     split lookup, and histogram accumulation run in one streaming pass per
     level, so rows cross HBM depth+1 times per round (depth histogram
-    passes + one routing-only leaf pass) instead of ~3x depth.  A matrix
+    passes + one leaf pass that routes them and adds their leaves to the
+    margin, ``ops.boost.route_margin_level``) instead of ~3x depth.  A matrix
     wider than one tile of codes (``ops.boost.TILE_FEATS``) is swept once a
     feature tile a level, and routed in a pass of its own a level.
 
@@ -703,11 +692,12 @@ def train_round_fused(
     # and no histogram work in the last row pass (depth collectives per
     # round, not depth+1, unless the caller's sequence has a leaf hop:
     # ``combine_leaf``).  The last pass routes rows to their leaves and
-    # applies ``margin += leaf[node]`` either inside one fused kernel
-    # (cfg.fused_final) or as a routing kernel plus an XLA gather from the
-    # 2**depth-entry leaf table — the gather form measured faster
-    # whole-round in both MXU modes and is the default; see the
-    # GBDTConfig.fused_final docstring (RESULTS/final_pass.jsonl).
+    # adds each row's leaf to the margin in one kernel, at every depth: XLA
+    # gathered every row from the 2**depth-entry table at some 12 cycles a
+    # row past 128 entries (21.5 ms a Criteo round of 2.6M rows at 256
+    # leaves), and even from 64 entries, a chain of selects, the routing
+    # kernel's node ids went back through HBM first (8.7 against 5.5 ms at
+    # HIGGS's 2.6M rows; PERF.md section 5).
     with jax.named_scope("leaf"):
         if combine_leaf is None:
             leaf_gh = split_child_masses(hist, feat, thr, cfg.missing, words)
@@ -716,17 +706,9 @@ def train_round_fused(
                 split_child_masses(local, feat, thr, cfg.missing, words))
         leaf = (-cfg.learning_rate * leaf_gh[:, 0]
                 / (leaf_gh[:, 1] + cfg.reg_lambda))
-        if cfg.fused_final:
-            margin3, _ = boost.block_rows(state.margin, block)
-            margin3, _node3 = boost.route_margin_level(
-                xb3, node3, margin3, feat, thr, leaf, words, depth=cfg.depth,
-                interpret=interpret, missing_code=missing_code)
-            margin = boost.unblock_rows(margin3, n)
-        else:
-            node3 = boost.route_level(xb3, node3, feat, thr, words,
-                                      depth=cfg.depth, interpret=interpret,
-                                      missing_code=missing_code)
-            margin = state.margin + leaf[boost.unblock_rows(node3, n)]
+        margin = boost.route_margin_level(
+            xb3, node3, state.margin, feat, thr, leaf, words,
+            depth=cfg.depth, interpret=interpret, missing_code=missing_code)
     t = state.round
     forest = Forest(
         feature=lax.dynamic_update_index_in_dim(

@@ -13,8 +13,11 @@ into a single streaming pass per row block:
   a table names, and ``derive_siblings`` reads the other off the parents'
   histogram — XGBoost's and LightGBM's subtraction trick, at the levels
   where it shortens the matmul.
-* ``route_level``   — route to the leaves, no histogram: the leaves' (g, h)
-  masses are read off the last level's histogram.
+* ``route_level``   — route one level down, no histogram: a level's pass of
+  its own where the codes are wider than one tile.
+* ``route_margin_level`` — the leaves' pass: route to the leaves and add
+  each row's leaf to the margin, no node ids written (the leaves' (g, h)
+  masses are read off the last level's histogram).
 
 The histogram itself is the one-hot MXU contraction of ``ops.hist``: the
 row block's gradient matrix L (one g column + one h column per node) is
@@ -853,32 +856,31 @@ def _tile_kernel(xb_ref, *refs, n_feat, tile, n_nodes, n_bins, m_pad, fc, i8,
            feats_left=n_feat - pl.program_id(0) * tile, codes_t=codes_t)
 
 
-# -- routing-only pass (leaf assignment without histogramming) -------------
-
-
-def _route_kernel(xb_ref, node_ref, feat_ref, thr_ref, *refs, p_pad, n_feat,
-                  missing_code=None):
-    """``refs``: the level's set table where it has categorical features
-    (``_set_table``), then the routed node ids."""
-    *set_ref, node_out_ref = refs
-    node_out_ref[0] = _route(xb_ref[0], node_ref[0], feat_ref[0:1],
-                             thr_ref[0:1], p_pad=p_pad, n_feat=n_feat,
-                             missing_code=missing_code,
-                             set_tab=set_ref[0][...] if set_ref else None)
+# -- routing-only passes (no histogram) ------------------------------------
 
 
 def _route_tile_kernel(xb_ref, node_ref, feat_ref, thr_ref, *refs, p_pad, tile,
-                       missing_code=None, cats=False):
+                       missing_code=None, cats=False, leaves=False):
     """Grid (row block, feature tile), the tiles innermost: the code of the
     feature a row's node splits on lies in one tile, so the lane-masked sums
-    of all tiles add up to it in ``node_out_ref``, which the last tile turns
-    into the node one level down.  The split-table lookup is the row
-    block's, made at its first tile and kept in scratch.  (Lanes past F in
+    of all tiles add up to it in the node ids' output block, which the last
+    tile turns into the node one level down.  The split-table lookup is the
+    row block's, made at its first tile and kept in scratch.  (Lanes past F in
     the ragged last tile match no feature.)  With categorical features
-    (``cats``) ``refs`` begins with the level's set table and ends with a
-    scratch that keeps the row block's sets (``_set_lookup``)."""
+    (``cats``) ``refs`` begins with the level's set table and its scratch
+    ends with one that keeps the row block's sets (``_set_lookup``).  The
+    leaves' pass (``leaves``: ``route_margin_level``) takes the leaf planes
+    and the margin after the set table and writes the margin plus each
+    row's leaf (``_leaf_row``) in place of the node ids, the codes' sum
+    kept in one more scratch."""
     set_ref, *refs = refs if cats else (None, *refs)
-    node_out_ref, fsel_ref, tsel_ref, *byts_ref = refs
+    if leaves:
+        (planes_ref, margin_ref, out_ref, fsel_ref, tsel_ref, *byts_ref,
+         xv_ref) = refs
+        at = Ellipsis
+    else:
+        out_ref, fsel_ref, tsel_ref, *byts_ref = refs
+        xv_ref, at = out_ref, 0
     t = pl.program_id(1)
     node = node_ref[0]
 
@@ -890,10 +892,10 @@ def _route_tile_kernel(xb_ref, node_ref, feat_ref, thr_ref, *refs, p_pad, tile,
         else:
             fsel_ref[...], tsel_ref[...] = _split_of(
                 node, feat_ref[0:1], thr_ref[0:1], p_pad=p_pad)
-        node_out_ref[0] = jnp.zeros_like(node)
+        xv_ref[at] = jnp.zeros_like(node)
 
     f_iota = lax.broadcasted_iota(jnp.int32, (node.shape[0], tile), 1)
-    node_out_ref[0] += jnp.sum(
+    xv_ref[at] += jnp.sum(
         jnp.where(f_iota == fsel_ref[...] - t * tile, xb_ref[0], 0), axis=1,
         keepdims=True)
 
@@ -901,81 +903,120 @@ def _route_tile_kernel(xb_ref, node_ref, feat_ref, thr_ref, *refs, p_pad, tile,
     def _turn():
         twice = node * 2
         if cats:
-            right = _went_right_by_set(node_out_ref[0], byts_ref[0][...])
+            right = _went_right_by_set(xv_ref[at], byts_ref[0][...])
         else:
-            right = went_right(node_out_ref[0], tsel_ref[...], missing_code)
-        node_out_ref[0] = twice + right.astype(jnp.int32)
+            right = went_right(xv_ref[at], tsel_ref[...], missing_code)
+        if leaves:
+            out_ref[0] = margin_ref[0] + _leaf_row(
+                twice + right.astype(jnp.int32), planes_ref[...])
+        else:
+            out_ref[0] = twice + right.astype(jnp.int32)
 
 
-# -- final pass: route to leaves + margin update in one kernel -------------
+# -- the leaves' pass: route to the leaves + margin update in one kernel ---
 
 
-def _route_margin_kernel(xb_ref, node_ref, margin_ref, feat_ref, thr_ref,
-                         leaf_ref, *refs, p_pad, l_pad, n_feat,
-                         missing_code=None):
+def _leaf_planes(leaf, l_pad: int):
+    """The leaf table as the leaves' pass takes it: rows 0, 1 and 2 of an
+    ``(8, l_pad)`` bfloat16 table are three planes whose float32 sum is
+    each finite leaf bit for bit — its sign, exponent and first 7
+    significand bits, then the next 8 of what is left, then the rest — cut
+    by masking the bits, so no rounding of a convert is in them.  (From
+    2**-100 down a plane would be subnormal, which the chip flushes; such a
+    leaf is lost in any margin above 2**-76 either way.)  A leaf that is not
+    finite (an empty leaf at ``reg_lambda`` 0 reads 0/0) is 0 in the planes,
+    since the MXU's 0 x NaN would reach every row, and row 3 holds its code:
+    1 for +inf, 2 for -inf, 3 for NaN, 0 for a finite leaf."""
+    top = lambda x: lax.bitcast_convert_type(
+        lax.bitcast_convert_type(x, jnp.int32) & -65536, jnp.float32)
+    finite = jnp.isfinite(leaf)
+    x = jnp.where(finite, leaf, 0.0)
+    hi = top(x)
+    mid = top(x - hi)
+    lo = x - hi - mid
+    code = jnp.where(finite, 0.0,
+                     jnp.where(jnp.isnan(leaf), 3.0,
+                               jnp.where(leaf > 0, 1.0, 2.0)))
+    return jnp.zeros((8, l_pad), jnp.bfloat16).at[:4, :leaf.shape[0]].set(
+        jnp.stack([hi, mid, lo, code]).astype(jnp.bfloat16))
+
+
+def _leaf_row(node, planes):
+    """``leaf[node]`` along the lanes, ``(1, R)``, for the ``(R, 1)`` node ids
+    of a row block: the rows' one-hot over the leaves against the planes
+    (``_leaf_planes``) on the MXU, contracted on the leaves (``q @ k.T``'s
+    form) so that the rows come out along the lanes.  Each plane's sum has
+    one nonzero term, so it is the plane's value, and the three add up to
+    the leaf exactly; a row whose leaf is not finite takes it from its
+    code."""
+    pm = node == lax.broadcasted_iota(
+        jnp.int32, (node.shape[0], planes.shape[1]), 1)
+    v = lax.dot_general(planes, pm.astype(jnp.float32).astype(jnp.bfloat16),
+                        (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+    code = v[3:4]
+    odd = jnp.where(code == 1.0, jnp.inf,
+                    jnp.where(code == 2.0, -jnp.inf, jnp.nan))
+    return jnp.where(code == 0.0, (v[0:1] + v[1:2]) + v[2:3], odd)
+
+
+def _route_margin_kernel(xb_ref, node_ref, feat_ref, thr_ref, *refs, p_pad,
+                         n_feat, missing_code=None):
     """``refs``: the set table where the level has categorical features
-    (``_set_table``), then the margin and the node ids out."""
-    *set_ref, margin_out_ref, node_out_ref = refs
+    (``_set_table``), then the leaf planes, the margin in and the margin
+    out."""
+    *set_ref, planes_ref, margin_ref, out_ref = refs
     node = _route(xb_ref[0], node_ref[0], feat_ref[0:1], thr_ref[0:1],
                   p_pad=p_pad, n_feat=n_feat, missing_code=missing_code,
                   set_tab=set_ref[0][...] if set_ref else None)
-    node_out_ref[0] = node
-    # margin += leaf[node] without a gather: the leaf table is tiny (64
-    # entries at depth 6, 256 at depth 8), so the same lane-masked
-    # reduction as _route's split lookup replaces XLA's gather of every row
-    # from a small table.
-    r = node.shape[0]
-    l_iota = lax.broadcasted_iota(jnp.int32, (r, l_pad), 1)
-    lv = jnp.sum(jnp.where(node == l_iota, leaf_ref[0:1], 0.0), axis=1,
-                 keepdims=True)
-    margin_out_ref[0] = margin_ref[0] + lv
+    out_ref[0] = margin_ref[0] + _leaf_row(node, planes_ref[...])
 
 
 @functools.partial(jax.jit,
                    static_argnames=("depth", "interpret", "missing_code"))
-def route_margin_level(xb3, node3, margin3, feat, thr, leaf, words=None, *,
+def route_margin_level(xb3, node3, margin, feat, thr, leaf, words=None, *,
                        depth: int, interpret: bool = False, missing_code=None):
-    """Final fused pass: route rows through the level-(depth-1) split tables
-    to their leaves AND apply the margin update ``margin += leaf[node]`` in
-    the same streaming pass.  Returns (margin3', leaf_node3).  Replaces
-    route_level + a host-level gather: XLA lowers a gather of every row
-    from a 2**depth-entry table poorly on TPU, while the in-kernel
-    lane-masked sum is a few VPU ops per row.  ``missing_code``: see
-    ``went_right``; ``words``: the level's routing sets (``route_words``)
-    where the configuration has categorical features."""
+    """The leaves' pass: route rows through the level-(depth-1) split tables
+    to their leaves and return ``margin + leaf[leaf node]``, ``[n]`` float32,
+    bit for bit what XLA's gather and add give.  The leaf node ids are not
+    written: the kernel reads the codes and the node ids and moves the
+    margin lane-dense, a ``(1, R)`` row a block (``_leaf_row``), where an XLA
+    gather of every row from a 256-entry table costs some 12 cycles a row
+    and one of up to 64 entries (a chain of selects) still reads the node
+    ids back.  Wider than one tile of codes the tiled routing pass does it
+    at its last tile; in one tile ``_route_margin_kernel`` is 0.3 to 1.5 ms
+    faster than that pass at 2.6M rows (PERF.md section 5).  ``missing_code``: see ``went_right``; ``words``: the
+    level's routing sets (``route_words``) where the configuration has
+    categorical features."""
     nb, R, F = xb3.shape
-    if F > TILE_FEATS:
-        # wider than one tile of codes: the tiled routing pass, and the
-        # leaf lookup left to XLA
-        node3 = route_level(xb3, node3, feat, thr, words, depth=depth,
-                            interpret=interpret, missing_code=missing_code)
-        return margin3 + leaf[node3], node3
+    n = margin.shape[0]
     n_prev = 2 ** (depth - 1)
-    n_leaves = 2 ** depth
     p_pad = _round_up(n_prev, 128)
-    l_pad = _round_up(n_leaves, 128)
     featp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(feat)
     thrp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(thr)
-    leafp = jnp.zeros((8, l_pad), jnp.float32).at[0, :n_leaves].set(leaf)
+    planes = _leaf_planes(leaf, _round_up(2 ** depth, 128))
     sets = [] if words is None else [_set_table(words, p_pad)]
-    return pl.pallas_call(
-        functools.partial(_route_margin_kernel, p_pad=p_pad, l_pad=l_pad,
-                          n_feat=F, missing_code=missing_code),
-        grid=(nb,),
-        in_specs=[
-            _blk(R, F), _blk(R, 1), _blk(R, 1),
-            pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
-            pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
-            pl.BlockSpec((8, l_pad), lambda i: (0, 0)),
-        ] + [pl.BlockSpec((p_pad, 128), lambda i: (0, 0)) for _ in sets],
-        out_specs=[_blk(R, 1), _blk(R, 1)],
-        out_shape=[
-            jax.ShapeDtypeStruct((nb, R, 1), jnp.float32),
-            jax.ShapeDtypeStruct((nb, R, 1), jnp.int32),
-        ],
-        interpret=interpret,
-        name=f"route_margin_d{depth}",
-    )(xb3, node3, margin3, featp, thrp, leafp, *sets)
+    margin3 = jnp.pad(margin, (0, nb * R - n)).reshape(nb, 1, R)
+    name = f"route_margin_d{depth}"
+    if F > TILE_FEATS:
+        out = _route_tiles(xb3, node3, featp, thrp, sets, (planes, margin3),
+                           name=name, interpret=interpret,
+                           missing_code=missing_code)
+    else:
+        fixed = lambda b: pl.BlockSpec(b.shape, lambda i: (0, 0))
+        row = pl.BlockSpec((1, 1, R), lambda i: (i, 0, 0))
+        out = pl.pallas_call(
+            functools.partial(_route_margin_kernel, p_pad=p_pad, n_feat=F,
+                              missing_code=missing_code),
+            grid=(nb,),
+            in_specs=[_blk(R, F), _blk(R, 1), fixed(featp), fixed(thrp)]
+            + [fixed(b) for b in sets] + [fixed(planes), row],
+            out_specs=row,
+            out_shape=jax.ShapeDtypeStruct(margin3.shape, jnp.float32),
+            interpret=interpret,
+            name=name,
+        )(xb3, node3, featp, thrp, *sets, planes, margin3)
+    return out.reshape(-1)[:n]
 
 
 @functools.partial(jax.jit,
@@ -983,50 +1024,55 @@ def route_margin_level(xb3, node3, margin3, feat, thr, leaf, words=None, *,
 def route_level(xb3, node3, feat, thr, words=None, *, depth: int,
                 interpret: bool = False, missing_code=None):
     """Route rows one level down through the level-(depth-1) split tables —
-    no histogram: the leaf (g, h) masses are read off the final level's
-    histogram instead (models.gbdt.split_child_masses), so the last row
-    pass only needs the leaf assignment for the margin update.
-    ``missing_code``: see ``went_right``; ``words``: the level's routing
-    sets (``route_words``) where the configuration has categorical
-    features."""
+    no histogram: a level's routing pass where the codes are wider than one
+    tile (``hist_level``), a grid over (row block, feature tile); at up to
+    ``TILE_FEATS`` features the tile is the codes' width.  The leaves' pass
+    is ``route_margin_level``.  ``missing_code``: see ``went_right``;
+    ``words``: the level's routing sets (``route_words``) where the
+    configuration has categorical features."""
     nb, R, F = xb3.shape
     n_prev = 2 ** (depth - 1)
     p_pad = _round_up(n_prev, 128)
     featp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(feat)
     thrp = jnp.zeros((8, p_pad), jnp.int32).at[0, :n_prev].set(thr)
     sets = [] if words is None else [_set_table(words, p_pad)]
-    if F > TILE_FEATS:
-        tab = pl.BlockSpec((8, p_pad), lambda i, t: (0, 0))
-        row = pl.BlockSpec((1, R, 1), lambda i, t: (i, 0, 0))
-        return pl.pallas_call(
-            functools.partial(_route_tile_kernel, p_pad=p_pad, tile=TILE_FEATS,
-                              missing_code=missing_code, cats=bool(sets)),
-            grid=(nb, -(-F // TILE_FEATS)),
-            in_specs=[
-                pl.BlockSpec((1, R, TILE_FEATS), lambda i, t: (i, 0, t)),
-                row, tab, tab,
-            ] + [pl.BlockSpec((p_pad, 128), lambda i, t: (0, 0)) for _ in sets],
-            out_specs=row,
-            out_shape=jax.ShapeDtypeStruct((nb, R, 1), jnp.int32),
-            scratch_shapes=[pltpu.VMEM((R, 1), jnp.int32)] * 2 + [
-                pltpu.VMEM((R, 128), jnp.float32) for _ in sets],
-            interpret=interpret,
-            name=f"route_level_d{depth}",
-        )(xb3, node3, featp, thrp, *sets)
+    return _route_tiles(xb3, node3, featp, thrp, sets,
+                        name=f"route_level_d{depth}", interpret=interpret,
+                        missing_code=missing_code)
+
+
+def _route_tiles(xb3, node3, featp, thrp, sets, leaves=(), *, name: str,
+                 interpret: bool, missing_code):
+    """``_route_tile_kernel`` over (row block, feature tile), a tile
+    ``TILE_FEATS`` codes wide or the codes' width where they are narrower:
+    the node ids one level down, written over ``node3`` as ``hist_level``'s
+    are, or given ``leaves`` (the leaf planes and the margin ``(nb, 1, R)``)
+    the margin plus each row's leaf."""
+    nb, R, F = xb3.shape
+    tile = min(F, TILE_FEATS)
+    fixed = lambda b: pl.BlockSpec(b.shape, lambda i, t: (0, 0))
+    rows = lambda shape: pl.BlockSpec(shape, lambda i, t: (i, 0, 0))
+    out = (1, 1, R) if leaves else (1, R, 1)
     return pl.pallas_call(
-        functools.partial(_route_kernel, p_pad=p_pad, n_feat=F,
-                          missing_code=missing_code),
-        grid=(nb,),
+        functools.partial(_route_tile_kernel, p_pad=featp.shape[1],
+                          tile=tile, missing_code=missing_code,
+                          cats=bool(sets), leaves=bool(leaves)),
+        grid=(nb, -(-F // tile)),
         in_specs=[
-            _blk(R, F), _blk(R, 1),
-            pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
-            pl.BlockSpec((8, p_pad), lambda i: (0, 0)),
-        ] + [pl.BlockSpec((p_pad, 128), lambda i: (0, 0)) for _ in sets],
-        out_specs=_blk(R, 1),
-        out_shape=jax.ShapeDtypeStruct((nb, R, 1), jnp.int32),
+            pl.BlockSpec((1, R, tile), lambda i, t: (i, 0, t)),
+            rows((1, R, 1)), fixed(featp), fixed(thrp),
+        ] + [fixed(b) for b in sets] + (
+            [fixed(leaves[0]), rows(out)] if leaves else []),
+        out_specs=rows(out),
+        out_shape=jax.ShapeDtypeStruct(
+            (nb, *out[1:]), jnp.float32 if leaves else jnp.int32),
+        scratch_shapes=[pltpu.VMEM((R, 1), jnp.int32)] * 2 + [
+            pltpu.VMEM((R, 128), jnp.float32) for _ in sets] + (
+            [pltpu.VMEM((R, 1), jnp.int32)] if leaves else []),
+        input_output_aliases={} if leaves else {1: 0},
         interpret=interpret,
-        name=f"route_level_d{depth}",
-    )(xb3, node3, featp, thrp, *sets)
+        name=name,
+    )(xb3, node3, featp, thrp, *sets, *leaves)
 
 
 # -- host wrappers (pre-blocked (nb, R, .) arrays) -------------------------
@@ -1161,8 +1207,13 @@ def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, words=None, *,
                depth: int, n_bins: int, interpret: bool = False,
                mxu_i8: bool = False, r_split: int = 1, missing_code=None):
     """Route one level down and histogram; returns
-    ([nodes built, F, B, 2], node3').  ``feat``/``thr`` are the level-(depth-1)
-    split tables, shape [2**(depth-1)].  ``r_split``: see _accum;
+    ([nodes built, F, B, 2], node3').  node3' is written over node3
+    (``input_output_aliases``; XLA copies node3 first where the caller
+    still reads it): the ids are ``(nb, R, 1)`` int32, which the chip tiles
+    to 128 lanes, 1.34 GB at 2.6M rows, so a round holds one buffer of them
+    and not one a level.
+    ``feat``/``thr`` are the level-(depth-1) split tables, shape
+    [2**(depth-1)].  ``r_split``: see _accum;
     ``missing_code``: see ``went_right``; ``words``: the level-(depth-1)
     routing sets (``route_words``) where the configuration has categorical
     features, taken by the kernel as a set table (``_set_table``) only
@@ -1247,6 +1298,7 @@ def hist_level(xb3, node3, g3, h3, feat, thr, built_right=None, words=None, *,
         interpret=interpret,
         name=f"hist_level_d{depth}",
         compiler_params=_vmem_params(plan),
+        input_output_aliases={1: 1},
     )(xb3, node3, g3, h3, featp, thrp, *sets, *built)
     if held:
         return _held_hists(out, plan, F, n_bins), node_out

@@ -185,19 +185,22 @@ FUSED_SHAPES = {
 
 
 @pytest.mark.parametrize("shape", FUSED_SHAPES)
-@pytest.mark.parametrize("fused_final", [True, False])
-def test_train_round_fused_matches_reference(fused_final, shape):
+@pytest.mark.parametrize("whole_blocks", [False, True],
+                         ids=["ragged", "whole_blocks"])
+def test_train_round_fused_matches_reference(whole_blocks, shape):
     """The fused Pallas round (ops.boost, run via the Pallas interpreter on
     CPU) must grow the exact same trees as the hook-based train_round —
-    with either final leaf pass (fused route+margin kernel, or routing
-    kernel + XLA leaf gather)."""
+    with a ragged last row block, and with the rows cut to whole blocks,
+    where the leaves' pass (``ops.boost.route_margin_level``: routing and
+    the margin's lookup in one kernel) takes the margin with no pad."""
     from rabit_tpu.ops import boost
 
     rng = np.random.RandomState(3)
     n, f, bins, depth, block, rounds, eta = FUSED_SHAPES[shape]
+    if whole_blocks:
+        n -= n % block
     cfg = gbdt.GBDTConfig(n_features=f, n_trees=rounds, depth=depth,
-                          n_bins=bins, learning_rate=eta,
-                          fused_final=fused_final)
+                          n_bins=bins, learning_rate=eta)
     if shape == "small":
         xb = jnp.asarray(rng.randint(0, cfg.n_bins, size=(n, f)), jnp.int32)
         y = jnp.asarray(rng.randint(0, 2, size=n), jnp.float32)
@@ -966,7 +969,9 @@ def test_the_epsilon_kernels_are_the_parent_of_pr_37s(d):
     parent's at level 0; level 5's was re-recorded when the built-child
     table came to hold the built children's node ids, one compare a lane
     against a row's node (``_gradient_matrix``), where it had held the
-    side a parent builds."""
+    side a parent builds, and again when the level's routing pass
+    (``route_level``) came to write the routed node ids over the ids it
+    reads (``input_output_aliases``)."""
     from rabit_tpu.ops import boost
     from tests.test_round_jaxpr import digest
 
@@ -997,7 +1002,7 @@ def test_the_epsilon_kernels_are_the_parent_of_pr_37s(d):
         "roll": 3, "dot_general": 4, "lane_broadcast": 64}
     # level 0 recorded on 54925f3, level 5 on this tree; this file's shapes
     assert digest(fn, *shapes) == {0: "fc67337e22ece3c0",
-                                   5: "c5f1a9d8184458f0"}[d]
+                                   5: "69eef720bed12dc6"}[d]
 
 
 def _level_shapes(nb, R, F, d, derived):

@@ -1,7 +1,18 @@
 """The three rounds the benchmark's accepted cells run, as jaxprs, at the
 HIGGS and the Criteo shape.
 
-All thirteen were re-recorded on this tree: from level 3 on
+All thirteen were re-recorded on this tree: the leaves' pass of every
+round at both shapes is one kernel, ``ops.boost.route_margin_level``
+(``route_margin_d<depth>``), which routes the rows to their leaves and adds
+each row's leaf to the margin — the margin in and out lane-dense, no node
+ids written — where the parent's ``route_level`` wrote the node ids and
+XLA gathered every row from the leaf table; and every level's kernel
+(``hist_level``, ``route_level``) writes its routed node ids over the ids
+it reads (``input_output_aliases``), so that the round holds one buffer of
+them, 128 lanes a row on the chip, where XLA's buffer assignment placed
+two or three.  The hybrid round's hops and what every level computes are
+what they were.  They had been recorded
+before when, from level 3 on
 ``ops.boost.hist_plan`` builds one child a parent and derives its sibling
 (level 3 stacks 16 rows of gradient matrix where every node stacked 32,
 level 4 32 where it stacked 64), so every round at both shapes changes at
@@ -58,20 +69,20 @@ SHAPES = {"higgs": (2_625_000, 28, 6), "criteo": (2_621_440, 67, 8),
 #: the switches of ``GBDTConfig`` a shape runs with
 SWITCHES = {"criteo-missing": {"missing": True}, "criteo-off": {"categorical": []}}
 WANT = {
-    ("higgs", "fused"): "7f284df6f0bce3fc",
-    ("higgs", "hybrid"): "cdd34b0eea370d4a",
-    ("higgs", "dp_fused"): "aad8d4587efbba99",
-    ("criteo", "fused"): "56f11d737bb5cec2",
-    ("criteo", "hybrid"): "3eec9c156bc241c6",
-    ("criteo", "dp_fused"): "43537a3901c705d3",
+    ("higgs", "fused"): "292ba3941487de1b",
+    ("higgs", "hybrid"): "191630972d0db2a5",
+    ("higgs", "dp_fused"): "8f57f21e7346ddde",
+    ("criteo", "fused"): "6196c6407a638609",
+    ("criteo", "hybrid"): "79a00463172f7715",
+    ("criteo", "dp_fused"): "d243dd14e7ad8627",
     # levels 0-4 alone: 0-2 built, 3 and 4 derived
-    ("higgs-d5", "fused"): "ee08845e28cda40c",
-    ("higgs-d5", "hybrid"): "6bddb4f99ac5ef9c",
-    ("higgs-d5", "dp_fused"): "c8b7f83d8410b058",
-    ("criteo-missing", "fused"): "2a9b9dad176d47aa",
-    ("criteo-off", "fused"): "56f11d737bb5cec2",
-    ("criteo-off", "hybrid"): "3eec9c156bc241c6",
-    ("criteo-off", "dp_fused"): "43537a3901c705d3",
+    ("higgs-d5", "fused"): "8d42e1648d07e42d",
+    ("higgs-d5", "hybrid"): "5b1cea6122764cac",
+    ("higgs-d5", "dp_fused"): "f7017e8b1f3a78cb",
+    ("criteo-missing", "fused"): "959f25c807fe7f2c",
+    ("criteo-off", "fused"): "6196c6407a638609",
+    ("criteo-off", "hybrid"): "79a00463172f7715",
+    ("criteo-off", "dp_fused"): "d243dd14e7ad8627",
 }
 
 

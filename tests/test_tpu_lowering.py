@@ -85,11 +85,13 @@ def test_route_kernels_lower():
     export_tpu(
         functools.partial(boost.route_level, depth=6), xb3, node3, tab, tab
     )
-    margin3 = jnp.zeros((NB, R, 1), jnp.float32)
-    leaf = jnp.zeros(1 << 6, jnp.float32)
+    # the leaves' pass, routing and margin in one kernel, at Criteo's depth
+    margin = jnp.zeros(NB * R - 100, jnp.float32)
+    tab = jnp.zeros(1 << 7, jnp.int32)
+    leaf = jnp.zeros(1 << 8, jnp.float32)
     export_tpu(
-        functools.partial(boost.route_margin_level, depth=6),
-        xb3, node3, margin3, tab, tab, leaf,
+        functools.partial(boost.route_margin_level, depth=8),
+        xb3, node3, margin, tab, tab, leaf,
     )
 
 
@@ -413,11 +415,41 @@ def test_one_tile_kernel_at_128_lanes_a_feature_compiles_for_v5e(
 
 
 def test_route_level_compiles_for_v5e(one_chip, no_compile_cache):
+    """The routing-only pass, one tile of the codes' width, and the leaves'
+    pass that the round runs at this depth, the margin in and out as a
+    bitcast of the flat ``[n]`` margin (no copy of it to 128 lanes)."""
     xb3, _g3, node3 = _blocked(one_chip)
     tab = _sds((1 << (DEPTH - 1),), jnp.int32, one_chip)
     c = _compile(functools.partial(boost.route_level, depth=DEPTH),
                  xb3, node3, tab, tab)
     assert "tpu_custom_call" in c.as_text()
+    n = NB_REAL * R
+    c = _compile(functools.partial(boost.route_margin_level, depth=DEPTH),
+                 xb3, node3, _sds((n,), jnp.float32, one_chip), tab, tab,
+                 _sds((1 << DEPTH,), jnp.float32, one_chip))
+    text = c.as_text()
+    assert f"route_margin_d{DEPTH}" in text
+    assert f"f32[{NB_REAL},{R},1]" not in text
+
+
+def test_fused_round_routes_the_node_ids_in_place_on_v5e(one_chip,
+                                                        no_compile_cache):
+    """A round's node ids are ``(nb, 1024, 1)`` int32, which the chip tiles
+    to 128 lanes (1.34 GB at HIGGS-quarter's 2,625,000 rows), as it does g,
+    h and the codes' copy.  Every level's kernel writes the routed ids over
+    the ids it reads (``input_output_aliases``), so the compiled round's
+    temporaries hold four such buffers and XLA's buffer assignment has no
+    second one a level to place.  (This shape compiles in seconds; the
+    1M-row one of the tests above takes minutes for the whole round.)"""
+    rows, nb = 2_625_000, 2564
+    cfg = gbdt.GBDTConfig(n_features=F, n_trees=4, depth=DEPTH, n_bins=B)
+    state = jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip),
+                         jax.eval_shape(lambda: gbdt.init_state(cfg, rows)))
+    xb3, _g3, _node3 = _blocked(one_chip, nb=nb)
+    c = _compile(functools.partial(gbdt.train_round_fused, cfg=cfg),
+                 state, xb3, _sds((rows,), jnp.float32, one_chip))
+    slot = nb * R * 128 * 4
+    assert c.memory_analysis().temp_size_in_bytes < 4.5 * slot
 
 
 def _dp_mesh(topo):
@@ -509,9 +541,9 @@ def test_whole_fused_round_compiles_for_v5e(one_chip, no_compile_cache, rows,
 def test_whole_hybrid_round_compiles_for_v5e(one_chip, no_compile_cache,
                                              monkeypatch, rows):
     """The round of the benchmark's ``higgs-quarter.engine-hop`` cell: the
-    fused round's kernels (a histogram a level and the leaves' routing pass:
-    depth + 1 ``tpu_custom_call``) on codes ``[n, F]`` blocked in the graph,
-    and a host callback a hop, depth + 1 of them.  The round asks
+    fused round's kernels (a histogram a level and the leaves' pass, routing
+    and margin: depth + 1 ``tpu_custom_call``) on codes ``[n, F]`` blocked
+    in the graph, and a host callback a hop, depth + 1 of them.  The round asks
     ``jax.default_backend()``, which is the CPU here, so the test steers it
     to its TPU branch."""
     import numpy as np
@@ -526,7 +558,7 @@ def test_whole_hybrid_round_compiles_for_v5e(one_chip, no_compile_cache,
         _state_shapes(cfg, rows, one_chip), xb, y)
     text = c.as_text()
     assert text.count("tpu_custom_call") >= DEPTH + 1
-    assert "hist_level_d5" in text and "route_level_d6" in text
+    assert "hist_level_d5" in text and "route_margin_d6" in text
     assert "node_histograms" not in text
     # a hop is one host transfer out and one back
     hops = [ln for ln in text.splitlines()
